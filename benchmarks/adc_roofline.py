@@ -11,16 +11,24 @@ flagship geometry and prints, per variant:
     bottleneck of the one-hot variant; the nibble variant cuts it 16x)
   - % of v5e HBM peak (819 GB/s) for the true traffic
 
-Run on the real chip (no env overrides). One JSON line per row.
+Runs compiled on a TPU v5e only — the one chip its peaks describe — and
+exits non-zero anywhere else; it never interprets a kernel. One JSON line
+per row, each naming the device.
 """
 
 import json
+import os
+import sys
 import time
 
 import numpy as np
 
-V5E_HBM_GBS = 819.0  # v5e HBM bandwidth peak
-V5E_BF16_TFLOPS = 197.0
+sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+
+# Published peaks of one v5e chip (Google Cloud documentation, "TPU v5e"),
+# keyed by the device_kind jax reports for it. A device that is not in the
+# table is an error, not a default.
+PEAKS = {"TPU v5 lite": {"hbm_gbs": 819.0}}
 
 
 def bench(fn, *args, warmup=2, iters=8):
@@ -40,8 +48,16 @@ def main():
     import jax.numpy as jnp
 
     from distributed_faiss_tpu.ops import adc_pallas, pq
+    from distributed_faiss_tpu.utils import envutil
 
-    backend = jax.default_backend()
+    envutil.place_compile_cache()
+    device_kind = jax.devices()[0].device_kind
+    if device_kind not in PEAKS:
+        sys.stderr.write(
+            f"adc_roofline: device_kind {device_kind!r} has no peaks here "
+            f"(known: {sorted(PEAKS)}); nothing to measure against\n")
+        return 1
+    peaks = PEAKS[device_kind]
     rng = np.random.default_rng(0)
     # flagship knnlm-like geometry: per-(query,probe) lists, m=64
     nq, m, ksub, L = 256, 64, 256, 4096
@@ -57,20 +73,20 @@ def main():
     variants = [
         ("xla-onehot", lambda: pq.adc_scan(lut, codes)),
         ("pallas-onehot-f32",
-         lambda: adc_pallas.adc_scan_pallas(lut, codes, interpret=backend == "cpu")),
+         lambda: adc_pallas.adc_scan_pallas(lut, codes)),
         ("pallas-onehot-bf16",
-         lambda: adc_pallas.adc_scan_pallas(lut_bf16, codes, interpret=backend == "cpu")),
+         lambda: adc_pallas.adc_scan_pallas(lut_bf16, codes)),
         ("pallas-nibble-f32",
-         lambda: adc_pallas.adc_scan_pallas_nibble(lut, codes, interpret=backend == "cpu")),
+         lambda: adc_pallas.adc_scan_pallas_nibble(lut, codes)),
         ("pallas-nibble-bf16",
-         lambda: adc_pallas.adc_scan_pallas_nibble(lut_bf16, codes, interpret=backend == "cpu")),
+         lambda: adc_pallas.adc_scan_pallas_nibble(lut_bf16, codes)),
     ]
 
     for name, fn in variants:
         try:
             dt = bench(fn)
         except Exception as e:  # noqa: BLE001 - report and continue
-            print(json.dumps({"variant": name, "backend": backend,
+            print(json.dumps({"variant": name, "device_kind": device_kind,
                               "error": repr(e)[:200]}), flush=True)
             continue
         lut_bytes = lut_bytes_f32 // (2 if "bf16" in name else 1)
@@ -78,18 +94,21 @@ def main():
         onehot_factor = 16 if "nibble" in name else ksub
         row = {
             "variant": name,
-            "backend": backend,
+            "device_kind": device_kind,
             "nq": nq, "m": m, "L": L,
             "ms": round(dt * 1e3, 3),
             "codes_per_s": round(rows * m / dt / 1e6, 1),  # M codes/s
             "rows_per_s": round(rows / dt / 1e6, 2),  # M rows/s
             "true_gbs": round(true_bytes / dt / 1e9, 2),
-            "hbm_pct": round(100 * true_bytes / dt / 1e9 / V5E_HBM_GBS, 2),
+            "hbm_pct": round(100 * true_bytes / dt / 1e9 / peaks["hbm_gbs"], 2),
             "onehot_store_gbs": round(
                 rows * m * onehot_factor * (2 if "bf16" in name else 4) / dt / 1e9, 1),
         }
         print(json.dumps(row), flush=True)
 
 
+    return 0
+
+
 if __name__ == "__main__":
-    main()
+    sys.exit(main())

@@ -146,9 +146,11 @@ def main():
     ap.add_argument("--only", choices=["knnlm", "ivfsq"], default=None)
     ap.add_argument("--recall-only", action="store_true",
                     help="skip QPS timing (recall is backend-independent: "
-                         "lets a CPU box validate the full-size recall bar "
-                         "while the chip is unavailable)")
+                         "lets a CPU box validate the full-size recall bar)")
     args = ap.parse_args()
+    from distributed_faiss_tpu.utils import envutil
+
+    envutil.place_compile_cache()
     size = "tiny" if args.tiny else ("small" if args.small else "full")
     rng = np.random.default_rng(7)
     if args.only in (None, "knnlm"):

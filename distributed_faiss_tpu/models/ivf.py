@@ -233,8 +233,8 @@ def _ivf_pq_search(centroids, codebooks, list_codes, list_ids, list_sizes, q,
             lut = jnp.broadcast_to(shared_lut[:, None], (nq, g, m, ksub))
         if use_pallas:
             # fused VMEM kernel: per-(query, probe) LUT vs its code tile.
-            # lut_bf16 halves the kernel's VMEM traffic (its measured
-            # bottleneck — 1.5x faster on TPU v5e); the one-hot side is
+            # lut_bf16 halves the kernel's LUT traffic (its speed is not
+            # measured on the chip for today's code); the one-hot side is
             # exact in bf16 and the LUT rounding (~0.4% rel) only perturbs
             # the ADC shortlist, which refine_k_factor rescores exactly.
             from distributed_faiss_tpu.ops import adc_pallas
@@ -269,9 +269,8 @@ def _ivf_flat_search_fused(centroids, list_data, list_ids, list_sizes, refine_da
 
     q3: (nblocks, block, d). ``lax.map`` runs the per-block program
     sequentially on device, so the transient-memory budgets sized for one
-    block still hold — but the host pays a single ~66 ms dispatch for the
-    entire batch instead of one per block (launch-bound serving,
-    benchmarks/profile_ivf.py)."""
+    block still hold — but the host pays a single dispatch for the
+    entire batch instead of one per block (benchmarks/profile_ivf.py)."""
 
     def body(qb):
         vals, ids = _ivf_flat_search(centroids, list_data, list_ids, list_sizes,
@@ -955,9 +954,9 @@ class IVFPQIndex(_IVFBase):
         self.nbits = nbits
         self.pq_iters = pq_iters
         self.use_pallas = use_pallas  # fused ADC kernel instead of XLA one-hot
-        # bf16 LUT inside the pallas kernel: 1.5x faster on TPU v5e (VMEM
-        # traffic is the kernel's bottleneck); pair with refine_k_factor to
-        # keep final scores exact. No effect on the XLA path.
+        # bf16 LUT inside the pallas kernel (not measured on the chip for
+        # today's code); pair with refine_k_factor to keep final scores
+        # exact. No effect on the XLA path.
         self.adc_lut_bf16 = adc_lut_bf16
         self._pallas_runtime_ok = True  # runtime disable, not persisted
         # refine_k_factor > 0: keep fp16 raw rows in HBM and exactly rescore

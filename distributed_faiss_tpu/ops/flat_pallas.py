@@ -57,8 +57,28 @@ def _fit_tile(tile: int, d: int, cap: int, interpret: bool) -> int:
     return max(t, 1)
 
 
+def _decode_f16_bits(h):
+    """int32 holding IEEE-half bit patterns (low 16 bits) -> exact float32.
+
+    Mosaic has no vector load for IEEE float16 on v5e ("Invalid vector type
+    for load"), so fp16 lists ride into the kernel bitcast to uint16 and are
+    decoded with integer ops: sign/exponent/mantissa re-packed into the f32
+    layout (exponent rebias 15 -> 127, all-ones exponent kept for inf/nan).
+    Zeros and subnormals are built arithmetically as ``mant * 2**-24`` — the
+    TPU flushes f32 denormals, so the usual shift-then-scale trick cannot
+    express them. Bit-identical to XLA's f16 -> f32 convert."""
+    sign = jnp.left_shift(h & 0x8000, 16)
+    exp = jnp.right_shift(h, 10) & 0x1F
+    mant = h & 0x3FF
+    exp32 = jnp.where(exp == 31, 255, exp + 112)
+    bits = sign | jnp.left_shift(exp32, 23) | jnp.left_shift(mant, 13)
+    val = jax.lax.bitcast_convert_type(bits, jnp.float32)
+    sub = mant.astype(jnp.float32) * (2.0 ** -24)
+    return jnp.where(exp == 0, jnp.where(sign != 0, -sub, sub), val)
+
+
 def _flat_kernel(metric: str, codec: str, scan_bf16: bool, stored_norms: bool,
-                 tile: int, *refs):
+                 tile: int, g: int, *refs):
     """Score one (query, probe, cap-tile) grid step; see module docstring."""
     li_ref, sz_ref = refs[0], refs[1]
     q_ref, data_ref, ids_ref = refs[2], refs[3], refs[4]
@@ -75,7 +95,12 @@ def _flat_kernel(metric: str, codec: str, scan_bf16: bool, stored_norms: bool,
     qf = q_ref[0].astype(jnp.float32)  # (1, d)
     x = data_ref[0]  # (tile, d) storage dtype
     if codec == "sq8":
-        x = vmin_ref[:, :] + x.astype(jnp.float32) * (span_ref[:, :] / 255.0)
+        # uint8 -> f32 has no Mosaic lowering; widen through int32 (as the
+        # ADC kernels do for their codes)
+        x = vmin_ref[:, :] + x.astype(jnp.int32).astype(jnp.float32) \
+            * (span_ref[:, :] / 255.0)
+    elif codec == "f16":
+        x = _decode_f16_bits(x.astype(jnp.int32))  # uint16 bit patterns
     else:
         x = x.astype(jnp.float32)
     if scan_bf16:
@@ -104,7 +129,7 @@ def _flat_kernel(metric: str, codec: str, scan_bf16: bool, stored_norms: bool,
         s = -(qn - 2.0 * ip + bn)
     ids = ids_ref[0]  # (1, tile)
     pos = kt * tile + jax.lax.broadcasted_iota(jnp.int32, (1, tile), 1)
-    ok = (pos < sz_ref[i, j]) & (ids >= 0)
+    ok = (pos < sz_ref[i * g + j]) & (ids >= 0)
     out_ref[0, 0] = jnp.where(ok, s, NEG_INF)
 
 
@@ -136,12 +161,22 @@ def flat_list_scan_pallas(q, list_data, list_ids, li, sizes_g,
     # (nlist, 1, cap) satisfies it (same trick as adc_pallas' LUT operand).
     def row_spec():
         return pl.BlockSpec((1, 1, tile),
-                            lambda i, j, kt, li_ref, sz_ref: (li_ref[i, j], 0, kt))
+                            lambda i, j, kt, li_ref, sz_ref: (li_ref[i * g + j], 0, kt))
+
+    if codec != "sq8":
+        # raw codecs decode by storage dtype; fp16 rides as its uint16 bit
+        # patterns (_decode_f16_bits). XLA:TPU materialises this reinterpret
+        # as a copy of the whole store per launch (its memory analysis: 541
+        # MB at 1024x512x512) — what the codec costs until the lists are
+        # stored as uint16 or the kernel drops it (ROADMAP S4).
+        codec = "f16" if list_data.dtype == jnp.float16 else "f32"
+        if codec == "f16":
+            list_data = jax.lax.bitcast_convert_type(list_data, jnp.uint16)
 
     in_specs = [
         pl.BlockSpec((1, 1, d), lambda i, j, kt, li_ref, sz_ref: (i, 0, 0)),
         pl.BlockSpec((1, tile, d),
-                     lambda i, j, kt, li_ref, sz_ref: (li_ref[i, j], kt, 0)),
+                     lambda i, j, kt, li_ref, sz_ref: (li_ref[i * g + j], kt, 0)),
         row_spec(),
     ]
     operands = [q.reshape(nq, 1, d), list_data,
@@ -156,7 +191,7 @@ def flat_list_scan_pallas(q, list_data, list_ids, li, sizes_g,
                      span.reshape(1, d).astype(jnp.float32)]
 
     out = pl.pallas_call(
-        functools.partial(_flat_kernel, metric, codec, scan_bf16, stored, tile),
+        functools.partial(_flat_kernel, metric, codec, scan_bf16, stored, tile, g),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,
             grid=(nq, g, cap // tile),
@@ -167,7 +202,11 @@ def flat_list_scan_pallas(q, list_data, list_ids, li, sizes_g,
         ),
         out_shape=jax.ShapeDtypeStruct((nq, g, 1, cap), jnp.float32),
         interpret=interpret,
-    )(li.astype(jnp.int32), sizes_g.astype(jnp.int32), *operands)
+    # the scalar-prefetched operands ride flattened: a 2-D (nq, g) int32
+    # array pads its minor dim to the 128-lane width in SMEM — 2 x 512 KB at
+    # nq=1024 against the 1 MB there is
+    )(li.astype(jnp.int32).reshape(nq * g),
+      sizes_g.astype(jnp.int32).reshape(nq * g), *operands)
     return out[:, :, 0, :]
 
 

@@ -57,25 +57,8 @@ from typing import Dict, Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-
-try:  # jax >= 0.6 exposes shard_map at the top level
-    from jax import shard_map as _shard_map_fn
-except ImportError:  # pragma: no cover
-    import inspect as _inspect
-
-    from jax.experimental.shard_map import shard_map as _shard_map_impl
-
-    if "check_vma" in _inspect.signature(_shard_map_impl).parameters:
-        _shard_map_fn = _shard_map_impl
-    else:
-        # older jax spells the replication-check knob ``check_rep``; the
-        # semantics of check_vma=False (skip the static replication/varying
-        # inference this module's integer id paths defeat) carry over 1:1
-        def _shard_map_fn(f, *, mesh, in_specs, out_specs, check_vma=None):
-            kw = {} if check_vma is None else {"check_rep": check_vma}
-            return _shard_map_impl(f, mesh=mesh, in_specs=in_specs,
-                                   out_specs=out_specs, **kw)
 
 from distributed_faiss_tpu.models import base
 from distributed_faiss_tpu.models import ivf as ivfmod
@@ -154,7 +137,7 @@ def _sharded_knn_jit(q, x, ntotals, mesh, k: int, metric: str, chunk: int,
     # all_gather'ed candidates) but the static checker can't infer it
     # through the integer id path
     if live is not None:
-        fn = _shard_map_fn(
+        fn = shard_map(
             lambda q, x_local, ntot_local, live_local: local_scan_merge(
                 q, x_local, ntot_local[0], k, metric, chunk,
                 live_local=live_local),
@@ -168,7 +151,7 @@ def _sharded_knn_jit(q, x, ntotals, mesh, k: int, metric: str, chunk: int,
     def local(q, x_local, ntot_local):
         return local_scan_merge(q, x_local, ntot_local[0], k, metric, chunk)
 
-    fn = _shard_map_fn(
+    fn = shard_map(
         local,
         mesh=mesh,
         in_specs=(P(), P(AXIS, None), P(AXIS)),
@@ -238,7 +221,7 @@ def _kmeans_step_jit(x, w, cent, mesh, k: int, chunk: int):
         counts = jax.lax.psum(counts, AXIS)
         return jnp.where(counts[:, None] > 0, sums / jnp.maximum(counts, 1.0)[:, None], cent)
 
-    fn = _shard_map_fn(
+    fn = shard_map(
         local,
         mesh=mesh,
         in_specs=(P(AXIS, None), P(AXIS), P()),
@@ -698,7 +681,7 @@ class ShardedPaddedLists:
             fids = ids_local.reshape(per).at[lpos].set(-1, mode="drop")
             return fids.reshape(nl, cap)
 
-        fn = _shard_map_fn(
+        fn = shard_map(
             local,
             mesh=self.mesh,
             in_specs=(P(AXIS, None), P(), P()),
@@ -729,7 +712,7 @@ class ShardedPaddedLists:
             return (flat.reshape((nl, cap) + payload_shape),
                     fids.reshape(nl, cap))
 
-        fn = _shard_map_fn(
+        fn = shard_map(
             local,
             mesh=self.mesh,
             in_specs=(P(AXIS, None) if not payload_shape else P(AXIS, None, None),
@@ -894,7 +877,7 @@ def _sharded_ivf_flat_search(centroids, list_data, list_ids, list_sizes, q,
         [P(), P(), P(), P(AXIS, None, None), P(AXIS, None), P(AXIS)],
         list_norms, raw_data, refining)
 
-    fn = _shard_map_fn(
+    fn = shard_map(
         wrapped,
         mesh=mesh,
         in_specs=tuple(specs),
@@ -1203,7 +1186,7 @@ def _sharded_ivf_pq_search(centroids, codebooks, list_codes, list_ids, list_size
         return best, jnp.take_along_axis(fi, pick, axis=1)
 
     if raw_data is not None:
-        fn = _shard_map_fn(
+        fn = shard_map(
             local,
             mesh=mesh,
             in_specs=(P(), P(), P(AXIS, None, None), P(AXIS, None), P(AXIS),
@@ -1212,7 +1195,7 @@ def _sharded_ivf_pq_search(centroids, codebooks, list_codes, list_ids, list_size
             check_vma=False,
         )
         return fn(q, groups, list_codes, list_ids, list_sizes, raw_data)
-    fn = _shard_map_fn(
+    fn = shard_map(
         lambda a, b, c, d, e: local(a, b, c, d, e, None),
         mesh=mesh,
         in_specs=(P(), P(), P(AXIS, None, None), P(AXIS, None), P(AXIS)),
@@ -1609,7 +1592,7 @@ def _sharded_ivf_flat_search_routed(centroids, list_data, list_ids, list_sizes, 
         [P(), P(), P(), P(), P(AXIS, None, None), P(AXIS, None), P(AXIS)],
         list_norms, raw_data, refining)
 
-    fn = _shard_map_fn(
+    fn = shard_map(
         wrapped,
         mesh=mesh,
         in_specs=tuple(specs),
@@ -1682,7 +1665,7 @@ def _sharded_ivf_pq_search_routed(centroids, codebooks, list_codes, list_ids,
                                    adc_k=adc_k)
 
     if refine:
-        fn = _shard_map_fn(
+        fn = shard_map(
             local,
             mesh=mesh,
             in_specs=(P(), P(), P(), P(AXIS, None, None), P(AXIS, None), P(AXIS),
@@ -1692,7 +1675,7 @@ def _sharded_ivf_pq_search_routed(centroids, codebooks, list_codes, list_ids,
         )
         return fn(q, probes, jnp.asarray(nq_real, jnp.int32),
                   list_codes, list_ids, list_sizes, raw_data)
-    fn = _shard_map_fn(
+    fn = shard_map(
         lambda a, b, c, d, e, f: local(a, b, c, d, e, f, None),
         mesh=mesh,
         in_specs=(P(), P(), P(), P(AXIS, None, None), P(AXIS, None), P(AXIS)),
@@ -1742,9 +1725,10 @@ def _routed_search_blocks(index, q, k: int, nprobe: int, group: int, call,
     out_s = np.empty((nq, k), np.float32)
     out_i = np.empty((nq, k), np.int64)
     slack = float(getattr(index, "_routed_slack", 2.0))
-    # serving is launch-bound on the relay (see base.pick_query_block), so
     # take the largest block whose routed transients fit the byte budget —
-    # they scale with the block through pair_bucket (see _routed_block_size)
+    # they scale with the block through pair_bucket (see _routed_block_size);
+    # the budget and the largest-block premise (base.pick_query_block) are
+    # unmeasured on a local chip — ROADMAP S3
     nb = _routed_block_size(nprobe, S, group, slack,
                             local_k if local_k is not None else k)
     for s0, n, block in base.query_blocks(q, nb):

@@ -77,6 +77,33 @@ def setup_server_logging(level=logging.INFO) -> None:
     )
 
 
+def device_report() -> dict:
+    """Where this process runs, as jax reports it: platform, device kind
+    and count, and per local device its id, coordinates (TPU) and the
+    bytes currently allocated on it — how a client learns which chip a
+    rank holds and how an index is spread over a rank's mesh. Creates the
+    backend if nothing has yet, which is the point: a rank takes its chips
+    when it starts, not at the first request."""
+    import jax
+
+    devices = jax.local_devices()
+
+    def one(dev):
+        stats = dev.memory_stats()  # None where the backend keeps none (CPU)
+        return {"id": dev.id,
+                "coords": list(getattr(dev, "coords", ())),
+                "bytes_in_use": stats["bytes_in_use"] if stats else None}
+
+    return {
+        "platform": devices[0].platform,
+        "device_kind": devices[0].device_kind,
+        "count": len(devices),
+        # the chip restriction this rank was launched with (launcher.rank_env)
+        "visible_chips": os.environ.get("TPU_VISIBLE_CHIPS"),
+        "devices": [one(dev) for dev in devices],
+    }
+
+
 class _ConnState:
     """Per-connection serving state shared by both loops: the response
     write lock (mux responses are written by whichever thread completes
@@ -653,7 +680,9 @@ class IndexServer:
         pallas_guarded): ``use_nibble`` is the process-wide nibble-kernel
         flag, ``pallas_degraded`` lists indexes whose configured pallas
         intent fell back to XLA on this backend — an operator's cue to
-        check the rank's logs before trusting its serving throughput."""
+        check the rank's logs before trusting its serving throughput.
+        ``device`` is :func:`device_report`: the platform and chips this
+        rank really runs on."""
         with self.indexes_lock:
             snapshot = list(self.indexes.items())
         states = {iid: idx.get_state().name for iid, idx in snapshot}
@@ -670,6 +699,7 @@ class IndexServer:
             "indexes": states,
             "kernels": {"use_nibble": adc_pallas.USE_NIBBLE,
                         "pallas_degraded": degraded},
+            "device": device_report(),
         }
 
     def stop(self) -> None:
@@ -728,6 +758,7 @@ class IndexServer:
         s.listen(16)
         # graftlint: atomic(socket): bound once before either serving loop accepts; stop()'s null runs during teardown, where the loops already treat accept()/select() OSErrors as the exit signal
         self.socket = s
+        logger.info("server rank=%d device %s", self.rank, device_report())
         self._start_metrics()
         return s
 
@@ -1240,6 +1271,7 @@ def main(argv=None):
                              "sweeper (peer resolution)")
     args = parser.parse_args(argv)
     setup_server_logging()
+    envutil.place_compile_cache()
     server = IndexServer(args.rank, args.storage_dir,
                          discovery_path=args.discovery)
     server.start_blocking(args.port, v6=args.ipv6, load_index=args.load_index)

@@ -434,8 +434,11 @@ class ServerHarness:
                "--discovery", self.discovery_path]
         if load_index:
             cmd.append("--load-index")
-        proc = subprocess.Popen(
-            cmd, env={**os.environ, **self.env, **(extra_env or {})})
+        # the same chips the rank held before it died (launcher.rank_env)
+        env = launcher.rank_env(
+            rank, self.num_servers,
+            {**os.environ, **self.env, **(extra_env or {})})
+        proc = subprocess.Popen(cmd, env=env)
         with self._lock:
             self.procs[rank] = proc
 

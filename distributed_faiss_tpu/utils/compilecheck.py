@@ -49,7 +49,7 @@ def enabled() -> bool:
 _MU = threading.Lock()
 _COUNTS = {}  # computation name -> number of XLA compilations observed
 
-# jax 0.4.x logs lowering via the pxla interpreter logger (DEBUG
+# jax logs lowering via the pxla interpreter logger (DEBUG
 # normally, WARNING under jax_log_compiles — both match):
 #   "Compiling <name> with global shapes and types [...]."
 _LOGGER_NAME = "jax._src.interpreters.pxla"

@@ -309,11 +309,15 @@ def spec_flat_list_scan_pallas():
     li = _sds((_K, _NPROBE), "int32")
     sz = _sds((_K, _NPROBE), "int32")
     norms = _sds((_NLIST, _CAP), "float32")
+    data8 = _sds((_NLIST, _CAP, _D), "uint8")
+    prm = _sds((_D,), "float32")
     return [
         ((q, data, ids, li, sz, norms), dict(metric="l2", codec="f16",
                                              interpret=True)),
         ((q, data, ids, li, sz, norms), dict(metric="l2", codec="f16",
                                              scan_bf16=True, interpret=True)),
+        ((q, data8, ids, li, sz, norms, prm, prm),
+         dict(metric="l2", codec="sq8", interpret=True)),
     ]
 
 

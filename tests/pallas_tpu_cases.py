@@ -1,0 +1,107 @@
+"""Every Pallas kernel at the geometries chip_smoke.py makes it emit, as
+abstract signatures — shared by tests/test_pallas_tpu_lowering.py, which
+lowers each for the ``tpu`` platform in-process, and by this file run as a
+script, which compiles each for a v5e with no chip attached (libtpu's
+compile-only topology) and prints one JSON line per case.
+
+Lowering catches what Pallas refuses (casts, block shapes); only the
+compile shows what Mosaic and XLA:TPU refuse — scoped VMEM, SMEM, vector
+load types — which is where every kernel repair of the chip bring-up was.
+"""
+
+import json
+import sys
+
+import jax
+import jax.numpy as jnp
+
+from distributed_faiss_tpu.ops import adc_pallas, flat_pallas
+
+_FLAT_DTYPES = {"f32": "float32", "f16": "float16", "sq8": "uint8"}
+
+
+def _flat(codec, metric, d, nq, g, cap, nlist=1024, scan_bf16=False):
+    def sig(sds):
+        prm = sds((d,), "float32") if codec == "sq8" else None
+        args = (sds((nq, d), "float32"), sds((nlist, cap, d), _FLAT_DTYPES[codec]),
+                sds((nlist, cap), "int32"), sds((nq, g), "int32"),
+                sds((nq, g), "int32"),
+                sds((nlist, cap), "float32") if metric == "l2" else None,
+                prm, prm)
+        return args, dict(metric=metric, codec=codec, scan_bf16=scan_bf16,
+                          interpret=False)
+
+    name = f"flat {codec} {metric} d={d} nq={nq} g={g} cap={cap}"
+    return name + (" bf16" if scan_bf16 else ""), flat_pallas.flat_list_scan_pallas, sig
+
+
+def _adc(kind, m, lut_dtype, nq, L):
+    fn = {"shared": adc_pallas.adc_scan_shared_pallas,
+          "onehot": adc_pallas.adc_scan_pallas,
+          "nibble": adc_pallas.adc_scan_pallas_nibble}[kind]
+
+    def sig(sds):
+        codes = (L, m) if kind == "shared" else (nq, L, m)
+        return ((sds((nq, m, 256), lut_dtype), sds(codes, "uint8")),
+                dict(interpret=False))
+
+    return f"adc {kind} m={m} {lut_dtype} nq={nq} L={L}", fn, sig
+
+
+def cases():
+    """[(name, jitted kernel entry, sig(sds) -> (args, kwargs))]."""
+    out = []
+    # ivfsq / IVF1024,SQ8 at dim 512 (cap 256-512, one probe per group), the
+    # f32 codec beside them, and the bf16 scan mode
+    for codec in ("f32", "f16", "sq8"):
+        for metric in ("l2", "dot"):
+            out.append(_flat(codec, metric, 512, 256, 1, 512))
+    out.append(_flat("f16", "l2", 512, 256, 1, 512, scan_bf16=True))
+    out.append(_flat("f16", "l2", 512, 8, 1, 256))  # a single-query bucket
+    # the widest scalar prefetch the block picker can ask for: 1024 queries
+    # x 8 probes (ivf_simple width) — two of these must fit SMEM
+    out.append(_flat("f32", "l2", 128, 1024, 8, 1024))
+    # knnlm: 64 x 8-bit codes, lists of 512, a 1024-query block of LUTs; and
+    # the smallest nibble-eligible m
+    for lut in ("float32", "bfloat16"):
+        out.append(_adc("nibble", 64, lut, 1024, 512))
+        out.append(_adc("onehot", 64, lut, 1024, 512))
+        out.append(_adc("shared", 64, lut, 32, 4096))
+        for kind in ("nibble", "onehot", "shared"):
+            out.append(_adc(kind, 8, lut, 64, 1024))
+    return out
+
+
+def lower_for_tpu(fn, sig, sds):
+    args, kwargs = sig(sds)
+    return fn.trace(*args, **kwargs).lower(lowering_platforms=("tpu",))
+
+
+def main():
+    """Compile every case for a v5e from its topology description alone."""
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+
+    try:
+        topo = topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
+    except Exception as e:  # no libtpu here: the caller skips
+        print(json.dumps({"unavailable": f"{type(e).__name__}: {e}"[:300]}))
+        return 0
+    sharding = SingleDeviceSharding(topo.devices[0])
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, jnp.dtype(dtype), sharding=sharding)
+
+    for name, fn, sig in cases():
+        try:
+            lower_for_tpu(fn, sig, sds).compile()
+            print(json.dumps({"case": name, "ok": True}), flush=True)
+        except Exception as e:
+            print(json.dumps({"case": name, "ok": False,
+                              "error": f"{type(e).__name__}: {e}"[:600]}),
+                  flush=True)
+    return 0
+
+
+if __name__ == "__main__":
+    sys.exit(main())

@@ -270,7 +270,7 @@ def test_factory_strings():
 
 def test_pick_query_block_budget():
     # tiny payload -> max block; the headline config (cap=512, d=128 fp32
-    # gather) must allow the full 1024 block the relay-latency fix relies on
+    # gather) must allow the full 1024 block
     assert base.pick_query_block(512 * 128 * 4) == base.MAX_QUERY_BLOCK
     # 4 MB/query (ivf_simple's huge-cap lists) -> pinned at the 256 floor
     assert base.pick_query_block(8192 * 128 * 4) == 256

@@ -82,7 +82,7 @@ def _buckets(row):
 
 def _closed_jaxprs_in(value):
     """ClosedJaxprs nested in an eqn param value (lists/tuples included)."""
-    import jax.core as jcore
+    import jax.extend.core as jcore
 
     if isinstance(value, jcore.ClosedJaxpr):
         yield value
@@ -92,7 +92,7 @@ def _closed_jaxprs_in(value):
 
 
 def _jaxprs_in(value):
-    import jax.core as jcore
+    import jax.extend.core as jcore
 
     if isinstance(value, jcore.Jaxpr):
         yield value
@@ -124,12 +124,9 @@ def _def_site(fn):
 def _eqn_site(eqn, default_path, default_line):
     """Repo-relative (path, line) of the user frame that built this eqn,
     falling back to the entry's def site for jax-internal frames."""
-    try:
-        from jax._src import source_info_util
+    from jax._src import source_info_util
 
-        frame = source_info_util.user_frame(eqn.source_info)
-    except Exception:
-        frame = None
+    frame = source_info_util.user_frame(eqn.source_info.traceback)
     if frame is not None:
         fname = getattr(frame, "file_name", None)
         line = getattr(frame, "start_line", None)
