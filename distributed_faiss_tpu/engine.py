@@ -36,11 +36,16 @@ from distributed_faiss_tpu.models.factory import (
     remove_rows_unsupported,
 )
 from distributed_faiss_tpu.mutation import compaction as _compaction
-from distributed_faiss_tpu.observability import spans as obs_spans
 from distributed_faiss_tpu.mutation import tombstones as _tombstones
 from distributed_faiss_tpu.mutation import versions as _versions
 from distributed_faiss_tpu.mutation.tombstones import TombstoneSet
-from distributed_faiss_tpu.utils import envutil, lockdep, serialization, xfercheck
+from distributed_faiss_tpu.utils import (
+    envutil,
+    lockdep,
+    serialization,
+    tracing,
+    xfercheck,
+)
 from distributed_faiss_tpu.utils.batching import SearchBatcher
 from distributed_faiss_tpu.utils.config import (
     IndexCfg,
@@ -289,12 +294,10 @@ class Index:
         # the server's get_perf_stats "engine" key — lets operators read
         # wire round-trip (client rpc stats), queue wait (scheduler), and
         # device time side by side when tuning pipelining depth
+        # — and the sink of this engine's stages (utils/tracing.stage:
+        # lock wait, launch, join, train, buffer drain; the model's feed /
+        # scan / refine_fetch stages inherit it through the context)
         self.perf = LatencyStats()
-        # distributed-tracing span ring (observability/spans.py): the
-        # owning server wires its SpanBuffer in (_wire_engine) so a
-        # sampled launch records an ``engine.launch`` span; standalone
-        # engines stay None and record nothing
-        self.span_buffer = None
         # newest committed snapshot generation in this shard's storage dir
         # (0 = nothing committed yet; from_storage_dir seeds it on restore)
         self._generation = 0
@@ -1208,7 +1211,7 @@ class Index:
         storage_dir = self.cfg.index_storage_dir
         if not storage_dir:
             return False
-        t0 = time.perf_counter()
+        t0 = tracing.now()
         with self.buffer_lock, self.index_lock:
             if self.tpu_index is None or self.state != IndexState.TRAINED:
                 return False
@@ -1303,8 +1306,8 @@ class Index:
             self.index_save_time = time.time()
             self._meta_epoch += 1  # in-flight joins retry on the new layout
             self._mutation_counters["compactions"] += 1
-        dt = time.perf_counter() - t0
-        self.perf.record("compaction_s", dt)
+        dt = tracing.book("engine.compaction", t0, sink=self.perf,
+                          counter="compaction_s")
         logger.info(
             "compacted %d tombstoned rows out (%d -> %d live) into "
             "generation %d in %.3fs", n0 - new_n, n0, new_n, gen, dt)
@@ -1340,7 +1343,8 @@ class Index:
                 return
             self.state = IndexState.TRAINING
         try:
-            self._train_impl()
+            with tracing.stage("engine.train", sink=self.perf):
+                self._train_impl()
         except BaseException:
             # conscious fix vs the reference: a failed (possibly async)
             # training run must not wedge the shard in TRAINING forever —
@@ -1448,40 +1452,43 @@ class Index:
 
             if taken_rows == 0:
                 break
-            add_data = np.concatenate(chunks, axis=0)
-            start_time = time.time()
-            with self.index_lock:
-                if self.state != IndexState.ADD or self.tpu_index is None:
-                    # a concurrent drop_index tore the index down mid-add:
-                    # bail without resetting state (drop already set it)
-                    logger.info("add worker: index dropped mid-add, exiting")
-                    return
-                self.tpu_index.add(add_data)
-                ntotal = self.tpu_index.ntotal
-                # buffer-aware deletes: rows tombstoned while they were
-                # still buffered keep their positional slot (the metadata
-                # join is positional), so they are added like any row and
-                # masked immediately — under the SAME lock hold, so no
-                # search window can see them live
-                dead_new = self.tombstones.rows_in_range(
-                    ntotal - add_data.shape[0], ntotal)
-                if dead_new:
-                    # unreachable for unsupported kinds (remove_ids rejects
-                    # them up front, so tombstones only exist on maskable
-                    # indexes) — but a mask failure here must never kill
-                    # the drain worker: that would wedge the engine in ADD
-                    # and every search would fail over around it forever
-                    try:
-                        # graftlint: ok(blocking-under-lock): the locked mask scatter is the tombstone consistency contract — device mutations serialize on index_lock like every launch
-                        self.tpu_index.remove_rows(
-                            np.asarray(dead_new, np.int64))
-                    except Exception:
-                        logger.exception(
-                            "drain-time tombstone mask failed for rows %s "
-                            "— rows serve until compaction", dead_new)
+            # one booking a drained chunk: concat, the wait for index_lock
+            # and the device add (set-up's ingest work, where it happens)
+            with tracing.stage("engine.add_drain", sink=self.perf) as drain:
+                add_data = np.concatenate(chunks, axis=0)
+                with self.index_lock:
+                    if self.state != IndexState.ADD or self.tpu_index is None:
+                        # a concurrent drop_index tore the index down mid-add:
+                        # bail without resetting state (drop already set it)
+                        logger.info("add worker: index dropped mid-add, exiting")
+                        return
+                    self.tpu_index.add(add_data)
+                    ntotal = self.tpu_index.ntotal
+                    # buffer-aware deletes: rows tombstoned while they were
+                    # still buffered keep their positional slot (the metadata
+                    # join is positional), so they are added like any row and
+                    # masked immediately — under the SAME lock hold, so no
+                    # search window can see them live
+                    dead_new = self.tombstones.rows_in_range(
+                        ntotal - add_data.shape[0], ntotal)
+                    if dead_new:
+                        # unreachable for unsupported kinds (remove_ids rejects
+                        # them up front, so tombstones only exist on maskable
+                        # indexes) — but a mask failure here must never kill
+                        # the drain worker: that would wedge the engine in ADD
+                        # and every search would fail over around it forever
+                        try:
+                            # graftlint: ok(blocking-under-lock): the locked mask scatter is the tombstone consistency contract — device mutations serialize on index_lock like every launch
+                            self.tpu_index.remove_rows(
+                                np.asarray(dead_new, np.int64))
+                        except Exception:
+                            logger.exception(
+                                "drain-time tombstone mask failed for rows %s "
+                                "— rows serve until compaction", dead_new)
+            self.perf.record("engine.add_drain_rows", float(add_data.shape[0]))
             logger.info(
                 "added %d vectors in %.3fs (ntotal=%d)",
-                add_data.shape[0], time.time() - start_time, ntotal,
+                add_data.shape[0], drain.dt, ntotal,
             )
             self._maybe_save(ignore_time=False)
 
@@ -1512,35 +1519,31 @@ class Index:
         ``launches`` dispatch counter get it diffed around the call into
         ``device_launches`` (dispatches this window took — 1.0 on the mesh
         path) and ``rows_per_launch`` (merged-window occupancy per
-        dispatch), both served through ``perf_stats``."""
-        with self.index_lock:
+        dispatch), both served through ``perf_stats``. Stages
+        (utils/tracing.stage): ``engine.lock_wait``, then ``engine.launch``
+        (counter ``device_search_s``) from launch to fetch."""
+        with tracing.stage("engine.lock_wait", sink=self.perf) as wait, \
+                self.index_lock:
+            wait.done()
             if self.state != IndexState.TRAINED:
                 raise RuntimeError(
                     NOT_TRAINED_REJECTION_FMT.format(state=self.state))
-            # sampled-trace handoff from the scheduler's batcher thread
-            # (observability/spans.py): one TLS read when a buffer is
-            # wired, nothing at all otherwise
-            trace_id = (obs_spans.current_trace()
-                        if self.span_buffer is not None else None)
             launches0 = getattr(self.tpu_index, "launches", None)
-            w0 = time.time() if trace_id is not None else 0.0
-            t0 = time.perf_counter()
-            out = self.tpu_index.search_batched(query_batch, top_k)
-            dt = time.perf_counter() - t0
-            self.perf.record("device_search_s", dt, exemplar=trace_id)
-            self.perf.record("device_search_rows", float(query_batch.shape[0]))
-            launches = None
-            if launches0 is not None:
-                launches = self.tpu_index.launches - launches0
+            rows = int(query_batch.shape[0])
+            # launch to fetch: the model's engine.feed / engine.scan /
+            # engine.refine_fetch stages nest inside and add up to it
+            with tracing.stage("engine.launch", sink=self.perf,
+                               counter="device_search_s", rows=rows) as launch:
+                out = self.tpu_index.search_batched(query_batch, top_k)
+                launches = None
+                if launches0 is not None:
+                    launches = launch.extra["launches"] = int(
+                        self.tpu_index.launches - launches0)
+            self.perf.record("device_search_rows", float(rows))
+            if launches is not None:
                 self.perf.record("device_launches", float(launches))
                 if launches > 0:
-                    self.perf.record(
-                        "rows_per_launch", query_batch.shape[0] / launches)
-            if trace_id is not None:
-                self.span_buffer.record(
-                    trace_id, "engine.launch", w0, dt,
-                    rows=int(query_batch.shape[0]),
-                    launches=None if launches is None else int(launches))
+                    self.perf.record("rows_per_launch", rows / launches)
             return out
 
     def _run_and_join(self, run, return_embeddings: bool):
@@ -1561,12 +1564,13 @@ class Index:
             # fetch scopes down in the blocked-search drivers
             with xfercheck.guarded("engine launch-to-fetch span"):
                 scores, indexes, embs_arr = run()
-            with self.buffer_lock:
-                if self._meta_epoch != epoch0:
-                    continue  # layout swapped mid-flight: retry on the new one
-                meta_arr, meta_n = self.id_to_metadata.snapshot()
-            return self._join_results(scores, indexes, embs_arr,
-                                      return_embeddings, meta_arr, meta_n)
+            with tracing.stage("engine.join", sink=self.perf):
+                with self.buffer_lock:
+                    if self._meta_epoch != epoch0:
+                        continue  # layout swapped mid-flight: retry on the new one
+                    meta_arr, meta_n = self.id_to_metadata.snapshot()
+                return self._join_results(scores, indexes, embs_arr,
+                                          return_embeddings, meta_arr, meta_n)
         raise RuntimeError(
             "metadata layout kept changing during search (compaction storm)")
 
@@ -1701,10 +1705,9 @@ class Index:
             if self.state != IndexState.TRAINED:
                 raise RuntimeError(
                     NOT_TRAINED_REJECTION_FMT.format(state=self.state))
-            t0 = time.perf_counter()
-            scores, indexes = self.tpu_index.search(query_batch, top_k)
-            self.perf.record("reconstruct_search_s",
-                             time.perf_counter() - t0)
+            with tracing.stage("engine.reconstruct_search", sink=self.perf,
+                               counter="reconstruct_search_s"):
+                scores, indexes = self.tpu_index.search(query_batch, top_k)
             flat = indexes.reshape(-1)
             if self.tpu_index.ntotal == 0:
                 # trained-but-empty window: all ids are -1
@@ -1748,11 +1751,16 @@ class Index:
         return scores, results_meta, embs
 
     def perf_stats(self, raw: bool = False) -> dict:
-        """Per-index device-launch latency summary: ``device_search_s``
-        (wall time of each locked launch), ``device_search_rows`` (rows per
-        merged window — the "_s" suffix on summary keys is historical;
-        these are counts), ``reconstruct_search_s`` (search+reconstruct
-        launches); for mesh-backed indexes additionally
+        """Per-index stage and launch summary: ``device_search_s`` (wall
+        time of each locked launch), ``device_search_rows`` (rows per
+        merged window) and the stages that make the launch up
+        (``engine.feed``, ``engine.scan``, ``engine.refine_fetch``) or
+        surround it (``engine.lock_wait``, ``engine.join``);
+        ``reconstruct_search_s`` (search+reconstruct launches);
+        ``engine.train`` and ``engine.add_drain`` / ``engine.add_drain_rows``
+        (seconds and rows of each drained buffer chunk — the "_s" suffix on
+        summary keys is historical; rows are counts); for mesh-backed
+        indexes additionally
         ``device_launches`` (device dispatches per merged window — the
         one-launch serving contract means max_s == 1.0) and
         ``rows_per_launch`` (window occupancy per dispatch). Served

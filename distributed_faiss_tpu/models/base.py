@@ -28,7 +28,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from distributed_faiss_tpu.ops import distance
-from distributed_faiss_tpu.utils import xfercheck
+from distributed_faiss_tpu.utils import tracing, xfercheck
 
 
 def _next_pow2(n: int, minimum: int) -> int:
@@ -442,17 +442,22 @@ def pick_query_block(probe_bytes_per_query: int, minimum: int = 256) -> int:
     return block
 
 
+def _padded_block(q: np.ndarray, s: int, block: int):
+    """(rows, rows padded to their jit bucket) of the block starting at s."""
+    chunk = q[s : s + block]
+    return chunk.shape[0], distance.pad_rows(
+        chunk, distance.bucket_size(chunk.shape[0]))
+
+
 def query_blocks(q: np.ndarray, block: int = 256):
     """Split a query batch into bucketed blocks to bound jit variants."""
-    nq = q.shape[0]
-    for s in range(0, nq, block):
-        chunk = q[s : s + block]
-        bucket = distance.bucket_size(chunk.shape[0])
-        yield s, chunk.shape[0], distance.pad_rows(chunk, bucket)
+    for s in range(0, q.shape[0], block):
+        n, chunk = _padded_block(q, s, block)
+        yield s, n, chunk
 
 
 def blocked_search(q: np.ndarray, k: int, metric: str, fn, block: int = 256,
-                   fused_fn=None):
+                   fused_fn=None, refine_fn=None):
     """THE blocked search driver (shared by the IVF family and the mesh
     indexes — one implementation so the bucketing/padding policy cannot
     drift between them).
@@ -479,6 +484,16 @@ def blocked_search(q: np.ndarray, k: int, metric: str, fn, block: int = 256,
     query/output padding, not a doubled working set; callers pinning
     their own batch sizes can stay at power-of-two multiples of the
     block to avoid even that.
+
+    Stage ledger (utils/tracing.stage; counters land in the caller's
+    sink, the engine's): a block is ``engine.feed`` (slice, pad,
+    ``device_put``), ``engine.scan`` (``fn`` / ``fused_fn``: dispatch of
+    the scan program up to wherever the callable itself waits — the IVF
+    family's ``pallas_guarded`` blocks until the scan is done) and
+    ``engine.refine_fetch`` (``refine_fn(block, ids)``, the exact rerank's
+    dispatch where the index refines outside the scan program, then the
+    result fetch and ``finalize_results``). The boundaries sit where the
+    host already waits: no sync is added for them.
     """
     q = np.asarray(q, np.float32)
     nq = q.shape[0]
@@ -489,18 +504,29 @@ def blocked_search(q: np.ndarray, k: int, metric: str, fn, block: int = 256,
     # at the jit boundary. (Mesh callers re-place the block onto their
     # sharding inside fn/fused_fn — also explicitly.)
     if fused_fn is not None and nq > block:
-        nblocks = _next_pow2(-(-nq // block), 1)
-        qp = np.pad(q, ((0, nblocks * block - nq), (0, 0)))
-        vals, ids = fused_fn(jax.device_put(qp.reshape(nblocks, block, -1)))
-        with xfercheck.explicit("blocked_search fused result fetch"):
-            out_s = np.asarray(vals).reshape(nblocks * block, -1)[:nq]
-            out_i = np.asarray(ids).reshape(nblocks * block, -1)[:nq].astype(np.int64)
-        return finalize_results(out_s, out_i, metric)
+        with tracing.stage("engine.feed"):
+            nblocks = _next_pow2(-(-nq // block), 1)
+            qp = np.pad(q, ((0, nblocks * block - nq), (0, 0)))
+            q3 = jax.device_put(qp.reshape(nblocks, block, -1))
+        with tracing.stage("engine.scan"):
+            vals, ids = fused_fn(q3)
+        with tracing.stage("engine.refine_fetch"):
+            with xfercheck.explicit("blocked_search fused result fetch"):
+                out_s = np.asarray(vals).reshape(nblocks * block, -1)[:nq]
+                out_i = np.asarray(ids).reshape(nblocks * block, -1)[:nq]
+            return finalize_results(out_s, out_i, metric)
     out_s = np.empty((nq, k), np.float32)
     out_i = np.empty((nq, k), np.int64)
-    for s, n, chunk in query_blocks(q, block):
-        vals, ids = fn(jax.device_put(chunk))
-        with xfercheck.explicit("blocked_search block result fetch"):
-            out_s[s : s + n] = np.asarray(vals)[:n]
-            out_i[s : s + n] = np.asarray(ids)[:n]
-    return finalize_results(out_s, out_i, metric)
+    for s in range(0, nq, block):
+        with tracing.stage("engine.feed"):
+            n, chunk = _padded_block(q, s, block)
+            chunk = jax.device_put(chunk)
+        with tracing.stage("engine.scan"):
+            vals, ids = fn(chunk)
+        with tracing.stage("engine.refine_fetch"):
+            if refine_fn is not None:
+                vals, ids = refine_fn(chunk, ids)
+            with xfercheck.explicit("blocked_search block result fetch"):
+                out_s[s : s + n], out_i[s : s + n] = finalize_results(
+                    np.asarray(vals)[:n], np.asarray(ids)[:n], metric)
+    return out_s, out_i

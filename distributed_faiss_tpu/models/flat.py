@@ -23,7 +23,7 @@ import numpy as np
 
 from distributed_faiss_tpu.models import base
 from distributed_faiss_tpu.ops import distance, sq
-from distributed_faiss_tpu.utils import sanitize, xfercheck
+from distributed_faiss_tpu.utils import sanitize, tracing, xfercheck
 
 _CODEC_DTYPES = {
     "f32": jnp.float32,
@@ -103,33 +103,40 @@ class FlatIndex(base.TpuIndex):
             # padded to full width — extra compute only). nblocks bucketed to
             # powers of two so variable-batch serving compiles O(log max)
             # fused variants, not one per distinct batch size
-            nblocks = base._next_pow2(-(-nq // nb), 1)
-            qp = np.pad(q, ((0, nblocks * nb - nq), (0, 0)))
-            # explicit device_put feeds: the serving path runs under
-            # DFT_XFERCHECK's transfer guard, which forbids the implicit
-            # uploads jnp.asarray/jit-dispatch would do here
-            vals, ids = sanitize.maybe_checked(
-                _flat_search_fused,
-                jax.device_put(qp.reshape(nblocks, nb, -1)), self.store.data,
-                jax.device_put(np.int32(self.store.ntotal)), k=k,
-                metric=self.metric, codec=self.codec,
-                vmin=kwargs.get("vmin"), span=kwargs.get("span"),
-                live=self.store.live,
-            )
-            with xfercheck.explicit("flat fused-search result fetch"):
-                out_s = np.asarray(vals).reshape(nblocks * nb, -1)[:nq]
-                out_i = np.asarray(ids).reshape(nblocks * nb, -1)[:nq].astype(np.int64)
-            return base.finalize_results(out_s, out_i, self.metric)
+            # (the three stages are the launch ledger's, as in
+            # base.blocked_search)
+            with tracing.stage("engine.feed"):
+                nblocks = base._next_pow2(-(-nq // nb), 1)
+                qp = np.pad(q, ((0, nblocks * nb - nq), (0, 0)))
+                # explicit device_put feeds: the serving path runs under
+                # DFT_XFERCHECK's transfer guard, which forbids the implicit
+                # uploads jnp.asarray/jit-dispatch would do here
+                q3 = jax.device_put(qp.reshape(nblocks, nb, -1))
+                ntotal = jax.device_put(np.int32(self.store.ntotal))
+            with tracing.stage("engine.scan"):
+                vals, ids = sanitize.maybe_checked(
+                    _flat_search_fused, q3, self.store.data, ntotal, k=k,
+                    metric=self.metric, codec=self.codec,
+                    vmin=kwargs.get("vmin"), span=kwargs.get("span"),
+                    live=self.store.live,
+                )
+            with tracing.stage("engine.refine_fetch"):
+                with xfercheck.explicit("flat fused-search result fetch"):
+                    out_s = np.asarray(vals).reshape(nblocks * nb, -1)[:nq]
+                    out_i = np.asarray(ids).reshape(nblocks * nb, -1)[:nq].astype(np.int64)
+                return base.finalize_results(out_s, out_i, self.metric)
         out_s = np.empty((nq, k), np.float32)
         out_i = np.empty((nq, k), np.int64)
         for s, n, block in base.query_blocks(q, nb):
-            vals, ids = distance.knn(
-                block, self.store.data, k, metric=self.metric,
-                ntotal=self.store.ntotal, live=self.store.live, **kwargs
-            )
-            with xfercheck.explicit("flat block-search result fetch"):
-                out_s[s : s + n] = np.asarray(vals)[:n]
-                out_i[s : s + n] = np.asarray(ids)[:n]
+            with tracing.stage("engine.scan"):
+                vals, ids = distance.knn(
+                    block, self.store.data, k, metric=self.metric,
+                    ntotal=self.store.ntotal, live=self.store.live, **kwargs
+                )
+            with tracing.stage("engine.refine_fetch"):
+                with xfercheck.explicit("flat block-search result fetch"):
+                    out_s[s : s + n] = np.asarray(vals)[:n]
+                    out_i[s : s + n] = np.asarray(ids)[:n]
         return base.finalize_results(out_s, out_i, self.metric)
 
     def reconstruct_batch(self, ids: np.ndarray) -> np.ndarray:

@@ -79,12 +79,13 @@ def _rerank_exact(store, q, cand_ids, k: int, metric: str):
     gathers are DMA-friendly, unlike the element gathers ADC avoids),
     rescans exactly in fp32, returns the top-k re-ordered subset.
     """
-    safe = jnp.where(cand_ids >= 0, cand_ids, 0)
-    rows = store[safe]  # (nq, R, d)
-    s = exact_candidate_scores(q, rows, metric)
-    s = jnp.where(cand_ids >= 0, s, distance.NEG_INF)
-    best, pos = jax.lax.top_k(s, k)
-    return best, jnp.take_along_axis(cand_ids, pos, axis=1)
+    with jax.named_scope("refine"):
+        safe = jnp.where(cand_ids >= 0, cand_ids, 0)
+        rows = store[safe]  # (nq, R, d)
+        s = exact_candidate_scores(q, rows, metric)
+        s = jnp.where(cand_ids >= 0, s, distance.NEG_INF)
+        best, pos = jax.lax.top_k(s, k)
+        return best, jnp.take_along_axis(cand_ids, pos, axis=1)
 
 
 def _mask_block(s, ids, sizes):
@@ -127,8 +128,9 @@ def _merge_group(carry, s, ids, k):
     (two-stage segmented top-k: width can reach g*cap ~ tens of thousands,
     where single-pass lax.top_k dominates the probe scan)."""
     best_v, best_i = carry
-    cv, cids = distance.segmented_topk_rows(s, k, ids)
-    return distance.merge_topk(best_v, best_i, cv, cids, k)
+    with jax.named_scope("merge_topk"):
+        cv, cids = distance.segmented_topk_rows(s, k, ids)
+        return distance.merge_topk(best_v, best_i, cv, cids, k)
 
 
 @functools.partial(jax.jit, static_argnames=("k", "nprobe", "g", "metric", "codec",
@@ -150,12 +152,15 @@ def _ivf_flat_search(centroids, list_data, list_ids, list_sizes, q,
     it behind refine_k_factor > 0 so final scores stay exact.
     """
     q = q.astype(jnp.float32)
-    coarse = distance.pairwise_scores(q, centroids, metric)
-    _, probes = distance.segmented_argtopk(coarse, nprobe)  # (nq, nprobe)
     nq = q.shape[0]
     cap = list_data.shape[1]
-    qn = jnp.sum(q * q, axis=1, keepdims=True)
-    groups = probes.reshape(nq, nprobe // g, g).transpose(1, 0, 2)  # (ng, nq, g)
+    # the four scopes (coarse, list_scan, merge_topk, refine) name the
+    # device's op events in a profiler trace; metadata only
+    with jax.named_scope("coarse"):
+        coarse = distance.pairwise_scores(q, centroids, metric)
+        _, probes = distance.segmented_argtopk(coarse, nprobe)  # (nq, nprobe)
+        qn = jnp.sum(q * q, axis=1, keepdims=True)
+        groups = probes.reshape(nq, nprobe // g, g).transpose(1, 0, 2)  # (ng, nq, g)
 
     init = (
         jnp.full((nq, k), distance.NEG_INF, jnp.float32),
@@ -163,37 +168,38 @@ def _ivf_flat_search(centroids, list_data, list_ids, list_sizes, q,
     )
 
     def body(carry, li):  # li: (nq, g)
-        ids = list_ids[li]  # (nq, g, cap)
-        sizes = list_sizes[li]  # (nq, g)
-        if use_pallas:
-            from distributed_faiss_tpu.ops import flat_pallas
+        with jax.named_scope("list_scan"):
+            ids = list_ids[li]  # (nq, g, cap)
+            sizes = list_sizes[li]  # (nq, g)
+            if use_pallas:
+                from distributed_faiss_tpu.ops import flat_pallas
 
-            s = flat_pallas.flat_list_scan_auto(
-                q, list_data, list_ids, li, sizes, list_norms, vmin, span,
-                metric=metric, codec=codec, scan_bf16=scan_bf16,
-            )  # (nq, g, cap), size/ids mask already applied in-kernel
-        else:
-            block = list_data[li]  # (nq, g, cap, d) storage dtype
-            if codec == "sq8":
-                block = vmin[None, None, None, :] + block.astype(jnp.float32) \
-                    * (span[None, None, None, :] / 255.0)
+                s = flat_pallas.flat_list_scan_auto(
+                    q, list_data, list_ids, li, sizes, list_norms, vmin, span,
+                    metric=metric, codec=codec, scan_bf16=scan_bf16,
+                )  # (nq, g, cap), size/ids mask already applied in-kernel
             else:
-                block = block.astype(jnp.float32)
-            if scan_bf16:
-                ip = jnp.einsum("qd,qgcd->qgc", q.astype(jnp.bfloat16),
-                                block.astype(jnp.bfloat16),
-                                preferred_element_type=jnp.float32)
-            else:
-                ip = jnp.einsum("qd,qgcd->qgc", q, block, precision=_HIGHEST,
-                                preferred_element_type=jnp.float32)
-            if metric == "dot":
-                s = ip
-            else:
-                bn = (list_norms[li] if list_norms is not None
-                      else base.row_norms_f32(block))
-                s = -(qn[:, :, None] - 2.0 * ip + bn)
-            valid = (jnp.arange(cap)[None, None, :] < sizes[:, :, None]) & (ids >= 0)
-            s = jnp.where(valid, s, distance.NEG_INF)
+                block = list_data[li]  # (nq, g, cap, d) storage dtype
+                if codec == "sq8":
+                    block = vmin[None, None, None, :] + block.astype(jnp.float32) \
+                        * (span[None, None, None, :] / 255.0)
+                else:
+                    block = block.astype(jnp.float32)
+                if scan_bf16:
+                    ip = jnp.einsum("qd,qgcd->qgc", q.astype(jnp.bfloat16),
+                                    block.astype(jnp.bfloat16),
+                                    preferred_element_type=jnp.float32)
+                else:
+                    ip = jnp.einsum("qd,qgcd->qgc", q, block, precision=_HIGHEST,
+                                    preferred_element_type=jnp.float32)
+                if metric == "dot":
+                    s = ip
+                else:
+                    bn = (list_norms[li] if list_norms is not None
+                          else base.row_norms_f32(block))
+                    s = -(qn[:, :, None] - 2.0 * ip + bn)
+                valid = (jnp.arange(cap)[None, None, :] < sizes[:, :, None]) & (ids >= 0)
+                s = jnp.where(valid, s, distance.NEG_INF)
         return _merge_group(carry, s.reshape(nq, g * cap), ids.reshape(nq, g * cap), k), None
 
     (vals, ids), _ = jax.lax.scan(body, init, groups)
@@ -206,15 +212,17 @@ def _ivf_pq_search(centroids, codebooks, list_codes, list_ids, list_sizes, q,
                    k: int, nprobe: int, g: int, metric: str,
                    use_pallas: bool = False, lut_bf16: bool = False):
     q = q.astype(jnp.float32)
-    coarse = distance.pairwise_scores(q, centroids, metric)
-    _, probes = distance.segmented_argtopk(coarse, nprobe)
     nq = q.shape[0]
     cap = list_codes.shape[1]
     m, ksub, dsub = codebooks.shape
-    groups = probes.reshape(nq, nprobe // g, g).transpose(1, 0, 2)  # (ng, nq, g)
+    with jax.named_scope("coarse"):
+        coarse = distance.pairwise_scores(q, centroids, metric)
+        _, probes = distance.segmented_argtopk(coarse, nprobe)
+        groups = probes.reshape(nq, nprobe // g, g).transpose(1, 0, 2)  # (ng, nq, g)
 
     if metric != "l2":
-        shared_lut = pq.adc_lut(q, codebooks, metric=metric)  # (nq, m, ksub)
+        with jax.named_scope("list_scan"):
+            shared_lut = pq.adc_lut(q, codebooks, metric=metric)  # (nq, m, ksub)
 
     init = (
         jnp.full((nq, k), distance.NEG_INF, jnp.float32),
@@ -222,35 +230,36 @@ def _ivf_pq_search(centroids, codebooks, list_codes, list_ids, list_sizes, q,
     )
 
     def body(carry, li):  # (nq, g)
-        codes = list_codes[li]  # (nq, g, cap, m)
-        ids = list_ids[li]
-        sizes = list_sizes[li]
-        if metric == "l2":
-            r = q[:, None, :] - centroids[li]  # (nq, g, d) residuals
-            lut = pq.adc_lut(r.reshape(nq * g, -1), codebooks, metric="l2")
-            lut = lut.reshape(nq, g, m, ksub)
-        else:
-            lut = jnp.broadcast_to(shared_lut[:, None], (nq, g, m, ksub))
-        if use_pallas:
-            # fused VMEM kernel: per-(query, probe) LUT vs its code tile.
-            # lut_bf16 halves the kernel's LUT traffic (its speed is not
-            # measured on the chip for today's code); the one-hot side is
-            # exact in bf16 and the LUT rounding (~0.4% rel) only perturbs
-            # the ADC shortlist, which refine_k_factor rescores exactly.
-            from distributed_faiss_tpu.ops import adc_pallas
+        with jax.named_scope("list_scan"):
+            codes = list_codes[li]  # (nq, g, cap, m)
+            ids = list_ids[li]
+            sizes = list_sizes[li]
+            if metric == "l2":
+                r = q[:, None, :] - centroids[li]  # (nq, g, d) residuals
+                lut = pq.adc_lut(r.reshape(nq * g, -1), codebooks, metric="l2")
+                lut = lut.reshape(nq, g, m, ksub)
+            else:
+                lut = jnp.broadcast_to(shared_lut[:, None], (nq, g, m, ksub))
+            if use_pallas:
+                # fused VMEM kernel: per-(query, probe) LUT vs its code tile.
+                # lut_bf16 halves the kernel's LUT traffic (its speed is not
+                # measured on the chip for today's code); the one-hot side is
+                # exact in bf16 and the LUT rounding (~0.4% rel) only perturbs
+                # the ADC shortlist, which refine_k_factor rescores exactly.
+                from distributed_faiss_tpu.ops import adc_pallas
 
-            s = adc_pallas.adc_scan_auto(
-                lut.reshape(nq * g, m, ksub).astype(
-                    jnp.bfloat16 if lut_bf16 else jnp.float32),
-                codes.reshape(nq * g, cap, m),
-            ).reshape(nq, g, cap)
-        else:
-            iota = jnp.arange(ksub, dtype=jnp.int32)
-            onehot = (codes[..., None].astype(jnp.int32) == iota).astype(jnp.float32)
-            s = jnp.einsum("qgmj,qgcmj->qgc", lut, onehot, precision=_HIGHEST,
-                           preferred_element_type=jnp.float32)
-        valid = (jnp.arange(cap)[None, None, :] < sizes[:, :, None]) & (ids >= 0)
-        s = jnp.where(valid, s, distance.NEG_INF)
+                s = adc_pallas.adc_scan_auto(
+                    lut.reshape(nq * g, m, ksub).astype(
+                        jnp.bfloat16 if lut_bf16 else jnp.float32),
+                    codes.reshape(nq * g, cap, m),
+                ).reshape(nq, g, cap)
+            else:
+                iota = jnp.arange(ksub, dtype=jnp.int32)
+                onehot = (codes[..., None].astype(jnp.int32) == iota).astype(jnp.float32)
+                s = jnp.einsum("qgmj,qgcmj->qgc", lut, onehot, precision=_HIGHEST,
+                               preferred_element_type=jnp.float32)
+            valid = (jnp.arange(cap)[None, None, :] < sizes[:, :, None]) & (ids >= 0)
+            s = jnp.where(valid, s, distance.NEG_INF)
         return _merge_group(carry, s.reshape(nq, g * cap), ids.reshape(nq, g * cap), k), None
 
     (vals, ids), _ = jax.lax.scan(body, init, groups)
@@ -425,13 +434,15 @@ class _IVFBase(base.TpuIndex):
         return out
 
     def _search_blocks(self, q: np.ndarray, k: int, fn, block: int = 256,
-                       fused_fn=None):
+                       fused_fn=None, refine_fn=None):
         """Blocked search driver — see ``models.base.blocked_search`` (the
         single shared implementation: one launch per block by default;
         with ``fused_fn`` a multi-block batch runs in ONE lax.map launch,
         with the pow2-bucketing and memory-cliff rationale documented
-        there)."""
-        return base.blocked_search(q, k, self.metric, fn, block, fused_fn)
+        there; ``refine_fn`` is the per-block exact rerank, dispatched
+        after ``fn``'s scan has finished)."""
+        return base.blocked_search(q, k, self.metric, fn, block, fused_fn,
+                                   refine_fn)
 
     def _empty_results(self, nq: int, k: int):
         d = np.full((nq, k), np.inf if self.metric == "l2" else -np.inf, np.float32)
@@ -631,11 +642,11 @@ class IVFFlatIndex(_IVFBase):
             self._validate_flat_pallas(scan)
 
         def run(b):
-            vals, ids = pallas_guarded(
+            return pallas_guarded(
                 self, lambda p: scan(b, p), 0, 0, shape=tuple(b.shape))
-            if self.refine_k_factor:
-                vals, ids = _rerank_exact(self.refine_store.data, b, ids, k, self.metric)
-            return vals, ids
+
+        def refine(b, ids):
+            return _rerank_exact(self.refine_store.data, b, ids, k, self.metric)
 
         def run_fused(q3):
             return pallas_guarded(
@@ -652,7 +663,9 @@ class IVFFlatIndex(_IVFBase):
                 0, 0, shape=tuple(q3.shape),
             )
 
-        return self._search_blocks(q, k, run, block=nb, fused_fn=run_fused)
+        return self._search_blocks(
+            q, k, run, block=nb, fused_fn=run_fused,
+            refine_fn=refine if self.refine_k_factor else None)
 
     def reconstruct_batch(self, ids: np.ndarray) -> np.ndarray:
         rows = self._device_rows(ids)
@@ -1019,13 +1032,13 @@ class IVFPQIndex(_IVFBase):
             )
 
         def run(b):
-            vals, ids = pallas_guarded(
+            return pallas_guarded(
                 self, lambda p: adc(b, p), self.m, self.codebooks.shape[1],
                 shape=tuple(b.shape),
             )
-            if self.refine_k_factor:
-                vals, ids = _rerank_exact(self.refine_store.data, b, ids, k, self.metric)
-            return vals, ids
+
+        def refine(b, ids):
+            return _rerank_exact(self.refine_store.data, b, ids, k, self.metric)
 
         def adc_fused(q3, with_pallas):
             return sanitize.maybe_checked(
@@ -1046,7 +1059,9 @@ class IVFPQIndex(_IVFBase):
                 shape=tuple(q3.shape),
             )
 
-        return self._search_blocks(q, k, run, block=nb, fused_fn=run_fused)
+        return self._search_blocks(
+            q, k, run, block=nb, fused_fn=run_fused,
+            refine_fn=refine if self.refine_k_factor else None)
 
     def reconstruct_batch(self, ids: np.ndarray) -> np.ndarray:
         ids = np.asarray(ids, np.int64)

@@ -24,7 +24,6 @@ from distributed_faiss_tpu.observability.export import (  # noqa: F401
 )
 from distributed_faiss_tpu.observability.spans import (  # noqa: F401
     SpanBuffer,
-    current_trace,
     local_buffer,
     maybe_sample,
     mint_trace_id,

@@ -23,6 +23,13 @@ timeline — offset, duration, stage, rank, and the stage's extras
 request paid the p99" answer the cumulative counters cannot give.
 Trace ids come from the ``p99_exemplar`` fields in the stats view (or
 any sampled client's logs).
+
+``--profile <seconds>`` asks every rank to run a profiler session on
+itself for that long (the ``profile`` op, observability/profile.py) and
+prints each rank's reduction: device busy and idle seconds, the idle
+seconds by the host stage that was open meanwhile, and device seconds by
+named scope. ``--keep`` leaves each rank's ``.xplane.pb`` in its storage
+directory and prints where.
 """
 
 import argparse
@@ -219,6 +226,47 @@ def fetch_trace(stubs, trace_id: str, pool: ThreadPoolExecutor) -> list:
     return obs_spans.merge_timelines(*per_rank)
 
 
+def fetch_profiles(stubs, seconds: float, keep: bool,
+                   pool: ThreadPoolExecutor) -> list:
+    """Every reachable rank profiles itself for ``seconds``, all at once
+    (one rank's window says little about a fan-out)."""
+
+    def one(entry):
+        stub = _stub_of(entry)
+        if stub is None:
+            return {"error": "unreachable"}
+        try:
+            return stub.generic_fun("profile", (seconds, keep),
+                                    timeout=seconds + 120.0)
+        except rpc.RETRYABLE_ERRORS + (rpc.ServerException,) as e:
+            return {"error": f"{type(e).__name__}: {e}".strip()[-300:]}
+
+    return list(pool.map(one, stubs))
+
+
+def render_profiles(profiles: list, as_json: bool) -> str:
+    if as_json:
+        return json.dumps({"ranks": profiles}, default=str)
+    lines = []
+    for rank, p in enumerate(profiles):
+        if "error" in p:
+            lines.append(f"rank {rank}: no profile: {p['error']}")
+            continue
+        lines.append(
+            f"rank {rank}: window {p['window_s']:.3f} s, device busy "
+            f"{p['busy_s']:.3f} s, idle {p['idle_s']:.3f} s "
+            f"({100 * p['idle_attributed_share']:.1f}% of it on a named "
+            f"host stage); {p['ops_with_scope']} of {p['ops']} device "
+            f"operations carry a scope")
+        lines.append("  idle seconds by host stage:")
+        lines += [f"    {s:>10.4f}  {n}" for n, s in p["idle_by_stage"]]
+        lines.append("  device seconds by scope (else HLO name):")
+        lines += [f"    {s:>10.4f}  {n}" for n, s in p["device_by_scope"]]
+        if p.get("xplane"):
+            lines.append(f"  trace kept at {p['xplane']}")
+    return "\n".join(lines)
+
+
 def main(argv=None, out=None) -> int:
     out = sys.stdout if out is None else out
     parser = argparse.ArgumentParser(
@@ -237,11 +285,21 @@ def main(argv=None, out=None) -> int:
     parser.add_argument("--trace", default=None, metavar="TRACE_ID",
                         help="print the merged span timeline for one "
                              "sampled request instead of the stats view")
+    parser.add_argument("--profile", type=float, default=None,
+                        metavar="SECONDS",
+                        help="have every rank profile itself for SECONDS "
+                             "and print device idle by host stage")
+    parser.add_argument("--keep", action="store_true",
+                        help="with --profile: keep each rank's .xplane.pb")
     args = parser.parse_args(argv)
 
     stubs = _connect(args.discovery)
     pool = _fanout_pool(stubs)
     try:
+        if args.profile is not None:
+            profiles = fetch_profiles(stubs, args.profile, args.keep, pool)
+            print(render_profiles(profiles, args.json), file=out)
+            return 0 if all("error" not in p for p in profiles) else 1
         if args.trace is not None:
             spans = fetch_trace(stubs, args.trace, pool)
             print(render_trace(spans, args.trace, args.json), file=out)

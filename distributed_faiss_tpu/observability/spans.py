@@ -2,11 +2,12 @@
 
 One sampled request = one ``trace_id`` minted client-side
 (``maybe_sample``) that rides the CALL frame's optional meta element
-beside ``req_id``/``deadline_s`` (parallel/rpc.py). Every stage that
-touches the request — client pack/round-trip, server queue wait, batch
-coalesce, device launch, failover hop, response write — records a span
-into its OWN process's bounded ``SpanBuffer``; nothing is pushed
-anywhere. The buffers are pulled lazily over the ordinary
+beside ``req_id``/``deadline_s`` (parallel/rpc.py), with the id of the
+caller's span as ``parent``. Every stage that touches the request books
+itself through ``utils/tracing.stage`` (the stage ledger,
+docs/OPERATIONS.md#stage-ledger), which records a span — ``span_id``,
+``parent``, name, start, duration — into its OWN process's bounded
+``SpanBuffer``; nothing is pushed anywhere. The buffers are pulled lazily over the ordinary
 ``get_trace_spans`` RPC op (server.py) and merged client-side
 (``IndexClient.get_trace_spans`` / the dfstat ``--trace`` view) into one
 causal timeline.
@@ -16,8 +17,9 @@ Design constraints (the reason this module is this small):
 - **byte-identical and near-zero-cost when off.** ``DFT_TRACE_SAMPLE``
   defaults to 0: ``maybe_sample`` returns None after one env read, no
   trace key enters any frame meta (legacy 3-tuple frames and pre-trace
-  peers interop unchanged), and every recording site is gated on
-  ``trace_id is not None`` — the serving path's frames stay
+  peers interop unchanged), and the one recording site
+  (``tracing.stage``) is gated on the thread's context holding a trace
+  — the serving path's frames stay
   byte-identical to the pre-trace wire (tested in
   tests/test_observability.py).
 - **spans are plain dicts.** They cross the wire through the normal
@@ -25,8 +27,9 @@ Design constraints (the reason this module is this small):
   into JSON unmodified.
 - **wall-clock starts, monotonic durations.** ``start_s`` is
   ``time.time()`` so spans from different processes land on one
-  timeline; ``dur_s`` should be measured with a monotonic clock by the
-  recorder. Cross-HOST skew shifts a rank's spans as a block — the
+  timeline — the clock the profiler's host events are stamped with
+  too (tests/test_stage_ledger.py) — and ``dur_s`` is measured with a
+  monotonic clock by the recorder. Cross-HOST skew shifts a rank's spans as a block — the
   within-rank causality (queue -> coalesce -> launch) is exact, which is
   what stage attribution needs.
 """
@@ -39,8 +42,11 @@ from typing import Optional
 
 from distributed_faiss_tpu.utils import envutil, lockdep
 
-# the CALL-frame meta key a trace rides under (beside req_id/deadline_s)
+# the CALL-frame meta keys a sampled trace rides under (beside
+# req_id/deadline_s): the request's id, and the id of the caller's span
+# that the rank's spans hang under
 TRACE_META_KEY = "trace_id"
+PARENT_META_KEY = "parent"
 
 DEFAULT_BUFFER = 2048
 
@@ -93,12 +99,17 @@ class SpanBuffer:
         self._counters = {"recorded": 0, "evicted": 0}
 
     def record(self, trace_id: str, name: str, start_s: float, dur_s: float,
+               span_id: Optional[str] = None, parent: Optional[str] = None,
                **extra) -> None:
         """Append one span. ``start_s`` is wall-clock (time.time());
-        ``dur_s`` a monotonic-clock duration. ``extra`` must stay
-        wire-safe (scalars/containers — it rides the frame skeleton)."""
+        ``dur_s`` a monotonic-clock duration; ``span_id`` names this span
+        and ``parent`` the span that caused it (None: the request's root).
+        ``extra`` must stay wire-safe (scalars/containers — it rides the
+        frame skeleton)."""
         span = {
             "trace_id": trace_id,
+            "span_id": span_id,
+            "parent": parent,
             "name": name,
             "start_s": float(start_s),
             "dur_s": float(dur_s),
@@ -152,26 +163,6 @@ def local_buffer() -> SpanBuffer:
         return _local
 
 
-# -------------------------------------------------------- launch trace handoff
-#
-# The scheduler's batcher thread calls the engine through a fixed
-# search_fn signature; a thread-local carries the representative sampled
-# trace_id of the window being launched so Index._device_search can
-# record its device span (riding the existing device_launches counters)
-# without a signature change through three layers. One TLS getattr per
-# launch when tracing is off.
-
-_TLS = threading.local()
-
-
-def set_current_trace(trace_id: Optional[str]) -> None:
-    _TLS.trace_id = trace_id
-
-
-def current_trace() -> Optional[str]:
-    return getattr(_TLS, "trace_id", None)
-
-
 def merge_timelines(*span_lists) -> list:
     """Merge per-process span lists into one timeline: dedupe exact
     duplicates (a loopback process fetching its own buffer sees each
@@ -182,8 +173,8 @@ def merge_timelines(*span_lists) -> list:
     merged = []
     for spans in span_lists:
         for s in spans or ():
-            key = (s.get("trace_id"), s.get("name"), s.get("rank"),
-                   s.get("start_s"), s.get("dur_s"))
+            key = (s.get("trace_id"), s.get("span_id"), s.get("name"),
+                   s.get("rank"), s.get("start_s"), s.get("dur_s"))
             if key in seen:
                 continue
             seen.add(key)

@@ -50,7 +50,7 @@ import numpy as np
 from distributed_faiss_tpu.mutation import versions as _versions
 from distributed_faiss_tpu.observability import spans as obs_spans
 from distributed_faiss_tpu.parallel import replication, rpc
-from distributed_faiss_tpu.utils import envutil, lockdep
+from distributed_faiss_tpu.utils import envutil, lockdep, tracing
 from distributed_faiss_tpu.utils.atomics import AtomicCounters
 from distributed_faiss_tpu.utils.config import (
     IndexCfg,
@@ -220,6 +220,9 @@ class IndexClient:
         # stats lock, and stats readers get a torn-free snapshot
         self.counters = AtomicCounters(
             ("reroutes", "failovers", "under_replicated", "quorum_failures"))
+        # this client's own stages (utils/tracing.stage): client.search,
+        # client.fanout_wait, client.merge — get_perf_stats' "client" key
+        self.stats = tracing.LatencyStats()
         # replica-group membership: logical shard group -> stub positions
         # (R=1 degenerates to one group per rank — the pre-replication
         # topology). Built from each rank's registered shard_group with a
@@ -1044,18 +1047,35 @@ class IndexClient:
         Tracing (observability/): ``trace_id`` pins this search to an
         explicit distributed trace; by default each call samples one via
         ``DFT_TRACE_SAMPLE`` (0 = never — the frames stay byte-identical
-        to the pre-trace wire). A traced search records the whole-fan-out
-        ``client.search`` span and a ``client.failover`` span per failed
-        replica hop into the process-local SpanBuffer, and the id rides
-        every per-rank frame so the servers' stages attribute their
-        spans to it — fetch the merged timeline with
-        ``get_trace_spans(trace_id)``.
+        to the pre-trace wire). The stages (utils/tracing.stage:
+        ``client.search`` whole call, ``client.fanout_wait`` submission to
+        a fan-out worker taking the per-rank call, ``client.merge``; the
+        stubs add pack / send / round trip) always land in this client's
+        counters (``get_perf_stats``: the ``client`` key); a traced search
+        also records them as spans — ``client.search`` the root, a
+        ``client.failover`` span per failed replica hop — into the
+        process-local SpanBuffer, and the id rides every per-rank frame so
+        the servers' stages hang their spans under it — fetch the merged
+        timeline with ``get_trace_spans(trace_id)``.
         """
-        q_size = query.shape[0]
         if trace_id is None:
             trace_id = obs_spans.maybe_sample()
-        fanout_w0 = time.time() if trace_id is not None else 0.0
-        fanout_p0 = time.perf_counter()
+        # the request's root span; everything booked below — here, on the
+        # fan-out workers, in the stubs and (through the frame meta) on the
+        # ranks — hangs under it
+        with tracing.bind(trace_id and (trace_id, None, None)), \
+                tracing.stage("client.search", sink=self.stats,
+                              index_id=index_id, rows=int(query.shape[0]),
+                              topk=int(topk)):
+            return self._search_fanout(
+                query, topk, index_id, return_embeddings, allow_partial,
+                partial_timeout, deadline, min_version, read_your_writes)
+
+    def _search_fanout(self, query, topk, index_id, return_embeddings,
+                       allow_partial, partial_timeout, deadline, min_version,
+                       read_your_writes) -> tuple:
+        """``search``'s body, inside its ``client.search`` stage."""
+        q_size = query.shape[0]
         if read_your_writes:
             own = self.last_write_version(index_id)
             if min_version is None or _versions.compare(own, min_version) > 0:
@@ -1096,7 +1116,6 @@ class IndexClient:
                 (rpc.BusyError,), abs_deadline, idx.generic_fun,
                 "search", (index_id, query, topk, return_embeddings),
                 search_kwargs, timeout=timeout, deadline=abs_deadline,
-                trace_id=trace_id,
             )
 
         def note_failover(group, pos):
@@ -1104,23 +1123,25 @@ class IndexClient:
             with self._stats_lock:
                 self._preferred[group] = pos
 
-        def note_hop(group, idx, error, att_w0, att_p0):
+        def note_hop(group, idx, error, att_p0):
             """Span for a failed replica attempt (the failover hop a
             merged timeline must show: which replica burned how much of
-            the budget before the group moved on). Wall-clock start,
-            monotonic duration — the spans-module contract."""
-            if trace_id is not None:
-                obs_spans.local_buffer().record(
-                    trace_id, "client.failover", att_w0,
-                    time.perf_counter() - att_p0, group=group,
-                    replica=idx.id, error=type(error).__name__)
+            the budget before the group moved on); no counter — the
+            ``failovers`` count is the rate."""
+            tracing.book("client.failover", att_p0, group=group,
+                         replica=idx.id, error=type(error).__name__)
 
-        def record_fanout():
-            if trace_id is not None:
-                obs_spans.local_buffer().record(
-                    trace_id, "client.search", fanout_w0,
-                    time.perf_counter() - fanout_p0, index_id=index_id,
-                    groups=len(plan), rows=int(q_size), topk=int(topk))
+        # the fan-out workers work for the caller's request: its trace,
+        # and the wait from this submission to a worker taking the call
+        ticket, fan_t0 = tracing.ticket(), tracing.now()
+
+        def on_worker(one):
+            def run(item):
+                with tracing.bind(ticket):
+                    tracing.book("client.fanout_wait", fan_t0,
+                                 sink=self.stats, group=item[0])
+                    return one(item)
+            return run
 
         if not allow_partial:
             # strict mode: a group with NO serving replica raises (the
@@ -1132,8 +1153,7 @@ class IndexClient:
                 last = None
                 for i, pos in enumerate(ordering):
                     idx = self.sub_indexes[pos]
-                    att_w0 = time.time() if trace_id is not None else 0.0
-                    att_p0 = time.perf_counter()
+                    att_p0 = tracing.now()
                     try:
                         out = call_stub(idx)
                     except rpc.TRANSPORT_ERRORS + (rpc.BusyError,) as e:
@@ -1141,7 +1161,7 @@ class IndexClient:
                             "replica %s (%s:%s) of group %s failed during "
                             "search, failing over: %s",
                             idx.id, idx.host, idx.port, group, e)
-                        note_hop(group, idx, e, att_w0, att_p0)
+                        note_hop(group, idx, e, att_p0)
                         last = e
                         continue
                     except rpc.ServerException as e:
@@ -1160,7 +1180,7 @@ class IndexClient:
                                 "replica %s of group %s cannot serve this "
                                 "search yet (%s); failing over to a peer",
                                 idx.id, group, e)
-                            note_hop(group, idx, e, att_w0, att_p0)
+                            note_hop(group, idx, e, att_p0)
                             last = e
                             continue
                         raise
@@ -1169,12 +1189,13 @@ class IndexClient:
                     return out
                 raise last
 
-            results = self.pool.map(one_strict, plan)
-            merged = IndexClient._aggregate_results(
-                results, topk, q_size, maximize_metric, return_embeddings
-            )
-            record_fanout()
-            return merged
+            # (a list: the merge stage below must not hold the wait for
+            # the ranks, which the stubs' round trips book)
+            results = list(self.pool.map(on_worker(one_strict), plan))
+            with tracing.stage("client.merge", sink=self.stats):
+                return IndexClient._aggregate_results(
+                    results, topk, q_size, maximize_metric, return_embeddings
+                )
 
         # partial mode: a group whose EVERY replica is transport-dead (or
         # still BUSY after the retry budget / past its deadline — alive
@@ -1190,14 +1211,13 @@ class IndexClient:
             fails = []
             for i, pos in enumerate(ordering):
                 idx = self.sub_indexes[pos]
-                att_w0 = time.time() if trace_id is not None else 0.0
-                att_p0 = time.perf_counter()
+                att_p0 = tracing.now()
                 try:
                     out = call_stub(idx, timeout=partial_timeout)
                 except rpc.DeadlineExceeded as e:
                     # the call's budget is spent: another replica cannot
                     # answer any sooner, so the group degrades now
-                    note_hop(group, idx, e, att_w0, att_p0)
+                    note_hop(group, idx, e, att_p0)
                     fails.append(_FailedRank(idx, e))
                     break
                 except rpc.TRANSPORT_ERRORS + (rpc.BusyError,) as e:
@@ -1205,7 +1225,7 @@ class IndexClient:
                         "replica %s (%s:%s) of group %s unreachable during "
                         "search; trying next replica: %s",
                         idx.id, idx.host, idx.port, group, e)
-                    note_hop(group, idx, e, att_w0, att_p0)
+                    note_hop(group, idx, e, att_p0)
                     fails.append(_FailedRank(idx, e))
                     continue
                 except rpc.ServerException as e:
@@ -1217,7 +1237,7 @@ class IndexClient:
                     if ((replication.drain_failover_eligible(e)
                          or replication.stale_read_failover_eligible(e))
                             and i + 1 < len(ordering)):
-                        note_hop(group, idx, e, att_w0, att_p0)
+                        note_hop(group, idx, e, att_p0)
                         fails.append(_FailedRank(idx, e))
                         continue
                     raise
@@ -1226,7 +1246,7 @@ class IndexClient:
                 return out
             return fails
 
-        raw = list(self.pool.map(one_partial, plan))
+        raw = list(self.pool.map(on_worker(one_partial), plan))
         ok = [r for r in raw if not isinstance(r, list)]
         missing = [
             {"server": f.stub.id, "host": f.stub.host, "port": f.stub.port,
@@ -1237,10 +1257,10 @@ class IndexClient:
             raise RuntimeError(
                 f"search failed on every rank: {[m['error'] for m in missing]}"
             )
-        merged = IndexClient._aggregate_results(
-            iter(ok), topk, q_size, maximize_metric, return_embeddings
-        )
-        record_fanout()
+        with tracing.stage("client.merge", sink=self.stats):
+            merged = IndexClient._aggregate_results(
+                iter(ok), topk, q_size, maximize_metric, return_embeddings
+            )
         return merged + (missing,)
 
     @staticmethod
@@ -1505,7 +1525,11 @@ class IndexClient:
         CLIENT-side view of that rank's stub — instantaneous/peak
         pipelining depth and wire round-trip percentiles — so operators
         see mux depth and wire p99 next to the rank's own scheduler and
-        engine stats (docs/OPERATIONS.md#wire-protocol-appendix).
+        engine stats (docs/OPERATIONS.md#wire-protocol-appendix) — with
+        the stub's stage rows (``client.pack``, ``client.send``,
+        ``client.round_trip.<op>``) beside them; the client-wide stages
+        (``client.search``, ``client.fanout_wait``, ``client.merge``) ride
+        every entry under a ``"client"`` key.
 
         Replication observability (ISSUE 8 satellite): each entry's
         ``"replication"`` key (the server's {rank, shard_group} identity)
@@ -1532,11 +1556,13 @@ class IndexClient:
 
         stats = list(self.pool.map(one, self.sub_indexes))
         repl = self.get_replication_stats()
+        own = self.stats.summary()
         for stub, entry in zip(self.sub_indexes, stats):
             if isinstance(entry, dict) and hasattr(stub, "rpc_stats"):
                 entry.setdefault("rpc", {})["client"] = stub.rpc_stats()
             if isinstance(entry, dict):
                 entry.setdefault("replication", {})["client"] = repl
+                entry["client"] = own
         return stats
 
     def get_trace_spans(self, trace_id: Optional[str] = None) -> list:

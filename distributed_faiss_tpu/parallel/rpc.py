@@ -22,7 +22,7 @@ Frame layout (little-endian):
 Multiplexing (docs/OPERATIONS.md#wire-protocol-appendix): every CALL frame
 from a mux client carries a ``req_id`` in the optional trailing meta
 element (the same dict that carries ``deadline_s`` and, for sampled
-requests, the distributed-tracing ``trace_id`` —
+requests, the distributed-tracing ``trace_id`` and ``parent`` span id —
 observability/spans.py), and the server
 answers with *tagged* response kinds (``KIND_*_MUX``) whose payload is
 ``({"req_id": n}, body)`` — so many calls can be in flight per connection
@@ -42,12 +42,12 @@ import socket
 import struct
 import threading
 import time
+from typing import Optional
 
 import numpy as np
 
-from distributed_faiss_tpu.observability import spans as obs_spans
 from distributed_faiss_tpu.parallel import wire
-from distributed_faiss_tpu.utils import envutil, lockdep
+from distributed_faiss_tpu.utils import envutil, lockdep, tracing
 from distributed_faiss_tpu.utils.tracing import LatencyStats
 
 DEFAULT_PORT = 12032  # same default port as the reference (rpc.py:22)
@@ -513,6 +513,10 @@ class FrameReader:
         self._buf = bytearray()
         self._pos = 0
         self._frame_started = False
+        # tracing.now() when the last frame's bytes were all in hand,
+        # before its skeleton was decoded: where the receiver's share of a
+        # request starts (the server's ``server.decode`` stage)
+        self.frame_t = 0.0
 
     @property
     def pending(self) -> bool:
@@ -599,6 +603,7 @@ class FrameReader:
             # (pending next-frame bytes, if any, slide to the front)
             del self._buf[:self._pos]
             self._pos = 0
+        self.frame_t = tracing.now()
         if not binary:
             return kind, _restore(restricted_loads(skel_bytes), arrays), False
         try:
@@ -649,6 +654,16 @@ def _decode_binary_skeleton(kind: int, skel: bytes, arrays):
     if req_id is None:
         return body
     return {"req_id": req_id}, body
+
+
+def stage_sink(fname: str, stats):
+    """Where a request stage's counter goes: the request's ledger
+    (docs/OPERATIONS.md#stage-ledger) is the served search path's, so
+    only ``search`` books ``client.pack`` / ``client.send`` /
+    ``server.*`` rows. Any other op keeps its per-op row and
+    ``client.round_trip.<op>``; its stages are spans only (a sampled
+    call), and the exporter carries no series for them."""
+    return stats if fname == "search" else tracing.SPAN_ONLY
 
 
 class _PendingCall:
@@ -904,21 +919,31 @@ class Client:
         the socket wait. An already-expired deadline raises
         ``DeadlineExceeded`` without touching the wire.
 
-        ``trace_id`` (a sampled request's id, observability/spans.py)
-        rides the frame meta beside ``req_id``/``deadline_s`` so the
-        server's stages attribute their spans to it; the stub records its
-        own ``client.pack`` / ``client.rpc`` spans into the process-local
-        SpanBuffer and stamps the id as the round-trip histogram's
-        exemplar. None (the default) adds no meta key and records
-        nothing — the wire stays byte-identical to the pre-trace frames."""
+        Stages (utils/tracing.stage, counters in ``self.stats``):
+        ``client.pack`` (frame encode) and ``client.send`` (wait for the
+        stub lock plus the frame write) — counters for ``search`` only,
+        ``stage_sink`` — then span ``client.rpc``, counter
+        ``client.round_trip.<op>`` (end of the send to demux completion).
+        The all-ops ``round_trip_s`` row is what it always was: stub-lock
+        wait and write included, a sampled request's id as its exemplar.
+        When the calling thread works for a sampled request
+        (``tracing.bind``), its ``trace_id`` rides the frame meta beside
+        ``req_id``/``deadline_s`` with the round trip's span id as
+        ``parent``, so the server's spans hang under it. Unsampled calls
+        add no meta key — the wire stays byte-identical to the pre-trace
+        frames. ``trace_id`` starts a trace at this call, for a caller
+        that talks to one stub directly (``IndexClient.search`` binds its
+        own)."""
+        if trace_id is not None:
+            with tracing.bind((trace_id, None, None)):
+                return self.generic_fun(fname, args, kwargs, timeout, deadline)
         if deadline is not None and deadline - time.time() <= 0:
             # cheap fast-fail before contending for the stub lock
             raise DeadlineExceeded(
                 f"deadline expired {time.time() - deadline:.3f}s before "
                 f"calling {fname}")
         if not self._mux:
-            return self._call_serial(fname, args, kwargs, timeout, deadline,
-                                     trace_id)
+            return self._call_serial(fname, args, kwargs, timeout, deadline)
         # ---- ensure a live connection (lock held briefly; may redial) ----
         with self._lock:
             # graftlint: ok(blocking-under-lock): redial backoff is bounded by RECONNECT_TIMEOUT and must serialize under the stub lock (connection state)
@@ -939,8 +964,7 @@ class Client:
             # extensible-meta contract). DFT_RPC_WIRE=pickle removes even
             # this, keeping frames byte-identical to the pre-wire client.
             meta["wire"] = 1
-        if trace_id is not None:
-            meta["trace_id"] = trace_id  # spans.TRACE_META_KEY pins this spelling
+        rt_span = self._trace_meta(meta)
         if deadline is not None:
             budget = deadline - time.time()
             if budget <= 0:
@@ -955,24 +979,21 @@ class Client:
         # and BEFORE touching the socket: a client-side pickling failure
         # (unpicklable argument) must raise without tearing down a healthy
         # connection — zero bytes have hit the wire.
-        if trace_id is not None:
-            w0, p0 = time.time(), time.perf_counter()
-        parts = None
-        if peer_wire:
-            # negotiated binary skeleton for the hot search frames; None
-            # (schema miss: unknown op/kwargs/meta) falls back to pickle
-            # for THIS frame only
-            parts = pack_binary_call(fname, tuple(args), kwargs or {}, meta)
-        if parts is None:
-            parts = pack_frame(KIND_CALL, (fname, tuple(args), kwargs or {}, meta))
-        if trace_id is not None:
-            obs_spans.local_buffer().record(
-                trace_id, "client.pack", w0, time.perf_counter() - p0,
-                fname=fname, server=self.id)
+        sink = stage_sink(fname, self.stats)
+        with tracing.stage("client.pack", sink=sink, fname=fname,
+                           server=self.id):
+            parts = None
+            if peer_wire:
+                # negotiated binary skeleton for the hot search frames;
+                # None (schema miss: unknown op/kwargs/meta) falls back to
+                # pickle for THIS frame only
+                parts = pack_binary_call(fname, tuple(args), kwargs or {}, meta)
+            if parts is None:
+                parts = pack_frame(
+                    KIND_CALL, (fname, tuple(args), kwargs or {}, meta))
         slot = _PendingCall(rid, fname)
-        w0 = time.time() if trace_id is not None else 0.0
-        t0 = time.perf_counter()
-        with self._lock:
+        with tracing.stage("client.send", sink=sink, fname=fname,
+                           server=self.id) as send, self._lock:
             if self._shutdown:
                 raise RuntimeError(f"client to {self.host}:{self.port} is closed")
             if self._closed or epoch != self._epoch:
@@ -992,6 +1013,7 @@ class Client:
                 # in-flight call on it: fail them all and drop the socket
                 self._fail_locked(e)
                 raise
+        t0 = tracing.now()
         # ---- wait for this call's slot, outside any lock ----
         if not slot.event.wait(wait):
             exc = socket.timeout(
@@ -1029,22 +1051,42 @@ class Client:
                     raise exc
         if slot.error is not None:
             raise slot.error
-        # record completed round trips only (parity with the serial path:
-        # a timeout/teardown must not land its wait ceiling in the p99)
-        dt = time.perf_counter() - t0
-        self.stats.record("round_trip_s", dt, exemplar=trace_id)
-        if trace_id is not None:
-            # send -> demux completion: wire both ways PLUS the server's
-            # queue/launch time — the merged timeline subtracts the
-            # server-recorded spans to isolate the wire itself
-            obs_spans.local_buffer().record(
-                trace_id, "client.rpc", w0, dt, fname=fname, server=self.id,
-                host=self.host, port=self.port)
+        self._book_round_trip(fname, t0, rt_span, send.dt)
         return self._interpret(slot.kind, slot.payload, fname)
 
+    def _trace_meta(self, meta: dict) -> Optional[str]:
+        """For a sampled request (the calling thread's context holds one)
+        put its ``trace_id`` into the CALL frame's meta, with the span id
+        minted here for this call's round trip as the ``parent`` of the
+        rank's spans; returns that id. Unsampled: nothing, None."""
+        ticket = tracing.ticket()
+        if ticket is None:
+            return None
+        rt_span = tracing.new_span_id()
+        # spans.TRACE_META_KEY / PARENT_META_KEY pin these spellings
+        meta["trace_id"] = ticket[0]
+        meta["parent"] = rt_span
+        return rt_span
+
+    def _book_round_trip(self, fname: str, t0: float, rt_span,
+                         send_s: float = 0.0) -> None:
+        """Completed round trips only (a timeout/teardown must not land
+        its wait ceiling in the p99). Span ``client.rpc``, counter
+        ``client.round_trip.<op>``: end of the send to demux completion —
+        wire both ways PLUS the rank's whole share (``server.request``),
+        which a merged timeline subtracts to isolate the wire itself.
+        ``round_trip_s`` (all ops) adds ``send_s``, the mux path's
+        stub-lock wait and write, as it always has."""
+        dt = tracing.book("client.rpc", t0, sink=self.stats,
+                          counter="client.round_trip." + fname,
+                          span_id=rt_span, fname=fname, server=self.id,
+                          host=self.host, port=self.port)
+        ticket = tracing.ticket()
+        self.stats.record("round_trip_s", send_s + dt,
+                          exemplar=ticket[0] if ticket else None)
+
     # graftlint: ok(blocking-under-lock): the serial client holds the stub lock across the round trip BY DEFINITION (one call per connection); per-call `timeout` bounds the socket when the caller asks
-    def _call_serial(self, fname, args, kwargs, timeout, deadline,
-                     trace_id=None):
+    def _call_serial(self, fname, args, kwargs, timeout, deadline):
         """The pre-mux client: ``_lock`` held across the whole round trip,
         frames only carry meta when a deadline (or a sampled trace) sets
         a key (byte-compatible with pre-deadline peers). Kept as the
@@ -1053,8 +1095,7 @@ class Client:
             self._ensure_connected_locked()
             budget = None
             meta = {}
-            if trace_id is not None:
-                meta["trace_id"] = trace_id  # spans.TRACE_META_KEY pins this spelling
+            rt_span = self._trace_meta(meta)
             if deadline is not None:
                 budget = deadline - time.time()
                 if budget <= 0:
@@ -1067,11 +1108,12 @@ class Client:
             payload = (fname, tuple(args), kwargs or {})
             if meta:
                 payload = payload + (meta,)
-            parts = pack_frame(KIND_CALL, payload)
+            with tracing.stage("client.pack", fname=fname, server=self.id,
+                               sink=stage_sink(fname, self.stats)):
+                parts = pack_frame(KIND_CALL, payload)
             if timeout is not None:
                 self.sock.settimeout(timeout)
-            w0 = time.time() if trace_id is not None else 0.0
-            t0 = time.perf_counter()
+            t0 = tracing.now()
             try:
                 _send_parts(self.sock, parts)
                 kind, payload = self._frame_reader.recv_frame()
@@ -1088,12 +1130,7 @@ class Client:
             finally:
                 if timeout is not None and not self._closed:
                     self.sock.settimeout(None)
-        dt = time.perf_counter() - t0
-        self.stats.record("round_trip_s", dt, exemplar=trace_id)
-        if trace_id is not None:
-            obs_spans.local_buffer().record(
-                trace_id, "client.rpc", w0, dt, fname=fname, server=self.id,
-                host=self.host, port=self.port)
+        self._book_round_trip(fname, t0, rt_span)
         return self._interpret(kind, payload, fname)
 
     def fetch_shard(self, index_id: str, timeout: float = 120.0):
@@ -1147,13 +1184,17 @@ class Client:
             in_flight = len(self._pending)
             peak = self._inflight_peak
             peer_wire = self._peer_wire
+        rows = self.stats.summary()
         return {
             "mux": self._mux,
             "wire": "binary" if self._wire else "pickle",
             "peer_wire": peer_wire,
             "in_flight": in_flight,
             "in_flight_peak": peak,
-            "round_trip_s": self.stats.summary().get("round_trip_s", {}),
+            "round_trip_s": rows.pop("round_trip_s", {}),
+            # the stub's stages: client.pack, client.send,
+            # client.round_trip.<op> (docs/OPERATIONS.md#stage-ledger)
+            **rows,
         }
 
     def __getattr__(self, name: str):

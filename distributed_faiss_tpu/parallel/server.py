@@ -33,6 +33,7 @@ import numpy as np
 
 from distributed_faiss_tpu.engine import Index
 from distributed_faiss_tpu.observability import export as obs_export
+from distributed_faiss_tpu.observability import profile as obs_profile
 from distributed_faiss_tpu.observability import spans as obs_spans
 from distributed_faiss_tpu.parallel import antientropy, rpc, wire
 from distributed_faiss_tpu.serving.scheduler import (
@@ -41,7 +42,7 @@ from distributed_faiss_tpu.serving.scheduler import (
     SchedulerStopped,
     SearchScheduler,
 )
-from distributed_faiss_tpu.utils import envutil, lockdep
+from distributed_faiss_tpu.utils import envutil, lockdep, tracing
 from distributed_faiss_tpu.utils.config import (
     AntiEntropyCfg,
     IndexCfg,
@@ -128,6 +129,36 @@ class _ConnState:
         self.reader = reader
 
 
+class _Call:
+    """One CALL frame's clock and trace, handed from the thread that read
+    it to the threads that finish it (batcher callback, RPC worker).
+
+    ``t_frame``: the whole frame in hand (``FrameReader.frame_t``);
+    ``t0``: decoded and about to be dispatched — where the per-op row
+    (``search``) has always started; ``t_done``: the scheduler's callback
+    fired. For a sampled request, ``root`` is the ticket its
+    ``server.request`` span (``span_id``, booked last) is booked under —
+    the parent that crossed the wire — and ``ticket`` the one that binds
+    the workers: their spans hang under ``server.request``."""
+
+    __slots__ = ("req_id", "fname", "sink", "t_frame", "t0", "t_done",
+                 "root", "ticket", "span_id")
+
+    def __init__(self, fname, t_frame, req_id, trace_id, parent, spans, perf):
+        self.req_id = req_id
+        self.fname = fname
+        # the request's ledger is the search path's: any other op keeps
+        # its per-op row, and its stages are spans only (rpc.stage_sink)
+        self.sink = rpc.stage_sink(fname, perf)
+        self.t_frame = t_frame
+        self.t0 = self.t_done = None
+        self.root = self.ticket = self.span_id = None
+        if trace_id is not None:
+            self.span_id = tracing.new_span_id()
+            self.root = (trace_id, parent, spans)
+            self.ticket = (trace_id, self.span_id, spans)
+
+
 class IndexServer:
     def __init__(self, rank: int, index_storage_dir: str,
                  scheduler_cfg: Optional[SchedulerCfg] = None,
@@ -152,6 +183,8 @@ class IndexServer:
         self.socket: Optional[socket.socket] = None
         self._stopping = threading.Event()
         self.perf = LatencyStats()  # per-RPC latency counters (SURVEY §5.1)
+        # a rank's stages are profiler events too (utils/tracing.stage)
+        tracing.annotate_stages()
         # background work (async training) runs on named, tracked threads so
         # stop() can wait for them instead of orphaning device work
         self._threads_lock = lockdep.lock("IndexServer._threads_lock")
@@ -182,8 +215,7 @@ class IndexServer:
             self.scheduler = SearchScheduler(
                 self._engine_search_batched, cfg,
                 name=f"search-batcher:r{rank}",
-                tag={"rank": rank, "shard_group": self.shard_group},
-                span_buffer=self.spans)
+                tag={"rank": rank, "shard_group": self.shard_group})
         # request multiplexing: calls whose frame meta carries a req_id are
         # dispatched without blocking the connection's reader (search → the
         # scheduler's async completion path, everything else → this worker
@@ -452,10 +484,9 @@ class IndexServer:
     # ---------------------------------------------------------- anti-entropy
 
     def _wire_engine(self, index: Index) -> None:
-        """Install the compaction-lease gate and this rank's span ring on
-        an engine entering the registry (the sweeper re-asserts every
-        sweep, so engines that predate the sweeper converge too)."""
-        index.span_buffer = self.spans
+        """Install the compaction-lease gate on an engine entering the
+        registry (the sweeper re-asserts every sweep, so engines that
+        predate the sweeper converge too)."""
         if self._antientropy is not None:
             index.compaction_gate = self._antientropy.may_compact
 
@@ -544,7 +575,7 @@ class IndexServer:
         digest computation may hash O(rows) on a cache miss and must not
         occupy the selector loop's shared reader. The inbound contact is
         itself liveness evidence for the failure detector."""
-        t0 = time.perf_counter()
+        t0 = tracing.now()
         try:
             req = payload if isinstance(payload, dict) else {}
             if self._antientropy is not None:
@@ -572,7 +603,8 @@ class IndexServer:
                 "compaction": {"held": held},
             }
             parts = rpc.pack_frame(rpc.KIND_DIGEST_RESP, resp)
-            self.perf.record("digest_exchange", time.perf_counter() - t0)
+            tracing.book("server.digest_exchange", t0, sink=self.perf,
+                         counter="digest_exchange")
         except Exception:
             tb = traceback.format_exc()
             logger.error("digest exchange failed: %s", tb)
@@ -611,7 +643,11 @@ class IndexServer:
         legacy call counts, worker-pool size; IndexClient merges each
         stub's client-side view in under ``rpc.client``), and ``"engine"``
         the per-index device-launch latency distributions — wire, queue,
-        and device time side by side.
+        and device time side by side. The stage ledger's rows
+        (docs/OPERATIONS.md#stage-ledger) sit where their layer's rows
+        are: ``server.*`` beside the per-op rows, ``sched.*`` under
+        ``scheduler.queues``, ``engine.*`` under ``engine.<index_id>``;
+        ``xla.compile`` counts this process's XLA compiles.
 
         ``raw=True`` threads the raw-histogram view through every
         LatencyStats block (bucket counts + trace exemplars) — the shape
@@ -621,6 +657,9 @@ class IndexServer:
         trace_id to feed ``get_trace_spans`` when asking what made the
         p99 spike."""
         out = self.perf.summary(raw=raw)
+        # XLA compiles in this process since it started (utils/tracing):
+        # a timed window's compiles are count after less count before
+        out["xla.compile"] = tracing.compile_row(raw=raw)
         if self.scheduler is not None:
             out["scheduler"] = self.scheduler.perf_stats(raw=raw)
         with self._mux_lock:
@@ -669,6 +708,21 @@ class IndexServer:
         new frame kinds, so legacy peers simply never call it."""
         spans = self.spans.snapshot(trace_id)
         return spans[-int(limit):] if limit else spans
+
+    def profile(self, seconds: float = 5.0, keep: bool = False) -> dict:
+        """Profile THIS rank for ``seconds`` and reduce its own trace
+        (observability/profile.py): device busy and idle, idle seconds by
+        the host stage open meanwhile, device seconds by named scope. An
+        ordinary op (mux calls run it on the RPC worker pool; it holds one
+        worker for the session); a plain application error while another
+        session is open in the process. ``keep`` leaves the ``.xplane.pb``
+        under this rank's storage directory and names it in the reply."""
+        keep_dir = None
+        if keep:
+            keep_dir = os.path.join(
+                self.index_storage_dir, f"profile_r{self.rank}",
+                time.strftime("%Y%m%d_%H%M%S"))
+        return obs_profile.profile(seconds, keep_dir)
 
     def ping(self) -> dict:
         """Liveness/health probe (the reference has no failure detection
@@ -844,10 +898,12 @@ class IndexServer:
             # per-call state keeps every dispatch path uniform — the mux
             # response writers dereference state unconditionally
             state = _ConnState(None, lockdep.lock("IndexServer.conn_wlock"))
-        if state.reader is not None:
-            kind, payload, was_binary = state.reader.recv_frame_ex()
-        else:
-            kind, payload, was_binary = rpc.recv_frame_ex(conn)
+        # throwaway states read unbuffered (bufsize=0 never over-reads
+        # past the frame; over-reading there would DROP the buffered bytes
+        # when the state dies)
+        reader = (state.reader if state.reader is not None
+                  else rpc.FrameReader(conn, bufsize=0))
+        kind, payload, was_binary = reader.recv_frame_ex()
         wlock = state.wlock
         if kind == rpc.KIND_CLOSE:
             raise rpc.ClientExit("client closed")
@@ -873,29 +929,29 @@ class IndexServer:
         # 3-tuple (legacy) or 4-tuple with frame meta carrying the caller's
         # remaining deadline budget (relative seconds — clock-skew-safe;
         # rebased onto this host's monotonic clock at decode), the sampled
-        # trace_id every serving stage attributes its spans to, and, from
-        # mux clients, the req_id that pipelined dispatch tags responses
-        # with
+        # trace_id (and parent span id) every serving stage attributes its
+        # spans to, and, from mux clients, the req_id that pipelined
+        # dispatch tags responses with
         fname, args, kwargs = payload[:3]
         frame_meta = payload[3] if len(payload) > 3 else None
         deadline = None
-        req_id = None
-        trace_id = None
         if isinstance(frame_meta, dict):
             if frame_meta.get("deadline_s") is not None:
                 deadline = time.monotonic() + float(frame_meta["deadline_s"])
-            req_id = frame_meta.get("req_id")
-            trace_id = frame_meta.get("trace_id")
             if was_binary or frame_meta.get("wire"):
                 # the peer decodes binary skeletons (explicit advert, or
                 # it just SENT one): search-family responses on this
                 # connection may go out binary from here on
                 state.peer_wire = True
-        if req_id is None:
+        else:
+            frame_meta = {}
+        call = _Call(fname, reader.frame_t, frame_meta.get("req_id"),
+                     frame_meta.get("trace_id"), frame_meta.get("parent"),
+                     self.spans, self.perf)
+        if call.req_id is None:
             with self._mux_lock:
                 self._mux_counters["legacy_calls"] += 1
-            self._call_sync(conn, fname, args, kwargs, deadline, eager_search,
-                            trace_id)
+            self._call_sync(conn, call, args, kwargs, deadline, eager_search)
             return
         # mux dispatch: the reader never blocks on the call — the response
         # is written req_id-tagged under the connection's write lock by
@@ -903,15 +959,14 @@ class IndexServer:
         with self._mux_lock:
             self._mux_counters["mux_calls"] += 1
             self._mux_inflight += 1
-        t0 = time.perf_counter()
         if fname == "search" and self.scheduler is not None:
-            self._dispatch_scheduled(conn, state, args, kwargs, deadline,
-                                     req_id, t0, trace_id)
+            self._dispatch_scheduled(conn, state, call, args, kwargs, deadline)
         else:
+            with tracing.bind(call.ticket):
+                self._book_decode(call)
             try:
                 self._rpc_workers.submit(
-                    self._dispatch_direct, conn, state, fname, args, kwargs,
-                    req_id, t0, trace_id)
+                    self._dispatch_direct, conn, state, call, args, kwargs)
             except RuntimeError:  # pool already shut down (server stopping)
                 with self._mux_lock:
                     self._mux_inflight -= 1
@@ -925,12 +980,13 @@ class IndexServer:
         tearing the transfer connection down undiagnosed). Runs on the
         worker pool; a peer that vanished mid-transfer costs a logged
         OSError, never an unhandled worker exception."""
-        t0 = time.perf_counter()
+        t0 = tracing.now()
         try:
             (index_id,) = tuple(payload)[:1]
             snapshot = self._get_index(index_id).export_snapshot()
             parts = rpc.pack_frame(rpc.KIND_SHARD_DATA, snapshot)
-            self.perf.record("fetch_shard", time.perf_counter() - t0)
+            tracing.book("server.fetch_shard", t0, sink=self.perf,
+                         counter="fetch_shard")
         except Exception:
             tb = traceback.format_exc()
             logger.error("shard fetch failed: %s", tb)
@@ -961,8 +1017,21 @@ class IndexServer:
             return "search:shed", {"reason": "deadline"}
         return None
 
-    def _call_sync(self, conn, fname, args, kwargs, deadline,
-                   eager_search, trace_id=None) -> None:
+    def _book_decode(self, call: _Call) -> None:
+        """``server.decode`` ends and the per-op row starts: the frame is
+        decoded and about to be dispatched (the caller has bound the
+        call's trace)."""
+        tracing.book("server.decode", call.t_frame, sink=call.sink,
+                     fname=call.fname)
+        call.t0 = tracing.now()
+
+    def _book_op(self, call: _Call, name: str) -> None:
+        """The per-op latency row (``search``, ``search:busy``, ...): from
+        the decoded frame's dispatch to the op's completion."""
+        tracing.book("server." + name, call.t0, sink=self.perf, counter=name)
+
+    def _call_sync(self, conn, call: _Call, args, kwargs, deadline,
+                   eager_search) -> None:
         """The legacy (no-req_id) path: serve the call on the reader thread
         and answer untagged, in order — an old client against a mux server
         works unchanged.
@@ -973,47 +1042,55 @@ class IndexServer:
         nothing further can be written safely — the OSError propagates and
         the serving loop drops the connection, instead of appending an
         ERROR frame to a torn stream."""
-        t0 = time.perf_counter()
-        try:
-            fn = getattr(self, fname)
-            if fname.startswith("_"):
-                raise AttributeError(fname)
-            if fname == "search" and self.scheduler is not None:
-                # admission-controlled path: queue bound + deadline shedding
-                ret = self._scheduled_search(args, kwargs, deadline,
-                                             eager_search, trace_id)
-            else:
-                ret = fn(*args, **kwargs)
-            self.perf.record(fname, time.perf_counter() - t0,
-                             exemplar=trace_id)
-            kind, payload = rpc.KIND_RESULT, ret
-        except Exception as e:
-            busy = self._classify_scheduler_reject(e)
-            if busy is not None:
-                self.perf.record(busy[0], time.perf_counter() - t0)
-                kind, payload = rpc.KIND_BUSY, busy[1]
-            else:
-                tb = traceback.format_exc()
-                logger.error("exception in %s: %s", fname, tb)
-                kind, payload = rpc.KIND_ERROR, tb
-        try:
-            # pack before writing: an unpicklable RESULT must degrade to a
-            # structured error frame, not a torn connection
-            parts = rpc.pack_frame(kind, payload)
-        except Exception:
-            tb = traceback.format_exc()
-            logger.error("could not serialize %s response: %s", fname, tb)
-            parts = rpc.pack_frame(rpc.KIND_ERROR, tb)
-        if trace_id is not None:
-            w0, p0 = time.time(), time.perf_counter()
-            rpc._send_parts(conn, parts)
-            self.spans.record(trace_id, "server.write", w0,
-                              time.perf_counter() - p0, fname=fname)
-        else:
-            rpc._send_parts(conn, parts)
+        fname = call.fname
+        with tracing.bind(call.ticket):
+            self._book_decode(call)
+            try:
+                fn = getattr(self, fname)
+                if fname.startswith("_"):
+                    raise AttributeError(fname)
+                if fname == "search" and self.scheduler is not None:
+                    # admission-controlled path: queue bound + deadline
+                    # shedding
+                    ret = self._scheduled_search(args, kwargs, deadline,
+                                                 eager_search)
+                else:
+                    ret = fn(*args, **kwargs)
+                self._book_op(call, fname)
+                kind, payload = rpc.KIND_RESULT, ret
+            except Exception as e:
+                busy = self._classify_scheduler_reject(e)
+                if busy is not None:
+                    self._book_op(call, busy[0])
+                    kind, payload = rpc.KIND_BUSY, busy[1]
+                else:
+                    tb = traceback.format_exc()
+                    logger.error("exception in %s: %s", fname, tb)
+                    kind, payload = rpc.KIND_ERROR, tb
+            with tracing.stage("server.pack", sink=call.sink, fname=fname):
+                try:
+                    # pack before writing: an unpicklable RESULT must
+                    # degrade to a structured error frame, not a torn
+                    # connection
+                    parts = rpc.pack_frame(kind, payload)
+                except Exception:
+                    tb = traceback.format_exc()
+                    logger.error("could not serialize %s response: %s",
+                                 fname, tb)
+                    parts = rpc.pack_frame(rpc.KIND_ERROR, tb)
+            with tracing.stage("server.write", sink=call.sink, fname=fname):
+                rpc._send_parts(conn, parts)
+        self._book_request(call)
 
-    def _scheduled_search(self, args, kwargs, deadline, eager=False,
-                          trace_id=None):
+    def _book_request(self, call: _Call) -> None:
+        """``server.request``: the whole frame in hand to the last byte of
+        the response written — the rank's whole share of a round trip,
+        and the parent of every span the rank booked for the request."""
+        with tracing.bind(call.root):
+            tracing.book("server.request", call.t_frame, sink=call.sink,
+                         span_id=call.span_id, fname=call.fname)
+
+    def _scheduled_search(self, args, kwargs, deadline, eager=False):
         """Normalize a search RPC's args onto the scheduler's submit."""
         vals = dict(zip(
             ("index_id", "query_batch", "top_k", "return_embeddings"), args))
@@ -1022,7 +1099,7 @@ class IndexServer:
         return self.scheduler.submit(
             vals["index_id"], vals["query_batch"], vals["top_k"],
             bool(vals.get("return_embeddings", False)), deadline=deadline,
-            eager=eager, trace_id=trace_id)
+            eager=eager)
 
     def _check_search_min_version(self, vals: dict) -> None:
         """Pop a search's ``min_version`` (read-your-writes) demand and
@@ -1036,8 +1113,8 @@ class IndexServer:
 
     # ------------------------------------------------------------ mux dispatch
 
-    def _dispatch_scheduled(self, conn, state, args, kwargs, deadline,
-                            req_id, t0, trace_id=None) -> None:
+    def _dispatch_scheduled(self, conn, state, call: _Call, args, kwargs,
+                            deadline) -> None:
         """Hand a mux search to the scheduler without blocking the reader:
         the scheduler already completes out of order via per-request
         events, so its completion callback just enqueues the tagged
@@ -1047,67 +1124,77 @@ class IndexServer:
         wait window now, and coalescing them is the whole point."""
 
         def done(result, error):
+            call.t_done = tracing.now()
             try:
                 self._rpc_workers.submit(self._finish_scheduled, conn, state,
-                                         req_id, result, error, t0, trace_id)
+                                         call, result, error)
             except RuntimeError:
                 # pool already shut down (server stopping): the client's
                 # demux will fail the call when the connection drops
                 with self._mux_lock:
                     self._mux_inflight -= 1
 
-        try:
-            vals = dict(zip(
-                ("index_id", "query_batch", "top_k", "return_embeddings"),
-                args))
-            vals.update(kwargs or {})
-            self._check_search_min_version(vals)
-            self.scheduler.submit_async(
-                vals["index_id"], vals["query_batch"], vals["top_k"],
-                bool(vals.get("return_embeddings", False)),
-                deadline=deadline, callback=done, trace_id=trace_id)
-        except Exception as e:
-            # admission rejected (BUSY/deadline/stopped) or bad args:
-            # answered synchronously — the request was never queued
-            self._finish_scheduled(conn, state, req_id, None, e, t0, trace_id)
+        with tracing.bind(call.ticket):
+            self._book_decode(call)
+            try:
+                vals = dict(zip(
+                    ("index_id", "query_batch", "top_k", "return_embeddings"),
+                    args))
+                vals.update(kwargs or {})
+                self._check_search_min_version(vals)
+                # (the scheduler takes the sampled request off this
+                # thread's context: tracing.ticket)
+                self.scheduler.submit_async(
+                    vals["index_id"], vals["query_batch"], vals["top_k"],
+                    bool(vals.get("return_embeddings", False)),
+                    deadline=deadline, callback=done)
+                return
+            except Exception as e:
+                error = e
+        # admission rejected (BUSY/deadline/stopped) or bad args:
+        # answered synchronously — the request was never queued
+        self._finish_scheduled(conn, state, call, None, error)
 
-    def _finish_scheduled(self, conn, state, req_id, result, error,
-                          t0, trace_id=None) -> None:
-        if error is None:
-            self.perf.record("search", time.perf_counter() - t0,
-                             exemplar=trace_id)
-            self._send_mux_response(conn, state, rpc.KIND_RESULT, result,
-                                    req_id, "search", trace_id)
-            return
-        busy = self._classify_scheduler_reject(error)
-        if busy is not None:
-            self.perf.record(busy[0], time.perf_counter() - t0)
-            self._send_mux_response(conn, state, rpc.KIND_BUSY, busy[1],
-                                    req_id, "search", trace_id)
-            return
-        tb = "".join(traceback.format_exception(
-            type(error), error, error.__traceback__))
-        logger.error("exception in scheduled search: %s", tb)
-        self._send_mux_response(conn, state, rpc.KIND_ERROR, tb,
-                                req_id, "search", trace_id)
+    def _finish_scheduled(self, conn, state, call: _Call, result,
+                          error) -> None:
+        with tracing.bind(call.ticket):
+            if call.t_done is not None:
+                # the batcher's callback to an RPC worker taking it
+                tracing.book("server.finish_wait", call.t_done,
+                             sink=self.perf)
+            if error is None:
+                self._book_op(call, "search")
+                self._send_mux_response(conn, state, rpc.KIND_RESULT, result,
+                                        call)
+                return
+            busy = self._classify_scheduler_reject(error)
+            if busy is not None:
+                self._book_op(call, busy[0])
+                self._send_mux_response(conn, state, rpc.KIND_BUSY, busy[1],
+                                        call)
+                return
+            tb = "".join(traceback.format_exception(
+                type(error), error, error.__traceback__))
+            logger.error("exception in scheduled search: %s", tb)
+            self._send_mux_response(conn, state, rpc.KIND_ERROR, tb, call)
 
-    def _dispatch_direct(self, conn, state, fname, args, kwargs, req_id,
-                         t0, trace_id=None) -> None:
+    def _dispatch_direct(self, conn, state, call: _Call, args,
+                         kwargs) -> None:
         """Worker-pool target for mux non-search ops."""
-        try:
-            if fname.startswith("_"):
-                raise AttributeError(fname)
-            fn = getattr(self, fname)
-            ret = fn(*args, **(kwargs or {}))
-            self.perf.record(fname, time.perf_counter() - t0,
-                             exemplar=trace_id)
-            self._send_mux_response(conn, state, rpc.KIND_RESULT, ret,
-                                    req_id, fname, trace_id)
-        except Exception:
-            tb = traceback.format_exc()
-            logger.error("exception in %s: %s", fname, tb)
-            self._send_mux_response(conn, state, rpc.KIND_ERROR, tb,
-                                    req_id, fname, trace_id)
+        fname = call.fname
+        with tracing.bind(call.ticket):
+            try:
+                if fname.startswith("_"):
+                    raise AttributeError(fname)
+                fn = getattr(self, fname)
+                ret = fn(*args, **(kwargs or {}))
+                self._book_op(call, fname)
+                self._send_mux_response(conn, state, rpc.KIND_RESULT, ret,
+                                        call)
+            except Exception:
+                tb = traceback.format_exc()
+                logger.error("exception in %s: %s", fname, tb)
+                self._send_mux_response(conn, state, rpc.KIND_ERROR, tb, call)
 
     def _pack_mux_response(self, state, base_kind, payload, req_id, fname):
         """Frame parts for one tagged response: binary skeleton when the
@@ -1121,34 +1208,35 @@ class IndexServer:
                 return parts
         return rpc.pack_tagged_response(base_kind, payload, req_id)
 
-    def _send_mux_response(self, conn, state, base_kind, payload, req_id,
-                           fname, trace_id=None) -> None:
+    def _send_mux_response(self, conn, state, base_kind, payload,
+                           call: _Call) -> None:
         """Write one req_id-tagged response frame under the connection's
-        write lock. A write failure means the peer is gone — its demux has
-        already failed the call client-side, so only log. Called exactly
-        once per mux call (every dispatch path funnels here), which is
-        what keeps the in-flight gauge honest."""
+        write lock (the caller has bound the call's trace). A write
+        failure means the peer is gone — its demux has already failed the
+        call client-side, so only log. Called exactly once per mux call
+        (every dispatch path funnels here), which is what keeps the
+        in-flight gauge honest."""
         wlock = state.wlock
+        req_id, fname = call.req_id, call.fname
         try:
-            try:
-                parts = self._pack_mux_response(state, base_kind, payload,
-                                                req_id, fname)
-            except Exception:
-                # unpicklable result: answer a structured error instead of
-                # leaving the caller waiting (zero bytes hit the wire yet)
-                tb = traceback.format_exc()
-                logger.error("could not serialize %s response: %s", fname, tb)
-                parts = rpc.pack_tagged_response(rpc.KIND_ERROR, tb, req_id)
-            if trace_id is not None:
-                w0, p0 = time.time(), time.perf_counter()
-                with wlock:
-                    rpc._send_parts(conn, parts)
-                self.spans.record(trace_id, "server.write", w0,
-                                  time.perf_counter() - p0, fname=fname,
-                                  req_id=req_id)
-            else:
-                with wlock:
-                    rpc._send_parts(conn, parts)
+            with tracing.stage("server.pack", sink=call.sink, fname=fname):
+                try:
+                    parts = self._pack_mux_response(state, base_kind, payload,
+                                                    req_id, fname)
+                except Exception:
+                    # unpicklable result: answer a structured error instead
+                    # of leaving the caller waiting (zero bytes hit the
+                    # wire yet)
+                    tb = traceback.format_exc()
+                    logger.error("could not serialize %s response: %s",
+                                 fname, tb)
+                    parts = rpc.pack_tagged_response(rpc.KIND_ERROR, tb,
+                                                     req_id)
+            # the wait for the connection's write lock plus the send
+            with tracing.stage("server.write", sink=call.sink, fname=fname,
+                               req_id=req_id), wlock:
+                rpc._send_parts(conn, parts)
+            self._book_request(call)
         except OSError as e:
             logger.info("mux response write failed (%s req=%s): %s",
                         fname, req_id, e)

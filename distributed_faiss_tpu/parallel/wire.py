@@ -21,8 +21,8 @@ referenced by u32 plane index):
 ``CALL`` (kind ``KIND_CALL | WIRE_BINARY_FLAG``)::
 
     u8 version (=1) | u8 op_id (index into BINARY_CALL_OPS) |
-    u8 meta_flags (1=req_id, 2=deadline_s, 4=trace_id) |
-    [u64 req_id] [f64 deadline_s] [str trace_id] |
+    u8 meta_flags (1=req_id, 2=deadline_s, 4=trace_id, 8=parent) |
+    [u64 req_id] [f64 deadline_s] [str trace_id] [str parent] |
     str index_id | u32 query_plane | u32 top_k | u8 return_embeddings
 
 The query plane is pinned to contiguous float32 — the dtype the serving
@@ -92,7 +92,8 @@ BINARY_CALL_OPS = ("search",)
 _META_REQ_ID = 1
 _META_DEADLINE = 2
 _META_TRACE = 4
-_KNOWN_META = frozenset({"req_id", "deadline_s", "trace_id", "wire"})
+_META_PARENT = 8  # the caller's span id: only ever set beside trace_id
+_KNOWN_META = frozenset({"req_id", "deadline_s", "trace_id", "parent", "wire"})
 
 _VERSION = 1
 _MAX_DEPTH = 32
@@ -216,6 +217,7 @@ def encode_call(fname: str, args, kwargs, meta):
     req_id = md.pop("req_id", None)
     deadline_s = md.pop("deadline_s", None)
     trace_id = md.pop("trace_id", None)
+    parent = md.pop("parent", None)
     if md:
         raise WireEncodeError(f"meta keys {sorted(md)} not in wire schema")
     out = bytearray()
@@ -231,6 +233,10 @@ def encode_call(fname: str, args, kwargs, meta):
         if type(trace_id) is not str:
             raise WireEncodeError("trace_id must be str")
         flags |= _META_TRACE
+    if parent is not None:
+        if type(parent) is not str:
+            raise WireEncodeError("parent must be str")
+        flags |= _META_PARENT
     out += _U8.pack(flags)
     if req_id is not None:
         out += _U64.pack(req_id)
@@ -238,6 +244,8 @@ def encode_call(fname: str, args, kwargs, meta):
         out += _F64.pack(float(deadline_s))
     if trace_id is not None:
         _enc_str(out, trace_id)
+    if parent is not None:
+        _enc_str(out, parent)
     _enc_str(out, index_id)
     out += _U32.pack(0)  # query plane ref (always the first plane)
     out += _U32.pack(top_k)
@@ -505,6 +513,8 @@ def decode_call(skel: bytes, arrays):
         meta["deadline_s"] = r.f64()
     if flags & _META_TRACE:
         meta["trace_id"] = r.s()
+    if flags & _META_PARENT:
+        meta["parent"] = r.s()
     index_id = r.s()
     q = _plane(arrays, r.u32())
     top_k = r.u32()

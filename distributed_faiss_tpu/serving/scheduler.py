@@ -43,12 +43,21 @@ Observability rides the shared ``LatencyStats`` histogram surface
 (utils/tracing.py): queue-wait and end-to-end latency with streaming
 percentiles, batch occupancy (requests and rows per launch), queue depth
 at flush, and monotonic shed/busy counters — all exported through the
-rank's ``get_perf_stats`` RPC under the ``"scheduler"`` key. Sampled
-requests (a non-None ``trace_id``) additionally record ``server.queue``
-(wait + which merge window they landed in and its occupancy) and
-``server.device`` (the window's launch) spans into the owning server's
-SpanBuffer, and stamp the latency histograms' exemplars
-(observability/spans.py).
+rank's ``get_perf_stats`` RPC under the ``"scheduler"`` key.
+
+The batcher thread keeps the launch loop's stage ledger
+(``utils/tracing.stage``, docs/OPERATIONS.md#stage-ledger): every second
+between two window ends is booked to exactly one of ``sched.idle`` (queue
+empty), ``sched.window_wait`` (a head request waits for followers),
+``sched.assemble`` (deadline shed, concat), the engine's stages (inside
+``server.device``, the engine call: a span and a profiler event, no
+counter) and ``sched.split`` (row split and completion callbacks), so the
+stages' totals add up to the thread's wall clock over windows that
+succeed (a stage that raises books nothing). A sampled request (its submitter's context held a trace,
+``tracing.ticket``) additionally gets ``server.queue`` (wait + which
+merge window it landed in and its occupancy) and ``server.device`` spans
+in its submitter's SpanBuffer, and stamps the latency histograms'
+exemplars (observability/spans.py).
 """
 
 import logging
@@ -58,8 +67,7 @@ from typing import Callable, List, Optional, Tuple
 
 import numpy as np
 
-from distributed_faiss_tpu.observability import spans as obs_spans
-from distributed_faiss_tpu.utils import lockdep, xfercheck
+from distributed_faiss_tpu.utils import lockdep, tracing, xfercheck
 from distributed_faiss_tpu.utils.atomics import AtomicCounters
 from distributed_faiss_tpu.utils.config import SchedulerCfg
 from distributed_faiss_tpu.utils.tracing import LatencyStats
@@ -91,12 +99,11 @@ class SchedulerStopped(RuntimeError):
 class _Request:
     __slots__ = ("index_id", "q", "k", "return_embeddings", "deadline",
                  "eager", "enqueue_t", "event", "result", "error",
-                 "callback", "trace_id")
+                 "callback", "ticket")
 
     def __init__(self, index_id: str, q: np.ndarray, k: int,
                  return_embeddings: bool, deadline: Optional[float],
-                 eager: bool = False, callback: Optional[Callable] = None,
-                 trace_id: Optional[str] = None):
+                 eager: bool = False, callback: Optional[Callable] = None):
         self.index_id = index_id
         self.q = q
         self.k = k
@@ -111,11 +118,15 @@ class _Request:
         # (result, error) when the request completes, instead of a thread
         # blocking on ``event``
         self.callback = callback
-        # sampled distributed trace this request belongs to (None for the
-        # unsampled default): queue-wait / coalesce / device spans are
-        # recorded against it, and it rides the latency histograms as
-        # their exemplar (observability/spans.py)
-        self.trace_id = trace_id
+        # the sampled trace the SUBMITTING thread works for (None for the
+        # unsampled default), taken from its context: the batcher thread
+        # binds it to book the queue-wait / device spans against it, and
+        # its id rides the latency histograms as their exemplar
+        self.ticket = tracing.ticket()
+
+    @property
+    def trace_id(self) -> Optional[str]:
+        return self.ticket[0] if self.ticket is not None else None
 
     @property
     def key(self) -> Tuple:
@@ -169,13 +180,9 @@ class SearchScheduler:
     """
 
     def __init__(self, search_fn: Callable, cfg: Optional[SchedulerCfg] = None,
-                 name: str = "search-batcher", tag: Optional[dict] = None,
-                 span_buffer=None):
+                 name: str = "search-batcher", tag: Optional[dict] = None):
         self._search_fn = search_fn
         self.cfg = cfg if cfg is not None else SchedulerCfg()
-        # span ring for sampled requests (the owning server's SpanBuffer):
-        # None (standalone schedulers, tracing off) records nothing
-        self.spans = span_buffer
         # replica identity riding the stats surface (replication layer):
         # admission behavior is unchanged per replica, but operators need
         # queue/shed numbers attributable to (rank, shard_group). Owned by
@@ -198,8 +205,7 @@ class SearchScheduler:
 
     def submit(self, index_id: str, query_batch: np.ndarray, top_k: int,
                return_embeddings: bool = False,
-               deadline: Optional[float] = None, eager: bool = False,
-               trace_id: Optional[str] = None):
+               deadline: Optional[float] = None, eager: bool = False):
         """Enqueue one search and block until its slice of a merged launch
         is ready. ``deadline`` is an absolute ``time.monotonic()`` instant;
         expired requests never reach the device. ``eager`` skips the
@@ -211,7 +217,7 @@ class SearchScheduler:
         still apply."""
         req = self.submit_async(index_id, query_batch, top_k,
                                 return_embeddings, deadline=deadline,
-                                eager=eager, trace_id=trace_id)
+                                eager=eager)
         # timeout-with-retry rather than one untimed wait: every admitted
         # request is eventually finished by the batcher (its loop survives
         # flush failures and stop() drains the queue) — the escape hatch
@@ -232,8 +238,7 @@ class SearchScheduler:
     def submit_async(self, index_id: str, query_batch: np.ndarray,
                      top_k: int, return_embeddings: bool = False,
                      deadline: Optional[float] = None, eager: bool = False,
-                     callback: Optional[Callable] = None,
-                     trace_id: Optional[str] = None) -> _Request:
+                     callback: Optional[Callable] = None) -> _Request:
         """Admission-checked enqueue that returns immediately (the mux
         serving loops' entry: the connection reader must keep pulling
         frames). ``callback(result, error)`` fires exactly once — on the
@@ -246,8 +251,7 @@ class SearchScheduler:
         if q.ndim != 2:
             raise ValueError(f"query batch must be 2-D, got shape {q.shape}")
         req = _Request(index_id, q, int(top_k), bool(return_embeddings),
-                       deadline, eager=eager, callback=callback,
-                       trace_id=trace_id)
+                       deadline, eager=eager, callback=callback)
         with self._cond:
             if self._stopping:
                 raise SchedulerStopped("scheduler is stopped")
@@ -330,7 +334,8 @@ class SearchScheduler:
                     # bounds the window in which a lost/raced notify (or
                     # an interpreter bug) could strand the batcher — the
                     # loop re-checks the queue and stop flag each lap
-                    self._cond.wait(timeout=1.0)
+                    with tracing.stage("sched.idle", sink=self.stats):
+                        self._cond.wait(timeout=1.0)
                     continue
                 head = self._queue[0]
                 rows = sum(r.rows for r in self._queue if r.key == head.key)
@@ -338,7 +343,8 @@ class SearchScheduler:
                 now = time.monotonic()
                 if (not head.eager and rows < self.cfg.max_batch_rows
                         and now < flush_at):
-                    self._cond.wait(flush_at - now)
+                    with tracing.stage("sched.window_wait", sink=self.stats):
+                        self._cond.wait(flush_at - now)
                     continue
                 # pop whole compatible requests until the row budget is
                 # reached; a single over-budget request still goes alone
@@ -356,87 +362,100 @@ class SearchScheduler:
                 return taken
 
     def _serve(self, batch: List[_Request]) -> None:
-        now = time.monotonic()
-        live: List[_Request] = []
-        for r in batch:
-            if r.deadline is not None and now >= r.deadline:
-                # shed without touching the device; the device batch only
-                # carries rows someone is still waiting for
-                self._counters.inc("shed_deadline")
-                r.error = DeadlineExpired(
-                    "deadline expired while queued "
-                    f"(waited {now - r.enqueue_t:.3f}s)")
-                self._finish(r)
-                continue
-            self.stats.record("queue_wait_s", now - r.enqueue_t,
-                              exemplar=r.trace_id)
-            live.append(r)
-        if not live:
-            return
-        window = self._counters.inc("batches")
-        n_rows = sum(r.rows for r in live)
-        self.stats.record("batch_requests", float(len(live)))
-        self.stats.record("batch_rows", float(n_rows))
-        traced = ([r for r in live if r.trace_id is not None]
-                  if self.spans is not None else [])
-        if traced:
-            # one queue span per sampled request: which merge window it
-            # landed in and that window's occupancy — the "why did my
-            # request wait / what did it share a launch with" answer
-            now_w = time.time()
-            for r in traced:
-                waited = now - r.enqueue_t
-                self.spans.record(
-                    r.trace_id, "server.queue", now_w - waited, waited,
-                    window=window, occupancy_requests=len(live),
-                    occupancy_rows=n_rows)
-        head = live[0]
-        try:
-            qcat = head.q if len(live) == 1 else _concat_rows(live, n_rows)
-            if traced:
-                # hand the engine a representative trace for the launch
-                # (the whole window IS one device program, so one span
-                # per sampled request below shares its timing)
-                obs_spans.set_current_trace(traced[0].trace_id)
-                launch_w0, launch_p0 = time.time(), time.perf_counter()
+        """One window: assemble, launch, split. An ``Exception`` fails the
+        window's callers here; anything else (a BaseException out of the
+        engine or the split) is the batcher loop's to catch, which
+        finishes every request of the batch (``_finish`` publishes once)."""
+        error = None
+        with tracing.stage("sched.assemble", sink=self.stats):
+            now = time.monotonic()
+            live: List[_Request] = []
+            for r in batch:
+                if r.deadline is not None and now >= r.deadline:
+                    # shed without touching the device; the device batch
+                    # only carries rows someone is still waiting for
+                    self._counters.inc("shed_deadline")
+                    r.error = DeadlineExpired(
+                        "deadline expired while queued "
+                        f"(waited {now - r.enqueue_t:.3f}s)")
+                    self._finish(r)
+                    continue
+                live.append(r)
+            if not live:
+                return
+            window = self._counters.inc("batches")
+            n_rows = sum(r.rows for r in live)
+            self.stats.record("batch_requests", float(len(live)))
+            self.stats.record("batch_rows", float(n_rows))
+            p_now = tracing.now()
+            for r in live:
+                # the queue wait, and for a sampled request which merge
+                # window it landed in and that window's occupancy — the
+                # "why did my request wait / what did it share a launch
+                # with" answer
+                with tracing.bind(r.ticket):
+                    tracing.book(
+                        "server.queue", p_now - (now - r.enqueue_t),
+                        sink=self.stats, counter="queue_wait_s",
+                        window=window, occupancy_requests=len(live),
+                        occupancy_rows=n_rows)
+            head = live[0]
             try:
-                # DFT_XFERCHECK=1 arms jax's transfer guard for the whole
-                # merged-window launch: any implicit host<->device copy in
-                # the flush fails the provoking request with provenance
-                with xfercheck.guarded("scheduler merge-window flush"):
-                    result = self._search_fn(
-                        head.index_id, qcat, head.k, head.return_embeddings)
-            finally:
-                if traced:
-                    launch_dt = time.perf_counter() - launch_p0
-                    obs_spans.set_current_trace(None)
-                    for r in traced:
-                        self.spans.record(
-                            r.trace_id, "server.device", launch_w0,
-                            launch_dt, window=window, rows=n_rows)
-            if not isinstance(result, tuple):
-                result = (result,)
-            offsets, ofs = [], 0
-            for r in live:
-                offsets.append((ofs, ofs + r.rows))
-                ofs += r.rows
-            per_elem = [_split_rows(v, offsets) for v in result]
-            for i, r in enumerate(live):
-                r.result = tuple(elem[i] for elem in per_elem)
-        except Exception as exc:
-            # one application error fails exactly the callers whose rows
-            # shared the launch — never the rest of the queue. Each caller
-            # gets its OWN exception object: submit() re-raises from N
-            # threads concurrently, and raising one shared instance races
-            # on its __traceback__ (interleaved frames in error reports).
-            for r in live:
+                qcat = head.q if len(live) == 1 else _concat_rows(live, n_rows)
+            except Exception as exc:
+                error = exc
+        result = None
+        if error is None:
+            # the whole window IS one device program: the engine's stages
+            # nest under one representative sampled request, and every
+            # other sampled request of the window gets the launch's span
+            # echoed into its own trace
+            traced = [r.ticket for r in live if r.ticket is not None]
+            try:
+                with tracing.bind(traced[0] if traced else None), \
+                        tracing.stage("server.device", sink=tracing.SPAN_ONLY,
+                                      window=window, rows=n_rows) as launch:
+                    # DFT_XFERCHECK=1 arms jax's transfer guard for the
+                    # whole merged-window launch: any implicit
+                    # host<->device copy in the flush fails the provoking
+                    # request with provenance
+                    with xfercheck.guarded("scheduler merge-window flush"):
+                        result = self._search_fn(
+                            head.index_id, qcat, head.k,
+                            head.return_embeddings)
+                for ticket in traced[1:]:
+                    launch.echo(ticket)
+            except Exception as exc:
+                error = exc
+        with tracing.stage("sched.split", sink=self.stats):
+            if error is None:
                 try:
-                    err = type(exc)(*exc.args)
-                except Exception:
-                    err = RuntimeError(f"scheduled search failed: {exc!r}")
-                err.__cause__ = exc
-                r.error = err
-        finally:
+                    if not isinstance(result, tuple):
+                        result = (result,)
+                    offsets, ofs = [], 0
+                    for r in live:
+                        offsets.append((ofs, ofs + r.rows))
+                        ofs += r.rows
+                    per_elem = [_split_rows(v, offsets) for v in result]
+                    for i, r in enumerate(live):
+                        r.result = tuple(elem[i] for elem in per_elem)
+                except Exception as exc:
+                    error = exc
+            if error is not None:
+                # one application error fails exactly the callers whose
+                # rows shared the launch — never the rest of the queue.
+                # Each caller gets its OWN exception object: submit()
+                # re-raises from N threads concurrently, and raising one
+                # shared instance races on its __traceback__ (interleaved
+                # frames in error reports).
+                for r in live:
+                    try:
+                        err = type(error)(*error.args)
+                    except Exception:
+                        err = RuntimeError(
+                            f"scheduled search failed: {error!r}")
+                    err.__cause__ = error
+                    r.result, r.error = None, err
             for r in live:
                 self._finish(r)
 

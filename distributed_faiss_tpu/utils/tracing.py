@@ -15,14 +15,17 @@ The reference has none of this beyond log lines (SURVEY §5.1); here:
   ``LatencyStats.delta`` diffs two summaries so rate computation (the
   dfstat CLI's ``--watch`` view) is shared library code, not ad-hoc CLI
   math.
-- ``traced``        — context manager stamping a jax.named_scope (visible in
-  xprof/tensorboard traces) and recording wall time into a LatencyStats.
-- ``profile_trace`` — wrapper around jax.profiler for capturing device
-  traces around a code block (TPU xprof dumps).
+- ``stage`` / ``book`` — THE way a timed site books its interval (the
+  stage ledger, docs/OPERATIONS.md#stage-ledger): one call records the
+  counter (always, into a ``LatencyStats``), a profiler event (rank
+  processes, so the stage sits in a profiler session's ``.xplane.pb`` on
+  the clock of the device's ``XLA Ops`` line) and, for a sampled request,
+  a span with its own id and its parent's. ``bind`` / ``ticket`` hand the
+  sampled request from thread to thread.
 """
 
 import bisect
-import contextlib
+import os
 import threading
 import time
 from typing import Dict, Optional
@@ -197,25 +200,228 @@ class LatencyStats:
             self._exemplars.clear()
 
 
-@contextlib.contextmanager
-def traced(name: str, stats: Optional[LatencyStats] = None):
-    """Named scope (xprof-friendly) + optional latency recording."""
-    import jax
+# ------------------------------------------------------------ stage ledger
+#
+# One thread-local context says which sampled request (if any) the thread
+# is working for and where its counters go; ``stage`` reads it, so no
+# ``trace_id=`` / ``span_buffer=`` parameter rides a signature for
+# tracing's sake. Costs with sampling off and no profiler session open
+# (CPU host, measured): about 0.5 us an annotation, 1.5 us a ``record``.
 
-    t0 = time.perf_counter()
-    with jax.named_scope(name):
-        yield
-    if stats is not None:
-        stats.record(name, time.perf_counter() - t0)
+now = time.perf_counter  # the one monotonic clock stage timing reads
+
+# The launch loop's ledger: every second of a rank's batcher thread between
+# two window ends is inside exactly one of these stages (serving/scheduler.py,
+# engine.py, models/base.py), so their totals add up to the thread's wall
+# clock. ``server.device`` (the engine call) and ``engine.launch`` (launch to
+# fetch, counter ``device_search_s``) are subtotals that contain some of them.
+LAUNCH_LOOP = (
+    "sched.idle", "sched.window_wait", "sched.assemble",
+    "engine.lock_wait", "engine.feed", "engine.scan", "engine.refine_fetch",
+    "engine.join", "sched.split",
+)
 
 
-@contextlib.contextmanager
-def profile_trace(log_dir: str):
-    """Capture a jax profiler trace (view with tensorboard/xprof)."""
-    import jax
+class _Context(threading.local):
+    trace_id = None  # the sampled request's id; None = not sampled
+    parent = None  # span id the next span booked on this thread hangs under
+    spans = None  # SpanBuffer the spans land in; None = the process-local one
+    sink = None  # LatencyStats the counters land in
 
-    jax.profiler.start_trace(log_dir)
-    try:
-        yield
-    finally:
-        jax.profiler.stop_trace()
+
+class _NoCounter:
+    """``sink=SPAN_ONLY``: the stage books its span and profiler event and
+    no counter — for an interval whose counter nobody reads (a non-search
+    op's pack, a subtotal), so the exporter carries no row for it."""
+
+    @staticmethod
+    def record(name, seconds, exemplar=None) -> None:
+        pass
+
+
+SPAN_ONLY = _NoCounter()
+
+_ctx = _Context()
+_annotation = None  # jax.profiler.TraceAnnotation, once a rank asked for it
+
+
+# process-wide rows with no layer to own them: ``xla.compile`` (one record
+# an XLA backend compile, cache hits included — they cost tens of ms too)
+PROCESS = LatencyStats()
+_COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
+
+
+def _on_jax_duration(event: str, duration: float, **_kw) -> None:
+    if event == _COMPILE_EVENT:
+        PROCESS.record("xla.compile", duration)
+
+
+def annotate_stages() -> None:
+    """Rank processes call this (``IndexServer.__init__``; once counts):
+    from then on every stage is also a ``jax.profiler.TraceAnnotation``, a
+    no-op until a profiler session is open in the process, and every XLA
+    compile is counted in ``PROCESS``'s ``xla.compile`` row (served by
+    ``get_perf_stats``), so a window's compiles are count after less count
+    before. A client process never calls it and never imports jax for
+    tracing."""
+    global _annotation
+    if _annotation is None:
+        import jax.monitoring
+        from jax.profiler import TraceAnnotation
+
+        jax.monitoring.register_event_duration_secs_listener(_on_jax_duration)
+        _annotation = TraceAnnotation
+
+
+def compile_row(raw: bool = False) -> dict:
+    """The ``xla.compile`` row, zeros before the first compile (0 compiles
+    is a value, not a missing row)."""
+    return PROCESS.summary(raw=raw).get(
+        "xla.compile", {"count": 0, "total_s": 0.0, "max_s": 0.0,
+                        "mean_s": 0.0})
+
+
+def ticket() -> Optional[tuple]:
+    """The sampled request this thread works for, as ``(trace_id, parent
+    span id, span buffer)`` to ``bind`` on another thread; None when the
+    request is not sampled."""
+    c = _ctx
+    return None if c.trace_id is None else (c.trace_id, c.parent, c.spans)
+
+
+class bind:
+    """Work for ``ticket``'s request inside the block (None: change
+    nothing); the thread's previous context comes back on exit."""
+
+    __slots__ = ("_ticket", "_old")
+
+    def __init__(self, ticket: Optional[tuple]):
+        self._ticket = ticket
+
+    def __enter__(self):
+        if self._ticket is not None:
+            c = _ctx
+            self._old = (c.trace_id, c.parent, c.spans)
+            c.trace_id, c.parent, c.spans = self._ticket
+        return self
+
+    def __exit__(self, *exc):
+        if self._ticket is not None:
+            c = _ctx
+            c.trace_id, c.parent, c.spans = self._old
+
+
+def _span_buffer(spans):
+    if spans is not None:
+        return spans
+    from distributed_faiss_tpu.observability.spans import local_buffer
+
+    return local_buffer()
+
+
+def new_span_id() -> str:
+    return os.urandom(4).hex()
+
+
+def book(name: str, t0: float, sink: Optional[LatencyStats] = None,
+         counter: Optional[str] = None, span_id: Optional[str] = None,
+         **extra) -> float:
+    """Book the interval from ``t0`` (a ``now()`` reading, possibly taken
+    on another thread) to this instant under ``name``: the counter into
+    ``sink`` (default: the context's) and, for a sampled request, a span
+    under the context's parent. For waits that no ``with`` block can
+    enclose — a queue, a hand-over between threads. ``counter`` names the
+    counter row where an older name has readers; ``span_id`` is the id
+    minted earlier for a span whose children were booked before it.
+    Returns the seconds."""
+    dt = now() - t0
+    c = _ctx
+    sink = sink if sink is not None else c.sink
+    if sink is not None:
+        sink.record(counter or name, dt, exemplar=c.trace_id)
+    if c.trace_id is not None:
+        _span_buffer(c.spans).record(c.trace_id, name, time.time() - dt, dt,
+                               span_id=span_id or new_span_id(),
+                               parent=c.parent, **extra)
+    return dt
+
+
+class stage:
+    """``with stage("sched.assemble"):`` books the block's wall time.
+
+    - counter, always: ``sink.record(name, dt)``; an explicit ``sink``
+      also becomes the context's for the stages nested inside (the engine
+      hands its ``LatencyStats`` down to the model's stages this way);
+    - profiler event, in a rank process (``annotate_stages``);
+    - span, when the thread works for a sampled request: ``span_id``,
+      ``parent`` (the enclosing stage, or the id that crossed the wire),
+      and stages nested inside hang under this one.
+
+    ``done()`` ends the stage before the block does (a lock wait ends
+    where the lock is taken: ``with stage(..) as st, lock: st.done()``).
+    A block that raises books nothing: a failure's wait ceiling must not
+    land in a latency row. ``dt`` holds the seconds afterwards."""
+
+    __slots__ = ("name", "sink", "counter", "extra", "dt", "_t0", "_w0",
+                 "_ann", "_span_id", "_parent", "_old_sink", "_open")
+
+    def __init__(self, name: str, sink: Optional[LatencyStats] = None,
+                 counter: Optional[str] = None, **extra):
+        self.name = name
+        self.sink = sink
+        self.counter = counter
+        self.extra = extra
+        self.dt = 0.0
+
+    def __enter__(self):
+        c = _ctx
+        self._old_sink = c.sink
+        if self.sink is None:
+            self.sink = c.sink
+        else:
+            c.sink = self.sink
+        self._span_id = None
+        if c.trace_id is not None:
+            self._span_id = new_span_id()
+            self._parent, c.parent = c.parent, self._span_id
+            self._w0 = time.time()
+        self._ann = None
+        if _annotation is not None:
+            self._ann = _annotation(self.name)
+            self._ann.__enter__()
+        self._open = True
+        self._t0 = now()
+        return self
+
+    def done(self, failed: bool = False) -> None:
+        if not self._open:
+            return
+        self._open = False
+        self.dt = dt = now() - self._t0
+        if self._ann is not None:
+            self._ann.__exit__(None, None, None)
+        c = _ctx
+        c.sink = self._old_sink
+        if self._span_id is not None:
+            c.parent = self._parent
+        if failed:
+            return
+        if self.sink is not None:
+            self.sink.record(self.counter or self.name, dt,
+                             exemplar=c.trace_id)
+        if self._span_id is not None:
+            _span_buffer(c.spans).record(c.trace_id, self.name, self._w0, dt,
+                                   span_id=self._span_id,
+                                   parent=self._parent, **self.extra)
+
+    def echo(self, ticket: tuple) -> None:
+        """Record this finished stage's span again in another sampled
+        request's trace: a merged window is ONE launch for every request
+        in it (the stage must have run for a sampled request itself)."""
+        trace_id, parent, spans = ticket
+        _span_buffer(spans).record(
+            trace_id, self.name, self._w0, self.dt, span_id=new_span_id(),
+            parent=parent, **self.extra)
+
+    def __exit__(self, exc_type, exc, tb):
+        self.done(failed=exc_type is not None)

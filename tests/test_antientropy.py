@@ -19,6 +19,7 @@ from multiprocessing.dummy import Pool as ThreadPool
 import numpy as np
 import pytest
 
+from distributed_faiss_tpu.utils import tracing
 from distributed_faiss_tpu.engine import Index
 from distributed_faiss_tpu.mutation.tombstones import TombstoneSet, id_match_key
 from distributed_faiss_tpu.parallel import antientropy, replication, rpc
@@ -371,6 +372,7 @@ def make_client(stubs, rcfg=None, groups=None):
     c.retry = rpc.RetryPolicy(max_attempts=2, base_delay=0.001, jitter=0.0)
     c._stats_lock = lockdep.lock("IndexClient._stats_lock")
     c.reroutes = deque(maxlen=REROUTE_LOG_LEN)
+    c.stats = tracing.LatencyStats()
     c.counters = AtomicCounters(
                   ("reroutes", "failovers", "under_replicated", "quorum_failures"))
     c.rcfg = rcfg or ReplicationCfg()

@@ -154,10 +154,10 @@ def test_fused_engages_only_past_one_block(rng, small_blocks):
 
     orig = idx._search_blocks
 
-    def spy(q, k, fn, block=256, fused_fn=None):
+    def spy(q, k, fn, block=256, fused_fn=None, **kw):
         if fused_fn is not None and np.asarray(q).shape[0] > block:
             called["fused"] += 1
-        return orig(q, k, fn, block=block, fused_fn=fused_fn)
+        return orig(q, k, fn, block=block, fused_fn=fused_fn, **kw)
 
     idx._search_blocks = spy
     idx.search(rng.standard_normal((8, d)).astype(np.float32), 3)

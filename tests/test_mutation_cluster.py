@@ -12,6 +12,7 @@ from multiprocessing.pool import ThreadPool
 import numpy as np
 import pytest
 
+from distributed_faiss_tpu.utils import tracing
 from distributed_faiss_tpu.parallel import replication, rpc
 from distributed_faiss_tpu.parallel.client import (
     IndexClient,
@@ -78,6 +79,7 @@ def make_client(stubs, rcfg=None, groups=None):
     c.retry = rpc.RetryPolicy(max_attempts=2, base_delay=0.001, jitter=0.0)
     c._stats_lock = lockdep.lock("IndexClient._stats_lock")
     c.reroutes = deque(maxlen=REROUTE_LOG_LEN)
+    c.stats = tracing.LatencyStats()
     c.counters = AtomicCounters(
                   ("reroutes", "failovers", "under_replicated", "quorum_failures"))
     c.rcfg = rcfg or ReplicationCfg()

@@ -249,7 +249,8 @@ def test_traced_mux_client_vs_legacy_server():
     meta = srv.metas[0]
     assert meta["trace_id"] == tid and "req_id" in meta
     local = spans.local_buffer().snapshot(tid)
-    assert {s["name"] for s in local} == {"client.pack", "client.rpc"}
+    assert {s["name"] for s in local} == {"client.pack", "client.send",
+                                          "client.rpc"}
     client.close()
 
 
@@ -262,8 +263,14 @@ def test_traced_serial_client_vs_traced_server(tmp_path):
     client = rpc.Client(1, "localhost", port, mux=False)
     tid = spans.mint_trace_id()
     client.generic_fun("search", ("obs", x[:3], 4), trace_id=tid)
-    names = {s["name"] for s in srv.spans.snapshot(tid)}
-    assert {"server.queue", "server.device", "server.write"} <= names
+    want = {"server.queue", "server.device", "server.write"}
+    deadline = time.time() + 5  # server.write ends after the reply is read
+    while True:
+        names = {s["name"] for s in srv.spans.snapshot(tid)}
+        if want <= names or time.time() > deadline:
+            break
+        time.sleep(0.01)
+    assert want <= names
     client.close()
 
 

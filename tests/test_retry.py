@@ -11,6 +11,7 @@ from multiprocessing.dummy import Pool as ThreadPool
 import numpy as np
 import pytest
 
+from distributed_faiss_tpu.utils import tracing
 from distributed_faiss_tpu.parallel import replication, rpc
 from distributed_faiss_tpu.parallel.client import (
     REROUTE_LOG_LEN,
@@ -164,6 +165,7 @@ def make_client(stubs, retry=None, replication_cfg=None):
                                        jitter=0.0)
     c._stats_lock = lockdep.lock("IndexClient._stats_lock")
     c.reroutes = deque(maxlen=REROUTE_LOG_LEN)
+    c.stats = tracing.LatencyStats()
     c.counters = AtomicCounters(
                   ("reroutes", "failovers", "under_replicated", "quorum_failures"))
     c.rcfg = replication_cfg or ReplicationCfg()

@@ -14,6 +14,7 @@ from multiprocessing.dummy import Pool as ThreadPool
 import numpy as np
 import pytest
 
+from distributed_faiss_tpu.utils import tracing
 from distributed_faiss_tpu.engine import Index
 from distributed_faiss_tpu.mutation import tombstones, versions
 from distributed_faiss_tpu.mutation.tombstones import TombstoneSet
@@ -623,6 +624,7 @@ def make_client(stubs, rcfg=None, vcfg=None):
     from collections import deque
 
     c.reroutes = deque(maxlen=8)
+    c.stats = tracing.LatencyStats()
     c.counters = AtomicCounters(
                   ("reroutes", "failovers", "under_replicated", "quorum_failures"))
     c.rcfg = rcfg or ReplicationCfg()
