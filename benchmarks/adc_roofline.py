@@ -1,9 +1,11 @@
 """ADC kernel roofline: measured throughput vs v5e peaks, per variant.
 
 VERDICT r2 missing #3: nothing quantified device utilization for the kernel
-SURVEY §7 says "decides IVF-PQ QPS". This script times the three ADC
-implementations (XLA one-hot einsum, Pallas one-hot, Pallas nibble) at the
-flagship geometry and prints, per variant:
+SURVEY §7 says "decides IVF-PQ QPS". This script times the ADC
+implementations (XLA one-hot einsum, Pallas one-hot, Pallas nibble, Pallas
+three-plane one-hot) at the flagship geometry and at the benchmark cells' own
+(``knnlm``: one table a (query, probe) pair, 128 / 2048 / 8192 pairs of
+capacity-1024 lists) and prints, per variant:
 
   - codes/s (candidate rows x m scored per second)
   - achieved HBM bytes/s for the true input traffic (codes + lut + out)
@@ -45,9 +47,7 @@ def bench(fn, *args, warmup=2, iters=8):
 
 def main():
     import jax
-    import jax.numpy as jnp
 
-    from distributed_faiss_tpu.ops import adc_pallas, pq
     from distributed_faiss_tpu.utils import envutil
 
     envutil.place_compile_cache()
@@ -58,9 +58,21 @@ def main():
             f"(known: {sorted(PEAKS)}); nothing to measure against\n")
         return 1
     peaks = PEAKS[device_kind]
+    # flagship knnlm-like geometry (per-(query,probe) lists, m=64), then the
+    # cells' lists (capacity 1024) at the pair counts ISSUE 25 named: a loop
+    # step of the served scan holds 128 to 512 pairs, and the kernels' rates
+    # a row are flat in the pair count (PERF.md, PR 25)
+    for nq, L in ((256, 4096), (128, 1024), (2048, 1024), (8192, 1024)):
+        one_geometry(nq, 64, 256, L, device_kind, peaks)
+    return 0
+
+
+def one_geometry(nq, m, ksub, L, device_kind, peaks):
+    import jax.numpy as jnp
+
+    from distributed_faiss_tpu.ops import adc_pallas, pq
+
     rng = np.random.default_rng(0)
-    # flagship knnlm-like geometry: per-(query,probe) lists, m=64
-    nq, m, ksub, L = 256, 64, 256, 4096
     lut = jnp.asarray(rng.standard_normal((nq, m, ksub)).astype(np.float32))
     lut_bf16 = lut.astype(jnp.bfloat16)
     codes = jnp.asarray(rng.integers(0, 256, (nq, L, m)).astype(np.uint8))
@@ -80,6 +92,9 @@ def main():
          lambda: adc_pallas.adc_scan_pallas_nibble(lut, codes)),
         ("pallas-nibble-bf16",
          lambda: adc_pallas.adc_scan_pallas_nibble(lut_bf16, codes)),
+        # f32 table values as three bf16 planes, bf16 one-hot, one MXU pass
+        ("pallas-planes-f32",
+         lambda: adc_pallas.adc_scan_pallas_planes(lut, codes)),
     ]
 
     for name, fn in variants:
@@ -92,6 +107,7 @@ def main():
         lut_bytes = lut_bytes_f32 // (2 if "bf16" in name else 1)
         true_bytes = code_bytes + lut_bytes + out_bytes
         onehot_factor = 16 if "nibble" in name else ksub
+        onehot_bytes = 2 if "bf16" in name or "planes" in name else 4
         row = {
             "variant": name,
             "device_kind": device_kind,
@@ -102,12 +118,9 @@ def main():
             "true_gbs": round(true_bytes / dt / 1e9, 2),
             "hbm_pct": round(100 * true_bytes / dt / 1e9 / peaks["hbm_gbs"], 2),
             "onehot_store_gbs": round(
-                rows * m * onehot_factor * (2 if "bf16" in name else 4) / dt / 1e9, 1),
+                rows * m * onehot_factor * onehot_bytes / dt / 1e9, 1),
         }
         print(json.dumps(row), flush=True)
-
-
-    return 0
 
 
 if __name__ == "__main__":

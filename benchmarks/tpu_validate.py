@@ -9,6 +9,9 @@ emit, and asserts parity with a plain numpy reference:
   - ADC one-hot, shared-list and per-query (the probed-lists path), at the
     knnlm geometry (m=64, ksub=256) and a small one, fp32 and bf16 LUTs
   - ADC nibble at m=64 and m=8, fp32 and bf16 LUTs
+  - ADC three-plane one-hot (fp32 table values in one bf16 MXU pass) at the
+    benchmark cells' geometry (m=64, lists of capacity 1024) and at m=8,
+    on tables whose entries need all three planes
   - fused flat list scan for the f32 / f16 / sq8 codecs x l2 / dot at
     d=512 (the ivfsq width), plus the bf16 scan mode
 
@@ -28,7 +31,7 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 def np_adc_shared(lut, codes):
     nq, L = lut.shape[0], codes.shape[0]
-    out = np.zeros((nq, L), np.float32)
+    out = np.zeros((nq, L), np.float64)  # the golden adds in float64
     for mi in range(codes.shape[1]):
         out += lut[:, mi, codes[:, mi].astype(np.int64)]
     return out
@@ -36,7 +39,7 @@ def np_adc_shared(lut, codes):
 
 def np_adc_per_query(lut, codes):
     nq, L = codes.shape[0], codes.shape[1]
-    out = np.zeros((nq, L), np.float32)
+    out = np.zeros((nq, L), np.float64)
     for qi in range(nq):
         out[qi] = np_adc_shared(lut[qi:qi + 1], codes[qi])[0]
     return out
@@ -56,9 +59,11 @@ def np_flat_scan(q, data, ids, sizes, li, metric, norms):
 
 
 def _report(name, got, want, dt, rtol, atol, **extra):
+    """``atol`` may be an array: a bound of its own for every element."""
     finite = np.isfinite(want)
+    bound = np.broadcast_to(atol, want.shape)[finite] + rtol * np.abs(want[finite])
     ok = bool(np.array_equal(finite, np.isfinite(got))
-              and np.allclose(got[finite], want[finite], rtol=rtol, atol=atol))
+              and np.all(np.abs(got[finite] - want[finite]) <= bound))
     err = float(np.max(np.abs(got[finite] - want[finite]))) if finite.any() else 0.0
     print(json.dumps({
         "case": name, **extra, "compiled": True, "max_abs_err": round(err, 7),
@@ -86,16 +91,35 @@ def adc_cases(rng):
         ("nibble_m8", "nibble", 16, 8, 1024, "float32"),
         ("nibble_m8_bf16", "nibble", 16, 8, 1024, "bfloat16"),
         ("nibble_ragged_L", "nibble", 8, 64, 700, "float32"),
+        # the served knnlm scan: one table a (query, probe) pair, capacity
+        # 1024; a wrong split or a dropped plane shows only compiled (the
+        # interpreter multiplies in f32: PR 21's nibble finding)
+        ("planes_knnlm_cell", "planes", 128, 64, 1024, "float32"),
+        ("planes_exact_grid", "planes-grid", 16, 8, 1024, "float32"),
+        ("planes_knnlm_wide", "planes-wide", 32, 64, 1024, "float32"),
+        ("planes_m8_cap128", "planes-wide", 16, 8, 128, "float32"),
     ]
     kernels = {
         "shared": adc_pallas.adc_scan_shared_pallas,
         "onehot": adc_pallas.adc_scan_pallas,
         "nibble": adc_pallas.adc_scan_pallas_nibble,
+        "planes": adc_pallas.adc_scan_pallas_planes,
     }
     for name, kind, nq, m, L, dtype in cases:
         ksub = 256
-        lut = jnp.asarray(rng.standard_normal((nq, m, ksub)).astype(np.float32),
-                          dtype=dtype)
+        kind, _, table_kind = kind.partition("-")
+        table = rng.standard_normal((nq, m, ksub))
+        if table_kind == "wide":
+            # magnitudes 1e-3 to 1e3, both signs: every entry needs its
+            # mid and lo planes, and the sums cancel
+            table = np.sign(table) * 10.0 ** rng.uniform(-3, 3, table.shape)
+        elif table_kind == "grid":
+            # 20 significant bits (all three planes) on a 2**-13 grid under
+            # 2**7: f32 holds every sum of m=8 exactly, so whatever order
+            # the MXU adds in the result is the golden bit for bit — a
+            # dropped or rounded plane cannot hide in accumulation noise
+            table = rng.integers(-(1 << 20), 1 << 20, table.shape) * 2.0 ** -13
+        lut = jnp.asarray(table.astype(np.float32), dtype=dtype)
         shape = (L, m) if kind == "shared" else (nq, L, m)
         codes = rng.integers(0, ksub, shape).astype(np.uint8)
         t0 = time.time()
@@ -104,7 +128,16 @@ def adc_cases(rng):
         # the golden sums the LUT the kernel was given (bf16-rounded or not)
         lut_np = np.asarray(lut.astype(jnp.float32))
         want = (np_adc_shared if kind == "shared" else np_adc_per_query)(lut_np, codes)
-        failures += _report(name, got, want, dt, 1e-4, 1e-4,
+        rtol, atol = 1e-4, 1e-4
+        if table_kind == "grid":
+            rtol = atol = 0.0
+        elif table_kind == "wide":
+            # entries of 1e3 leave f32 sums of m terms an error of up to
+            # m * 2**-24 of the summed magnitudes, under HIGHEST as here
+            mag = (np_adc_shared if kind == "shared" else np_adc_per_query)(
+                np.abs(lut_np), codes)
+            atol = atol + m * 2.0 ** -24 * mag
+        failures += _report(name, got, want, dt, rtol, atol,
                             nq=nq, m=m, L=L, lut=dtype)
     return failures
 

@@ -1765,8 +1765,15 @@ class Index:
         one-launch serving contract means max_s == 1.0) and
         ``rows_per_launch`` (window occupancy per dispatch). Served
         through IndexServer.get_perf_stats under ``"engine"``; ``raw``
-        adds the bucket histograms (the Prometheus exporter's view)."""
-        return self.perf.summary(raw=raw)
+        adds the bucket histograms (the Prometheus exporter's view).
+        ``engine.scan_fused`` counts the ``engine.scan`` blocks whose scan
+        ran the fused Pallas ADC kernel (models/ivf.py books it); it stands
+        at zero beside ``engine.scan`` until one does, so a scan that fell
+        back to XLA reads 0 of n and not "no such row"."""
+        out = self.perf.summary(raw=raw)
+        if "engine.scan" in out:
+            out.setdefault("engine.scan_fused", tracing.zero_row())
+        return out
 
     def get_centroids(self):
         with self.index_lock:

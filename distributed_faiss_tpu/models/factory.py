@@ -144,7 +144,8 @@ def _build_knnlm(cfg: IndexCfg):
         )
     return IVFPQIndex(cfg.dim, _centroids(cfg), m=m, nbits=nbits, metric=cfg.get_metric(),
                       kmeans_iters=_kmeans_iters(cfg),
-                      use_pallas=bool(cfg.extra.get("pallas_adc", False)),
+                      # absent: the index chooses its ADC kernel; set: forced
+                      use_pallas=cfg.extra.get("pallas_adc"),
                       refine_k_factor=int(cfg.extra.get("refine_k_factor", 0)),
                       adc_lut_bf16=bool(cfg.extra.get("adc_lut_bf16", False)))
 

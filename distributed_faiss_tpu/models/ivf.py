@@ -39,7 +39,7 @@ import numpy as np
 
 from distributed_faiss_tpu.models import base
 from distributed_faiss_tpu.ops import distance, kmeans, pq, sq
-from distributed_faiss_tpu.utils import sanitize
+from distributed_faiss_tpu.utils import sanitize, tracing, xfercheck
 
 logger = logging.getLogger()
 
@@ -242,17 +242,24 @@ def _ivf_pq_search(centroids, codebooks, list_codes, list_ids, list_sizes, q,
                 lut = jnp.broadcast_to(shared_lut[:, None], (nq, g, m, ksub))
             if use_pallas:
                 # fused VMEM kernel: per-(query, probe) LUT vs its code tile.
-                # lut_bf16 halves the kernel's LUT traffic (its speed is not
-                # measured on the chip for today's code); the one-hot side is
-                # exact in bf16 and the LUT rounding (~0.4% rel) only perturbs
-                # the ADC shortlist, which refine_k_factor rescores exactly.
+                # At f32 table values the three-plane kernel wherever its
+                # geometry holds (one bf16 MXU pass, exact: the cells' path,
+                # chip-timed in PERF.md PR 25); else the older dispatcher.
+                # lut_bf16 rounds the table itself (~0.4% rel), which only
+                # perturbs the ADC shortlist that refine_k_factor rescores
+                # exactly; its one-hot side is exact in bf16.
                 from distributed_faiss_tpu.ops import adc_pallas
 
-                s = adc_pallas.adc_scan_auto(
-                    lut.reshape(nq * g, m, ksub).astype(
-                        jnp.bfloat16 if lut_bf16 else jnp.float32),
-                    codes.reshape(nq * g, cap, m),
-                ).reshape(nq, g, cap)
+                lut2 = lut.reshape(nq * g, m, ksub)
+                codes2 = codes.reshape(nq * g, cap, m)
+                if not lut_bf16 and adc_pallas.planes_supported(m, ksub, cap):
+                    s = adc_pallas.adc_scan_pallas_planes(
+                        lut2, codes2, interpret=not adc_pallas.on_tpu())
+                else:
+                    s = adc_pallas.adc_scan_auto(
+                        lut2.astype(jnp.bfloat16 if lut_bf16 else jnp.float32),
+                        codes2)
+                s = s.reshape(nq, g, cap)
             else:
                 iota = jnp.arange(ksub, dtype=jnp.int32)
                 onehot = (codes[..., None].astype(jnp.int32) == iota).astype(jnp.float32)
@@ -468,6 +475,43 @@ def clip_f16(x: np.ndarray) -> np.ndarray:
     return np.clip(np.asarray(x, np.float32), -f16max, f16max).astype(np.float16)
 
 
+def _first_use_check(index, scan, probe, kernel: str, tol: float) -> None:
+    """First-use oracle check of a pallas scan kernel: run ``scan(probe,
+    True)`` (the kernel) and ``scan(probe, False)`` (the XLA path) on one
+    tiny padded block and demote the kernel for this process
+    (``index._pallas_runtime_ok = False``, logged, shown in
+    ``ping()["kernels"]["pallas_degraded"]``) if the scores disagree past
+    ``tol`` (relative and absolute) — ``pallas_guarded`` catches a kernel
+    that raises, this one that runs and returns wrong numbers. A probe
+    where BOTH paths fail is a bad request — leave the kernel alone and let
+    the real search surface the error through pallas_guarded."""
+    try:
+        pv, _ = scan(probe, True)
+        jax.block_until_ready(pv)
+    except Exception:
+        try:
+            jax.block_until_ready(scan(probe, False))
+        except Exception:
+            return  # both failed: request/state problem, not the kernel
+        index._pallas_runtime_ok = False
+        logger.exception(
+            "pallas %s kernel failed its first-use oracle check; using the "
+            "XLA scan for the rest of this process", kernel)
+        return
+    xv, _ = scan(probe, False)
+    with xfercheck.explicit("first-use oracle check fetch"):
+        pv, xv = np.asarray(pv), np.asarray(xv)
+    finite = np.isfinite(xv)
+    if not (np.array_equal(finite, np.isfinite(pv))
+            and np.allclose(pv[finite], xv[finite], rtol=tol, atol=tol)):
+        index._pallas_runtime_ok = False
+        logger.error(
+            "pallas %s kernel disagrees with the XLA oracle on first use "
+            "(max delta %.3g); using the XLA scan", kernel,
+            float(np.max(np.abs(pv[finite] - xv[finite]))) if finite.any() else 0.0,
+        )
+
+
 class IVFFlatIndex(_IVFBase):
     """IVF with raw/fp16/sq8 vector payloads.
 
@@ -576,37 +620,8 @@ class IVFFlatIndex(_IVFBase):
         return self.norm_lists.data
 
     def _validate_flat_pallas(self, scan) -> None:
-        """First-use oracle check (mirrors the adc_pallas discipline): run
-        the pallas kernel and the XLA path on one tiny padded block and
-        demote the kernel for this process if they disagree. A probe where
-        BOTH paths fail is a bad request — leave the kernel alone and let
-        the real search surface the error through pallas_guarded."""
         self._pallas_flat_validated = True
-        try:
-            pv, _ = scan(self._pallas_probe, True)
-            jax.block_until_ready(pv)
-        except Exception:
-            try:
-                jax.block_until_ready(scan(self._pallas_probe, False))
-            except Exception:
-                return  # both failed: request/state problem, not the kernel
-            self._pallas_runtime_ok = False
-            logger.exception(
-                "pallas flat-scan kernel failed its first-use oracle check; "
-                "using the XLA scan for the rest of this process"
-            )
-            return
-        xv, _ = scan(self._pallas_probe, False)
-        pv, xv = np.asarray(pv), np.asarray(xv)
-        finite = np.isfinite(xv)
-        if not (np.array_equal(finite, np.isfinite(pv))
-                and np.allclose(pv[finite], xv[finite], rtol=1e-3, atol=1e-3)):
-            self._pallas_runtime_ok = False
-            logger.error(
-                "pallas flat-scan kernel disagrees with the XLA oracle on "
-                "first use (max delta %.3g); using the XLA scan",
-                float(np.max(np.abs(pv[finite] - xv[finite]))) if finite.any() else 0.0,
-            )
+        _first_use_check(self, scan, self._pallas_probe, "flat-scan", 1e-3)
 
     def search(self, q: np.ndarray, k: int):
         if self._n == 0:
@@ -808,6 +823,14 @@ _BOTH_FAILED_SIGS = set()
 _BOTH_FAILED_CAP = 16
 
 
+def pallas_wanted(index) -> bool:
+    """The index's kernel intent: an explicit ``use_pallas`` forces either
+    way; None leaves the choice to what the index can see of itself
+    (``IVFPQIndex._fused_adc_applies``)."""
+    want = index.use_pallas
+    return index._fused_adc_applies() if want is None else bool(want)
+
+
 def pallas_guarded(index, call, m: int, ksub: int, shape=None):
     """Run ``call(use_pallas)`` with kernel-fault attribution (ADVICE r3: a
     nibble failure must not abandon the proven one-hot kernel).
@@ -838,11 +861,12 @@ def pallas_guarded(index, call, m: int, ksub: int, shape=None):
     here, not at a later np.asarray. ``shape`` is the request's query/batch
     shape, folded into the both-failed signature (see _BOTH_FAILED_SIGS).
 
-    The flat-scan kernel (ops/flat_pallas.py) reuses this guard with
-    m=ksub=0: nibble_supported is then False, which reduces the ladder to
-    exactly "pallas kernel -> XLA oracle -> demote _pallas_runtime_ok".
+    The flat-scan kernel (ops/flat_pallas.py) and the three-plane ADC
+    kernel (no nibble rung above it) reuse this guard with m=ksub=0:
+    nibble_supported is then False, which reduces the ladder to exactly
+    "pallas kernel -> XLA oracle -> demote _pallas_runtime_ok".
     """
-    with_pallas = index.use_pallas and index._pallas_runtime_ok
+    with_pallas = pallas_wanted(index) and index._pallas_runtime_ok
     nibble_was_on = _adc_pallas.USE_NIBBLE
     epoch0 = _adc_pallas.NIBBLE_SWEEP_EPOCH
     try:
@@ -941,7 +965,8 @@ def pallas_guarded(index, call, m: int, ksub: int, shape=None):
         logger.exception(
             "pallas kernel (%s) failed on this backend; using the XLA path "
             "for the rest of this process (persisted use_pallas intent is "
-            "unchanged)", "ADC one-hot" if ksub else "flat scan",
+            "unchanged)",
+            "ADC one-hot" if ksub else getattr(index, "_PALLAS_KERNEL", "flat scan"),
         )
         index._pallas_runtime_ok = False
         return out
@@ -956,7 +981,7 @@ class IVFPQIndex(_IVFBase):
 
     def __init__(self, dim: int, nlist: int, m: int = 64, nbits: int = 8,
                  metric: str = "l2", kmeans_iters: int = 10, pq_iters: int = 15,
-                 use_pallas: bool = False, refine_k_factor: int = 0,
+                 use_pallas: Optional[bool] = None, refine_k_factor: int = 0,
                  adc_lut_bf16: bool = False):
         super().__init__(dim, nlist, metric, kmeans_iters)
         if dim % m != 0:
@@ -966,12 +991,18 @@ class IVFPQIndex(_IVFBase):
         self.m = m
         self.nbits = nbits
         self.pq_iters = pq_iters
-        self.use_pallas = use_pallas  # fused ADC kernel instead of XLA one-hot
-        # bf16 LUT inside the pallas kernel (not measured on the chip for
-        # today's code); pair with refine_k_factor to keep final scores
-        # exact. No effect on the XLA path.
+        # fused ADC kernel instead of the XLA one-hot: None = the index
+        # chooses from what it can see (_fused_adc_applies); True / False
+        # force either path (tests, A/B runs, the knnlm builder's
+        # ``pallas_adc`` extra)
+        self.use_pallas = None if use_pallas is None else bool(use_pallas)
+        # bf16 LUT inside the pallas kernel, where use_pallas is forced on
+        # (a rounded table is never the index's own choice); pair with
+        # refine_k_factor to keep final scores exact. No effect on the XLA
+        # path.
         self.adc_lut_bf16 = adc_lut_bf16
         self._pallas_runtime_ok = True  # runtime disable, not persisted
+        self._adc_validated = False  # first fused scan checked against XLA
         # refine_k_factor > 0: keep fp16 raw rows in HBM and exactly rescore
         # the top k*refine_k_factor ADC candidates (FAISS IndexRefine-style;
         # what lifts PQ configs past recall 0.95)
@@ -989,6 +1020,22 @@ class IVFPQIndex(_IVFBase):
 
     def _make_lists(self):
         return base.PaddedLists(self.nlist, (self.m,), np.uint8)
+
+    _PALLAS_KERNEL = "ADC three-plane"
+
+    def _planes_geometry(self) -> bool:
+        """The three-plane kernel takes this index's lists as they are now
+        (the capacity grows with the lists, so ask at every search)."""
+        return self.lists is not None and _adc_pallas.planes_supported(
+            self.m, 1 << self.nbits, self.lists.cap)
+
+    def _fused_adc_applies(self) -> bool:
+        """``use_pallas=None``: take the fused kernel where the code can see
+        that it applies — a TPU backend (elsewhere the kernel would run in
+        the interpreter) and a geometry the three-plane kernel compiles for
+        (ksub 256, capacity in whole 128-row tiles, a table of m inside the
+        VMEM model). Anything else runs the XLA one-hot, as before."""
+        return _adc_pallas.on_tpu() and self._planes_geometry()
 
     def train(self, x: np.ndarray) -> None:
         x = np.asarray(x, np.float32)
@@ -1018,9 +1065,20 @@ class IVFPQIndex(_IVFBase):
         # group payload: codes + ids + lut + score blocks (the one-hot feeds
         # the MXU contraction without full materialization)
         nb = base.pick_query_block(self.lists.cap * (self.m + 8) + self.m * 256 * 4)
+        # the group is sized for the block this call launches: a batch under
+        # one block pads to its own pow2 bucket, not to nb, and a probe of
+        # it gathers that much less — a short window then scans its probes
+        # in a few loop steps (one, online) and not in nprobe of them, each
+        # with its own gather, top-k merge and tens of device ops
+        rows = nb if q.shape[0] > nb else distance.bucket_size(q.shape[0])
         g = probe_group_size(
-            nprobe, pq_probe_payload_bytes(self.lists.cap, self.m, nq_block=nb))
+            nprobe, pq_probe_payload_bytes(self.lists.cap, self.m, nq_block=rows))
         adc_k = k * self.refine_k_factor if self.refine_k_factor else k
+        lut_bf16 = self.use_pallas is True and self.adc_lut_bf16
+        # the three-plane kernel has no nibble rung above it: its guard is
+        # the plain "kernel -> XLA oracle -> demote" ladder (m = ksub = 0)
+        planes = not lut_bf16 and self._planes_geometry()
+        geom = (0, 0) if planes else (self.m, self.codebooks.shape[1])
 
         def adc(b, with_pallas):
             return sanitize.maybe_checked(
@@ -1028,14 +1086,37 @@ class IVFPQIndex(_IVFBase):
                 self.centroids, self.codebooks, self.lists.data, self.lists.ids,
                 self.lists.sizes, b, k=adc_k, nprobe=nprobe, g=g,
                 metric=self.metric, use_pallas=with_pallas,
-                lut_bf16=with_pallas and self.adc_lut_bf16,
+                lut_bf16=with_pallas and lut_bf16,
             )
 
+        if (planes and not self._adc_validated and self._pallas_runtime_ok
+                and pallas_wanted(self)):
+            # first fused scan of this index (warm-up, in a served rank):
+            # its ADC scores against the XLA path's on one small block
+            self._adc_validated = True
+            _first_use_check(
+                self, adc,
+                jax.device_put(distance.pad_rows(np.asarray(q[:8], np.float32), 8)),
+                self._PALLAS_KERNEL, 1e-4)
+
+        def guarded(call, shape):
+            """pallas_guarded, plus the count row that says the block's
+            scan ran the fused kernel (``engine.scan_fused``, beside the
+            ``engine.scan`` stage this runs in): the last path tried is the
+            one whose result is served."""
+            tried = []
+
+            def attempt(with_pallas):
+                tried.append(with_pallas)
+                return call(with_pallas)
+
+            out = pallas_guarded(self, attempt, *geom, shape=shape)
+            if tried[-1]:
+                tracing.count("engine.scan_fused")
+            return out
+
         def run(b):
-            return pallas_guarded(
-                self, lambda p: adc(b, p), self.m, self.codebooks.shape[1],
-                shape=tuple(b.shape),
-            )
+            return guarded(lambda p: adc(b, p), tuple(b.shape))
 
         def refine(b, ids):
             return _rerank_exact(self.refine_store.data, b, ids, k, self.metric)
@@ -1048,16 +1129,13 @@ class IVFPQIndex(_IVFBase):
                 self.refine_store.data if self.refine_k_factor else None,
                 q3, k=k, adc_k=adc_k, nprobe=nprobe, g=g, metric=self.metric,
                 use_pallas=with_pallas,
-                lut_bf16=with_pallas and self.adc_lut_bf16,
+                lut_bf16=with_pallas and lut_bf16,
                 refine=bool(self.refine_k_factor),
             )
 
         def run_fused(q3):
             # same degrade ladder as the per-block path
-            return pallas_guarded(
-                self, lambda p: adc_fused(q3, p), self.m, self.codebooks.shape[1],
-                shape=tuple(q3.shape),
-            )
+            return guarded(lambda p: adc_fused(q3, p), tuple(q3.shape))
 
         return self._search_blocks(
             q, k, run, block=nb, fused_fn=run_fused,
@@ -1084,7 +1162,11 @@ class IVFPQIndex(_IVFBase):
             "nprobe": self.nprobe,
             "trained": self.is_trained,
             "refine_k_factor": self.refine_k_factor,
-            "use_pallas": self.use_pallas,
+            # ``pallas_adc`` is the intent as held (None = choose, True /
+            # False = forced); ``use_pallas`` stays a plain bool for the
+            # sharded subclass's loader and for older readers
+            "use_pallas": bool(self.use_pallas),
+            "pallas_adc": self.use_pallas,
             "adc_lut_bf16": self.adc_lut_bf16,
         }
         if self.is_trained:
@@ -1098,9 +1180,14 @@ class IVFPQIndex(_IVFBase):
 
     @classmethod
     def from_state_dict(cls, state) -> "IVFPQIndex":
+        # a snapshot from before ``pallas_adc`` holds use_pallas only, and
+        # its False was the default of the time, not a choice: it loads as
+        # "choose" and must not pin the XLA path; its True was asked for
+        forced = (state["pallas_adc"] if "pallas_adc" in state
+                  else (True if state.get("use_pallas") else None))
         idx = cls(int(state["dim"]), int(state["nlist"]), int(state["m"]),
                   int(state["nbits"]), str(state["metric"]),
-                  use_pallas=bool(state.get("use_pallas", False)),
+                  use_pallas=forced,
                   refine_k_factor=int(state.get("refine_k_factor", 0)),
                   adc_lut_bf16=bool(state.get("adc_lut_bf16", False)))
         idx.nprobe = int(state["nprobe"])

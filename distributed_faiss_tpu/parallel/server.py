@@ -745,7 +745,8 @@ class IndexServer:
         degraded = []
         for iid, idx in snapshot:
             tpu_index = getattr(idx, "tpu_index", None)
-            if (getattr(tpu_index, "use_pallas", False)
+            # None = the index chose the kernel itself (IVFPQIndex)
+            if (getattr(tpu_index, "use_pallas", False) is not False
                     and not getattr(tpu_index, "_pallas_runtime_ok", True)):
                 degraded.append(iid)
         return {

@@ -94,6 +94,11 @@ REGISTRY = (
      "qualname": "adc_scan_pallas_nibble",
      "trace": True, "spec": "spec_adc_scan_pallas_nibble",
      "buckets": None, "budget": 0, "hot": True},
+    {"path": "distributed_faiss_tpu/ops/adc_pallas.py",
+     "import": "distributed_faiss_tpu.ops.adc_pallas",
+     "qualname": "adc_scan_pallas_planes",
+     "trace": True, "spec": "spec_adc_scan_pallas",
+     "buckets": None, "budget": 0, "hot": True},
     # --- ops/pq.py -------------------------------------------------------
     {"path": "distributed_faiss_tpu/ops/pq.py",
      "import": "distributed_faiss_tpu.ops.pq", "qualname": "_pq_encode_block",

@@ -273,12 +273,15 @@ def annotate_stages() -> None:
         _annotation = TraceAnnotation
 
 
+def zero_row() -> dict:
+    """A row that never fired, for a count whose 0 is a value and not a
+    missing row (``xla.compile``, ``engine.scan_fused``)."""
+    return {"count": 0, "total_s": 0.0, "max_s": 0.0, "mean_s": 0.0}
+
+
 def compile_row(raw: bool = False) -> dict:
-    """The ``xla.compile`` row, zeros before the first compile (0 compiles
-    is a value, not a missing row)."""
-    return PROCESS.summary(raw=raw).get(
-        "xla.compile", {"count": 0, "total_s": 0.0, "max_s": 0.0,
-                        "mean_s": 0.0})
+    """The ``xla.compile`` row, zeros before the first compile."""
+    return PROCESS.summary(raw=raw).get("xla.compile", zero_row())
 
 
 def ticket() -> Optional[tuple]:
@@ -344,6 +347,15 @@ def book(name: str, t0: float, sink: Optional[LatencyStats] = None,
                                span_id=span_id or new_span_id(),
                                parent=c.parent, **extra)
     return dt
+
+
+def count(name: str, value: float = 1.0) -> None:
+    """Book one occurrence into the context's counter sink — a count row
+    beside the stage the thread is in (``engine.scan_fused`` inside
+    ``engine.scan``); nothing where no stage handed a sink down."""
+    sink = _ctx.sink
+    if sink is not None:
+        sink.record(name, value)
 
 
 class stage:

@@ -38,7 +38,8 @@ def _flat(codec, metric, d, nq, g, cap, nlist=1024, scan_bf16=False):
 def _adc(kind, m, lut_dtype, nq, L):
     fn = {"shared": adc_pallas.adc_scan_shared_pallas,
           "onehot": adc_pallas.adc_scan_pallas,
-          "nibble": adc_pallas.adc_scan_pallas_nibble}[kind]
+          "nibble": adc_pallas.adc_scan_pallas_nibble,
+          "planes": adc_pallas.adc_scan_pallas_planes}[kind]
 
     def sig(sds):
         codes = (L, m) if kind == "shared" else (nq, L, m)
@@ -69,6 +70,13 @@ def cases():
         out.append(_adc("shared", 64, lut, 32, 4096))
         for kind in ("nibble", "onehot", "shared"):
             out.append(_adc(kind, 8, lut, 64, 1024))
+    # the three-plane kernel at the benchmark cells' shapes: one table a
+    # (query, probe) pair of an online window (4 x 32), a 64-row and a
+    # 256-row window, lists of capacity 1024; and its smallest geometry
+    for pairs in (128, 2048, 8192):
+        out.append(_adc("planes", 64, "float32", pairs, 1024))
+    out.append(_adc("planes", 8, "float32", 64, 128))
+    out.append(_adc("planes", 128, "float32", 16, 1024))  # twice the cells' table
     return out
 
 

@@ -5,6 +5,7 @@ import numpy as np
 import pytest
 
 from distributed_faiss_tpu.ops import adc_pallas, pq
+from distributed_faiss_tpu.utils import tracing
 
 
 @pytest.fixture
@@ -196,6 +197,10 @@ def test_pallas_degrade_ladder(rng, monkeypatch):
     def boom(*a, **k):
         raise RuntimeError("kernel abort (injected)")
 
+    # this ladder is the older dispatcher's (adc_scan_auto: nibble, then
+    # one-hot), which a forced index still runs wherever the three-plane
+    # kernel does not take its geometry — so refuse every geometry here
+    monkeypatch.setattr(adc_pallas, "planes_supported", lambda m, ksub, L: False)
     # drop compiled variants so the injected failure is actually reached
     ivfmod._ivf_pq_search.clear_cache()
     monkeypatch.setattr(adc_pallas, "USE_NIBBLE", True)
@@ -357,3 +362,268 @@ def test_stale_executable_excuse_covers_concurrent_inflight(monkeypatch):
     adc_pallas.NIBBLE_SWEEP_EPOCH = epoch0
     assert ivfmod.pallas_guarded(idx_c, stale_exec, 8, 256) == "xla-result"
     assert idx_c._pallas_runtime_ok is False, "budget spent yet still excused"
+
+
+# ------------------------------------------------- three-plane kernel (PR 25)
+
+
+def np_adc_f64(lut, codes):
+    """float64 golden of the per-pair scan: lut (P, m, ksub), codes (P, L, m)."""
+    out = np.zeros(codes.shape[:2])
+    for mi in range(codes.shape[2]):
+        out += np.take_along_axis(lut[:, mi, :].astype(np.float64),
+                                  codes[:, :, mi].astype(np.int64), axis=1)
+    return out
+
+
+def wide_tables(rng, shape):
+    """Entries of magnitude 1e-3 to 1e3, both signs: none is a bf16 value,
+    so every one needs its mid and lo planes, and the sums cancel."""
+    return (rng.choice([-1.0, 1.0], shape)
+            * 10.0 ** rng.uniform(-3, 3, shape)).astype(np.float32)
+
+
+@pytest.mark.parametrize("m", [8, 64])
+@pytest.mark.parametrize("cap", [128, 1024])
+def test_planes_kernel_golden(rng, m, cap):
+    lut = wide_tables(rng, (3, m, 256))
+    codes = rng.integers(0, 256, (3, cap, m)).astype(np.uint8)
+    got = np.asarray(adc_pallas.adc_scan_pallas_planes(lut, codes, interpret=True))
+    np.testing.assert_allclose(got, np_adc_f64(lut, codes), rtol=1e-4, atol=1e-4)
+
+
+def test_three_planes_hold_an_f32_exactly(rng):
+    import jax.numpy as jnp
+
+    x = wide_tables(rng, (1, 4096))
+    planes = np.asarray(adc_pallas._bf16_planes(jnp.asarray(x)).astype(jnp.float32))
+    assert planes.shape == (adc_pallas._PLANE_ROWS, 4096)
+    np.testing.assert_array_equal((planes[0] + planes[1]) + planes[2], x[0])
+    assert not planes[3:].any()
+    assert planes[2].any(), "no entry needed its third plane: a weak table"
+    # two planes are not enough: the bar the kernel test holds would not
+    # tell, this does
+    assert np.abs((planes[0] + planes[1]) - x[0]).max() > 0
+
+
+def test_planes_kernel_is_exact_where_f32_sums_are(rng):
+    """Entries on a 2**-13 grid under 2**7 (20 significant bits: all three
+    planes) whose m=8 sums f32 holds exactly: the kernel must return the
+    golden bit for bit, whatever order the MXU adds in."""
+    lut = (rng.integers(-(1 << 20), 1 << 20, (2, 8, 256)) * 2.0 ** -13).astype(np.float32)
+    codes = rng.integers(0, 256, (2, 256, 8)).astype(np.uint8)
+    got = np.asarray(adc_pallas.adc_scan_pallas_planes(lut, codes, interpret=True))
+    np.testing.assert_array_equal(got, np_adc_f64(lut, codes).astype(np.float32))
+
+
+class _Lists:
+    def __init__(self, cap):
+        self.cap = cap
+
+
+# (on a TPU, m, list capacity, use_pallas) -> the fused kernel runs
+@pytest.mark.parametrize("tpu,m,cap,forced,fused", [
+    (False, 8, 1024, None, False),   # the CPU backend keeps the XLA one-hot
+    (True, 8, 1024, None, True),     # a TPU and a geometry the kernel takes
+    (True, 64, 1024, None, True),    # the benchmark cells' own
+    (True, 8, 96, None, False),      # capacity not in whole 128-row tiles
+    (True, 8, 64, None, False),      # the smallest capacity lists start at
+    (True, 512, 1024, None, False),  # a table the VMEM model refuses
+    (False, 8, 1024, True, True),    # an explicit True forces (tests, A/B)
+    (True, 64, 1024, False, False),  # an explicit False forces
+], ids=["cpu", "tpu", "tpu-m64", "cap96", "cap64", "m512", "force-on", "force-off"])
+def test_the_index_chooses_its_adc_kernel(monkeypatch, tpu, m, cap, forced, fused):
+    from distributed_faiss_tpu.models import ivf as ivfmod
+
+    monkeypatch.setattr(adc_pallas, "on_tpu", lambda: tpu)
+    idx = ivfmod.IVFPQIndex(2 * m, 4, m=m, use_pallas=forced)
+    idx.lists = _Lists(cap)
+    assert ivfmod.pallas_wanted(idx) is fused
+    if forced is None:
+        assert idx._fused_adc_applies() is fused
+
+
+def small_pq(rng, **kw):
+    from distributed_faiss_tpu.models.ivf import IVFPQIndex
+
+    n, d = 3000, 32
+    x = rng.standard_normal((n, d)).astype(np.float32)
+    idx = IVFPQIndex(d, 8, m=8, metric="l2", kmeans_iters=3, pq_iters=3,
+                     refine_k_factor=4, **kw)
+    idx.train(x[:2000])
+    idx.add(x)
+    idx.set_nprobe(4)
+    assert idx.lists.cap % 128 == 0
+    return idx, x
+
+
+def xla_twin(idx):
+    """The same trained index, forced onto the XLA one-hot."""
+    from distributed_faiss_tpu.models.ivf import IVFPQIndex
+
+    ref = IVFPQIndex.from_state_dict({**idx.state_dict(), "pallas_adc": False})
+    assert ref.use_pallas is False
+    return ref
+
+
+def test_a_chosen_index_runs_the_planes_kernel_and_matches_xla(rng, monkeypatch):
+    """use_pallas=None on (what it takes for) a TPU: the probe loop calls
+    the three-plane kernel — interpreted here, so the spy pins the mode —
+    and serves what the XLA path serves."""
+    from distributed_faiss_tpu.models import ivf as ivfmod
+
+    idx, x = small_pq(rng)
+    assert idx.use_pallas is None
+    want_d, want_i = xla_twin(idx).search(x[:20], 5)
+    calls = []
+    orig = adc_pallas.adc_scan_pallas_planes
+
+    def spy(lut, codes, **kw):
+        calls.append(tuple(lut.shape))
+        return orig(lut, codes, interpret=True)
+
+    monkeypatch.setattr(adc_pallas, "on_tpu", lambda: True)
+    monkeypatch.setattr(adc_pallas, "adc_scan_pallas_planes", spy)
+    ivfmod._ivf_pq_search.clear_cache()
+    got_d, got_i = idx.search(x[:20], 5)
+    ivfmod._ivf_pq_search.clear_cache()  # the spy is baked into the traces
+    assert calls and idx._adc_validated and idx._pallas_runtime_ok
+    np.testing.assert_array_equal(got_i, want_i)
+    np.testing.assert_allclose(got_d, want_d, rtol=1e-4, atol=1e-4)
+
+
+@pytest.mark.parametrize("saved,loads_as", [
+    ({"use_pallas": False}, None),                       # the old default: choose
+    ({"use_pallas": True}, True),                        # the old pallas_adc=True
+    ({"use_pallas": False, "pallas_adc": None}, None),   # today's default
+    ({"use_pallas": False, "pallas_adc": False}, False), # today's forced off
+    ({"use_pallas": True, "pallas_adc": True}, True),
+], ids=["old-default", "old-forced", "choose", "off", "on"])
+def test_a_snapshots_kernel_intent(saved, loads_as):
+    from distributed_faiss_tpu.models.ivf import IVFPQIndex
+
+    state = IVFPQIndex(16, 4, m=4).state_dict()
+    del state["use_pallas"], state["pallas_adc"]
+    idx = IVFPQIndex.from_state_dict({**state, **saved})
+    assert idx.use_pallas is loads_as
+    again = IVFPQIndex.from_state_dict(idx.state_dict())
+    assert again.use_pallas is loads_as
+
+
+def test_the_factory_leaves_the_choice_to_the_index():
+    from distributed_faiss_tpu.models import factory
+    from distributed_faiss_tpu.utils.config import IndexCfg
+
+    def build(**extra):
+        return factory.build_index(IndexCfg(
+            index_builder_type="knnlm", dim=32, metric="l2", centroids=4,
+            code_size=8, **extra))
+
+    assert build().use_pallas is None
+    assert build(pallas_adc=True).use_pallas is True
+    assert build(pallas_adc=False).use_pallas is False
+    assert build(adc_lut_bf16=False).adc_lut_bf16 is False
+
+
+def test_first_fused_scan_with_wrong_scores_demotes_and_serves_xla(rng, monkeypatch):
+    """A kernel that runs and returns wrong numbers (one bf16 pass where
+    three were meant: PR 21's nibble finding) raises nothing for
+    pallas_guarded to catch: the first-use check does, before a caller
+    sees a score."""
+    from distributed_faiss_tpu.models import ivf as ivfmod
+
+    idx, x = small_pq(rng, use_pallas=True)
+    want_d, want_i = xla_twin(idx).search(x[:20], 5)
+    orig = adc_pallas.adc_scan_pallas_planes
+
+    def one_plane(lut, codes, **kw):
+        import jax.numpy as jnp
+
+        return orig(lut.astype(jnp.bfloat16).astype(jnp.float32), codes, **kw)
+
+    monkeypatch.setattr(adc_pallas, "adc_scan_pallas_planes", one_plane)
+    ivfmod._ivf_pq_search.clear_cache()
+    sink = tracing.LatencyStats()
+    with tracing.stage("engine.launch", sink=sink):
+        got_d, got_i = idx.search(x[:20], 5)
+    ivfmod._ivf_pq_search.clear_cache()
+    assert idx._adc_validated
+    assert idx._pallas_runtime_ok is False, "wrong scores survived the first use"
+    assert "engine.scan_fused" not in sink.summary()
+    np.testing.assert_array_equal(got_i, want_i)
+    np.testing.assert_array_equal(got_d, want_d)
+
+
+@pytest.mark.parametrize("forced,nq,scans,fused", [
+    (True, 20, 1, 1),    # one block, fused
+    (True, 37, 1, 1),    # five blocks in one lax.map launch: one engine.scan
+    (False, 20, 1, 0),   # the XLA path books no fused scan
+    (None, 20, 1, 0),    # nor does the CPU backend's own choice
+], ids=["fused", "fused-multiblock", "forced-off", "cpu-choice"])
+def test_scan_fused_counts_once_a_fused_scan(rng, monkeypatch, forced, nq, scans, fused):
+    from distributed_faiss_tpu.models import base
+
+    monkeypatch.setattr(base, "MAX_QUERY_BLOCK", 8)
+    idx, x = small_pq(rng, use_pallas=forced)
+    sink = tracing.LatencyStats()
+    with tracing.stage("engine.launch", sink=sink):
+        idx.search(x[:nq], 5)
+    rows = sink.summary()
+    assert rows["engine.scan"]["count"] == scans
+    assert rows.get("engine.scan_fused", {"count": 0})["count"] == fused
+    idx.search(x[:nq], 5)  # outside an engine there is no sink: nothing booked
+    assert sink.summary()["engine.scan"]["count"] == scans
+
+
+def test_per_block_scans_each_count(rng, monkeypatch):
+    """Without a fused_fn every block is its own engine.scan; with the
+    kernel on each books engine.scan_fused beside it."""
+    from distributed_faiss_tpu.models import base
+
+    monkeypatch.setattr(base, "MAX_QUERY_BLOCK", 8)
+    idx, x = small_pq(rng, use_pallas=True)
+    blocked = base.blocked_search
+    monkeypatch.setattr(
+        base, "blocked_search",
+        lambda q, k, metric, fn, block=256, fused_fn=None, refine_fn=None:
+        blocked(q, k, metric, fn, block, None, refine_fn))
+    sink = tracing.LatencyStats()
+    with tracing.stage("engine.launch", sink=sink):
+        idx.search(x[:20], 5)
+    rows = sink.summary()
+    assert rows["engine.scan"]["count"] == 3 == rows["engine.scan_fused"]["count"]
+
+
+def _obs(scans, fused, ranks=1):
+    def snap(n_scan, n_fused):
+        block = {"engine.scan": {"count": n_scan, "total_s": 0.1 * n_scan}}
+        if n_fused is not None:
+            block["engine.scan_fused"] = {"count": n_fused, "total_s": float(n_fused)}
+        return {"engine": {"bench": block}}
+
+    return {"index_id": "bench", "window_s": 1.0,
+            "stats_before": [snap(5, None if fused is None else 5)] * ranks,
+            "stats_after": [snap(5 + scans, None if fused is None else 5 + fused)] * ranks}
+
+
+@pytest.mark.parametrize("name", ["kernel.adc_fused_pct", "kernel.adc_fused_pct.online"])
+def test_the_fused_share_readers(name):
+    import os
+    import sys
+
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    sys.path.insert(0, repo)
+    from perfbench import loader
+
+    reader = loader.load_module(os.path.join(
+        repo, "perfbench", "layer_metrics", f"{name}.py"))
+    assert reader.read(_obs(40, 40)) == 100.0
+    assert reader.read(_obs(40, 40, ranks=4)) == 100.0
+    assert reader.read(_obs(40, 0)) == 0.0  # a silent demotion reads 0
+    assert reader.read(_obs(40, 10)) == 25.0
+    # a program without the counter (the parent commit): nothing to read
+    assert reader.read(_obs(40, None)) is None
+    assert reader.read({"index_id": "bench", "window_s": 1.0}) is None
+    bench = loader.read_json(os.path.join(repo, "BENCHMARK.json"))
+    entry = [m for m in bench["per_layer"] if m["name"] == name]
+    assert len(entry) == 1 and entry[0]["layer"] == "models and kernels"

@@ -209,6 +209,26 @@ def test_counters_are_booked_with_sampling_off_and_spans_are_not(rank):
     assert spans.local_buffer().stats()["recorded"] == recorded
 
 
+def test_scan_fused_stands_at_zero_beside_engine_scan_on_the_cpu(rank):
+    """The knnlm index chooses its ADC kernel itself; on the CPU backend it
+    keeps the XLA one-hot, and the rank says so: ``engine.scan_fused`` is
+    served at zero beside a counting ``engine.scan`` (0 of n, not a missing
+    row), and no index is listed as degraded."""
+    client, x = rank["client"], rank["x"]
+    assert rank["idx"].tpu_index.use_pallas is None
+    before = client.get_perf_stats()[0]["engine"][INDEX_ID]
+    client.search(x[:8], 5, INDEX_ID)
+    after = client.get_perf_stats()[0]["engine"][INDEX_ID]
+    assert after["engine.scan"]["count"] > before["engine.scan"]["count"]
+    assert after["engine.scan_fused"]["count"] == 0
+    assert rank["srv"].ping()["kernels"]["pallas_degraded"] == []
+    rank["idx"].tpu_index._pallas_runtime_ok = False  # as a demotion leaves it
+    try:
+        assert rank["srv"].ping()["kernels"]["pallas_degraded"] == [INDEX_ID]
+    finally:
+        rank["idx"].tpu_index._pallas_runtime_ok = True
+
+
 def test_every_span_of_a_sampled_request_hangs_under_client_search(rank):
     client, x = rank["client"], rank["x"]
     tid = spans.mint_trace_id()
