@@ -1769,10 +1769,17 @@ class Index:
         ``engine.scan_fused`` counts the ``engine.scan`` blocks whose scan
         ran the fused Pallas ADC kernel (models/ivf.py books it); it stands
         at zero beside ``engine.scan`` until one does, so a scan that fell
-        back to XLA reads 0 of n and not "no such row"."""
+        back to XLA reads 0 of n and not "no such row".
+        ``engine.scan_rows`` (a count row, shown the same way) sums the
+        rows of its store an exact scan read, capacity padding included,
+        one record an ``engine.scan`` (models/flat.py books it);
+        ``engine.store_grow`` is one record a reallocation of a
+        ``DeviceVectorStore`` (models/base.py), allocation to the end of
+        the copy."""
         out = self.perf.summary(raw=raw)
         if "engine.scan" in out:
             out.setdefault("engine.scan_fused", tracing.zero_row())
+            out.setdefault("engine.scan_rows", tracing.zero_row())
         return out
 
     def get_centroids(self):

@@ -96,15 +96,20 @@ class DeviceVectorStore:
         if self.cap >= target:
             return
         newcap = _next_pow2(target, self.min_cap)
-        if self.data is None:
-            self.data = jnp.zeros((newcap,) + self.row_shape, self.dtype)
-        else:
-            pad = [(0, newcap - self.cap)] + [(0, 0)] * len(self.row_shape)
-            self.data = jnp.pad(self.data, pad)
-        if self.live is not None:
-            # new capacity rows are live until masked
-            self.live = jnp.pad(self.live, (0, newcap - self.cap),
-                                constant_values=True)
+        # one booking a growth (the first allocation included), to the end of
+        # the copy: old and new store are both alive until then, which is
+        # what bounds the rows a chip can hold (docs/OPERATIONS.md)
+        with tracing.stage("engine.store_grow"):
+            if self.data is None:
+                self.data = jnp.zeros((newcap,) + self.row_shape, self.dtype)
+            else:
+                pad = [(0, newcap - self.cap)] + [(0, 0)] * len(self.row_shape)
+                self.data = jnp.pad(self.data, pad)
+            if self.live is not None:
+                # new capacity rows are live until masked
+                self.live = jnp.pad(self.live, (0, newcap - self.cap),
+                                    constant_values=True)
+            jax.block_until_ready((self.data, self.live))
         self.cap = newcap
 
     def mask_rows(self, rows: np.ndarray) -> None:
@@ -489,7 +494,8 @@ def blocked_search(q: np.ndarray, k: int, metric: str, fn, block: int = 256,
     sink, the engine's): a block is ``engine.feed`` (slice, pad,
     ``device_put``), ``engine.scan`` (``fn`` / ``fused_fn``: dispatch of
     the scan program up to wherever the callable itself waits — the IVF
-    family's ``pallas_guarded`` blocks until the scan is done) and
+    family's ``pallas_guarded`` and a flat index's callables block until
+    the scan is done) and
     ``engine.refine_fetch`` (``refine_fn(block, ids)``, the exact rerank's
     dispatch where the index refines outside the scan program, then the
     result fetch and ``finalize_results``). The boundaries sit where the

@@ -23,7 +23,7 @@ import numpy as np
 
 from distributed_faiss_tpu.models import base
 from distributed_faiss_tpu.ops import distance, sq
-from distributed_faiss_tpu.utils import sanitize, tracing, xfercheck
+from distributed_faiss_tpu.utils import sanitize, tracing
 
 _CODEC_DTYPES = {
     "f32": jnp.float32,
@@ -90,54 +90,44 @@ class FlatIndex(base.TpuIndex):
         if self.ntotal == 0:
             empty_d = np.full((nq, k), np.inf if self.metric == "l2" else -np.inf, np.float32)
             return empty_d, np.full((nq, k), -1, np.int64)
-        q = np.asarray(q, np.float32)
         kwargs = {}
         if self.codec == "sq8":
             kwargs = {"codec": "sq8", "vmin": self.sq_params["vmin"], "span": self.sq_params["span"]}
+        store = self.store
+
+        def scanned(out, blocks):
+            """The scan's outputs once the device has them: ``engine.scan``
+            (base.blocked_search opens it around these callables) then runs
+            from the dispatch to the end of the wait, and the fetch after it
+            times the fetch alone. ``engine.scan_rows`` counts the rows of
+            the store the scan read, capacity padding included."""
+            out = jax.block_until_ready(out)
+            tracing.count("engine.scan_rows", float(blocks * store.cap))
+            return out
+
+        def ntotal_on_device():
+            # explicit device_put: the serving path runs under DFT_XFERCHECK's
+            # transfer guard, which forbids the implicit upload at jit dispatch
+            return jax.device_put(np.int32(store.ntotal))
+
+        def scan_block(block):
+            return scanned(distance.knn(
+                block, store.data, k, metric=self.metric, ntotal=ntotal_on_device(),
+                live=store.live, **kwargs), 1)
+
+        def scan_fused(q3):
+            # multi-block batch: one launch for all blocks (lax.map)
+            return scanned(sanitize.maybe_checked(
+                _flat_search_fused, q3, store.data, ntotal_on_device(), k=k,
+                metric=self.metric, codec=self.codec, vmin=kwargs.get("vmin"),
+                span=kwargs.get("span"), live=store.live), q3.shape[0])
+
         # per-query transient is the (nq, chunk) score block of the running
         # scan — launch-bound serving wants the largest block that keeps it
         # within budget (see base.pick_query_block)
-        nb = base.pick_query_block(65536 * 4)
-        if nq > nb:
-            # multi-block batch: one launch for all blocks (trailing block
-            # padded to full width — extra compute only). nblocks bucketed to
-            # powers of two so variable-batch serving compiles O(log max)
-            # fused variants, not one per distinct batch size
-            # (the three stages are the launch ledger's, as in
-            # base.blocked_search)
-            with tracing.stage("engine.feed"):
-                nblocks = base._next_pow2(-(-nq // nb), 1)
-                qp = np.pad(q, ((0, nblocks * nb - nq), (0, 0)))
-                # explicit device_put feeds: the serving path runs under
-                # DFT_XFERCHECK's transfer guard, which forbids the implicit
-                # uploads jnp.asarray/jit-dispatch would do here
-                q3 = jax.device_put(qp.reshape(nblocks, nb, -1))
-                ntotal = jax.device_put(np.int32(self.store.ntotal))
-            with tracing.stage("engine.scan"):
-                vals, ids = sanitize.maybe_checked(
-                    _flat_search_fused, q3, self.store.data, ntotal, k=k,
-                    metric=self.metric, codec=self.codec,
-                    vmin=kwargs.get("vmin"), span=kwargs.get("span"),
-                    live=self.store.live,
-                )
-            with tracing.stage("engine.refine_fetch"):
-                with xfercheck.explicit("flat fused-search result fetch"):
-                    out_s = np.asarray(vals).reshape(nblocks * nb, -1)[:nq]
-                    out_i = np.asarray(ids).reshape(nblocks * nb, -1)[:nq].astype(np.int64)
-                return base.finalize_results(out_s, out_i, self.metric)
-        out_s = np.empty((nq, k), np.float32)
-        out_i = np.empty((nq, k), np.int64)
-        for s, n, block in base.query_blocks(q, nb):
-            with tracing.stage("engine.scan"):
-                vals, ids = distance.knn(
-                    block, self.store.data, k, metric=self.metric,
-                    ntotal=self.store.ntotal, live=self.store.live, **kwargs
-                )
-            with tracing.stage("engine.refine_fetch"):
-                with xfercheck.explicit("flat block-search result fetch"):
-                    out_s[s : s + n] = np.asarray(vals)[:n]
-                    out_i[s : s + n] = np.asarray(ids)[:n]
-        return base.finalize_results(out_s, out_i, self.metric)
+        return base.blocked_search(q, k, self.metric, scan_block,
+                                   block=base.pick_query_block(65536 * 4),
+                                   fused_fn=scan_fused)
 
     def reconstruct_batch(self, ids: np.ndarray) -> np.ndarray:
         rows = self.store.rows(np.asarray(ids))
