@@ -1773,13 +1773,18 @@ class Index:
         ``engine.scan_rows`` (a count row, shown the same way) sums the
         rows of its store an exact scan read, capacity padding included,
         one record an ``engine.scan`` (models/flat.py books it);
+        ``engine.scan_prefilter`` (a count row, shown the same way) counts
+        the ``engine.scan`` blocks of an exact scan whose per-chunk top-k
+        chose its segments by their maxima before sorting
+        (``ops/distance.topk_prefilters``; models/flat.py books it);
         ``engine.store_grow`` is one record a reallocation of a
         ``DeviceVectorStore`` (models/base.py), allocation to the end of
         the copy."""
         out = self.perf.summary(raw=raw)
         if "engine.scan" in out:
-            out.setdefault("engine.scan_fused", tracing.zero_row())
-            out.setdefault("engine.scan_rows", tracing.zero_row())
+            for name in ("engine.scan_fused", "engine.scan_rows",
+                         "engine.scan_prefilter"):
+                out.setdefault(name, tracing.zero_row())
         return out
 
     def get_centroids(self):

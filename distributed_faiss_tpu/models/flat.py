@@ -100,9 +100,14 @@ class FlatIndex(base.TpuIndex):
             (base.blocked_search opens it around these callables) then runs
             from the dispatch to the end of the wait, and the fetch after it
             times the fetch alone. ``engine.scan_rows`` counts the rows of
-            the store the scan read, capacity padding included."""
+            the store the scan read, capacity padding included;
+            ``engine.scan_prefilter`` the scans whose per-chunk top-k chose
+            its segments by their maxima (the rule the traced code
+            branches on, asked of the same k and chunk)."""
             out = jax.block_until_ready(out)
             tracing.count("engine.scan_rows", float(blocks * store.cap))
+            if distance.topk_prefilters(k, min(distance.SCAN_CHUNK, store.cap)):
+                tracing.count("engine.scan_prefilter")
             return out
 
         def ntotal_on_device():
@@ -126,7 +131,7 @@ class FlatIndex(base.TpuIndex):
         # scan — launch-bound serving wants the largest block that keeps it
         # within budget (see base.pick_query_block)
         return base.blocked_search(q, k, self.metric, scan_block,
-                                   block=base.pick_query_block(65536 * 4),
+                                   block=base.pick_query_block(distance.SCAN_CHUNK * 4),
                                    fused_fn=scan_fused)
 
     def reconstruct_batch(self, ids: np.ndarray) -> np.ndarray:

@@ -79,39 +79,98 @@ def merge_topk(vals_a, ids_a, vals_b, ids_b, k: int):
     return best, jnp.take_along_axis(ids, pos, axis=1)
 
 
-# lax.top_k cost grows super-linearly with row width on TPU (sorting-network
-# passes over the whole row); the 65,536-wide per-chunk top-k — not the MXU
-# matmul — dominated the flat scan. Exact two-stage reduction: per-segment
-# top-k (every global top-k element is inside its own segment's top-k, so
-# the union is an exact superset), then one narrow top-k over G*k.
+# Exact top-k of a wide row, by one of three first stages chosen from the
+# static (k, W) alone (`topk_prefilters` is the rule). What each costs on a
+# v5e under jax 0.9.0 (PERF.md section 6, PR 29; ms a call on 128 rows, about
+# 0.7 of it the call itself):
+# - plain top_k, for narrow rows (W <= 2 * _TOPK_SEGMENT) and what the other
+#   two cannot take: 0.9 at (k, W) = (10, 4096), 1.3 at (10, 65536), 2.0 at
+#   (80, 32768), 8.4 at (256, 65536);
+# - the prefilter, where a row's 128-column segments are at least 4 k: the
+#   maximum of every segment (one read of the block, no sort), top_k over
+#   those maxima (the one sort left, W / 128 wide), then top_k over the k
+#   chosen segments alone (128 k wide: XLA's TopK call): 0.9 at (10, 8192),
+#   1.0 at (10, 65536), 2.9 at (128, 65536); in the exact scan's loop, 32
+#   chunks a launch of 256 rows, 45.7 ms a launch against 58.1 with plain
+#   top_k. Segments of 256 or 512 columns, or the gather as a one-hot
+#   select, a flat row gather or dynamic slices, were no faster;
+# - the two-stage reduction, otherwise (k too large for the row's segments,
+#   the IVF merges' k = 80): top_k of every 2048-wide segment, a full sort
+#   of each along the lanes, then top_k over their union: 2.9 at
+#   (80, 32768), 4.5 to 5.1 at any k over 65,536 columns, 265.7 ms for that
+#   same launch of the scan. It beat plain top_k only at (256, 65536).
 _TOPK_SEGMENT = 2048
+_PREFILTER_SEGMENT = 128
 
 
-def _seg_reduce(s, k: int):
-    """Exact top-k over rows of (nq, W) scores via the two-stage reduction.
+def topk_prefilters(k: int, width: int) -> bool:
+    """Whether the exact top-k of ``width``-wide rows chooses its segments
+    by their maxima before it sorts (the rule `_seg_reduce` branches on;
+    the host books ``engine.scan_prefilter`` from it)."""
+    k = min(k, width)
+    return (width > 2 * _TOPK_SEGMENT
+            and -(-width // _PREFILTER_SEGMENT) >= 4 * k)
 
-    Returns (vals, pos) with pos indexing the ORIGINAL columns. Non-aligned
-    widths are padded with NEG_INF (so every wide row takes the fast path).
-    A padded column can only surface when a row has fewer than k finite
-    entries; its pos is returned as -1, preserving the callers' invariant
-    that a NEG_INF slot never carries a live id (masked columns inside the
-    original width keep whatever id the caller stored there, exactly like
-    plain top_k). Falls back to single-pass top_k only for narrow rows or
-    k > segment.
-    """
+
+def _pad_to_segments(s, seg: int):
+    """(nq, W) -> (nq, G, seg), the tail segment filled with NEG_INF."""
     nq, w = s.shape
-    seg = _TOPK_SEGMENT
-    kk = min(k, w)
-    if w <= 2 * seg or kk > seg:
-        return jax.lax.top_k(s, kk)
     wp = -(-w // seg) * seg
     if wp != w:
         s = jnp.pad(s, ((0, 0), (0, wp - w)), constant_values=NEG_INF)
-    g = wp // seg
-    sv, sp = jax.lax.top_k(s.reshape(nq, g, seg), kk)         # (nq, g, kk)
-    flat = (jnp.arange(g, dtype=jnp.int32) * seg)[None, :, None] + sp
-    cv, cp = jax.lax.top_k(sv.reshape(nq, g * kk), kk)
-    pos = jnp.take_along_axis(flat.reshape(nq, g * kk), cp, axis=1)
+    return s.reshape(nq, wp // seg, seg)
+
+
+def _prefilter_candidates(s, k: int):
+    """The k segments of a row that can hold one of its top-k, by their
+    maxima: a segment left out is beaten by k segments whose maximum is
+    larger, or equal and at a lower column, so k elements precede anything
+    it holds in top_k's order (value descending, column ascending). Taken in
+    ascending order, so that ties among the candidates still fall to the
+    lower column. Returns (scores (nq, k * seg), their columns (nq, k, seg))."""
+    seg = _PREFILTER_SEGMENT
+    s3 = _pad_to_segments(s, seg)
+    _, chosen = jax.lax.top_k(jnp.max(s3, axis=2), k)
+    chosen = jnp.sort(chosen, axis=1)
+    cand = jnp.take_along_axis(s3, chosen[:, :, None], axis=1)
+    cols = chosen[:, :, None] * seg + jnp.arange(seg, dtype=jnp.int32)
+    return cand.reshape(-1, k * seg), cols
+
+
+def _segment_candidates(s, k: int):
+    """Every 2048-wide segment's own top-k: each of the row's top-k is
+    inside its segment's. Returns (scores (nq, G * k), their columns
+    (nq, G, k))."""
+    seg = _TOPK_SEGMENT
+    s3 = _pad_to_segments(s, seg)
+    g = s3.shape[1]
+    sv, sp = jax.lax.top_k(s3, k)
+    cols = (jnp.arange(g, dtype=jnp.int32) * seg)[None, :, None] + sp
+    return sv.reshape(-1, g * k), cols
+
+
+def _seg_reduce(s, k: int):
+    """Exact top-k over rows of (nq, W) scores: the values and positions
+    ``lax.top_k(s, k)`` gives, ties and rows of fewer than k finite scores
+    included, by the first stage `topk_prefilters` chooses.
+
+    Returns (vals, pos) with pos indexing the ORIGINAL columns. Non-aligned
+    widths are padded with NEG_INF. A padded column can only surface when a
+    row has fewer than k finite entries; its pos is returned as -1,
+    preserving the callers' invariant that a NEG_INF slot never carries a
+    live id (masked columns inside the original width keep whatever id the
+    caller stored there, exactly like plain top_k).
+    """
+    w = s.shape[1]
+    kk = min(k, w)
+    if topk_prefilters(kk, w):
+        cand, cols = _prefilter_candidates(s, kk)
+    elif w <= 2 * _TOPK_SEGMENT or kk > _TOPK_SEGMENT:
+        return jax.lax.top_k(s, kk)
+    else:
+        cand, cols = _segment_candidates(s, kk)
+    cv, cp = jax.lax.top_k(cand, kk)
+    pos = jnp.take_along_axis(cols.reshape(cand.shape), cp, axis=1)
     return cv, jnp.where(pos < w, pos, -1)
 
 
@@ -204,7 +263,11 @@ def _knn_scan(q, x, ntotal, k: int, metric: str, chunk: int, codec: str = "raw",
     return vals, ids
 
 
-def knn(q, x, k: int, metric: str = "l2", ntotal=None, chunk: int = 65536,
+# rows of the corpus a step of the scan scores: the width of its top-k
+SCAN_CHUNK = 65536
+
+
+def knn(q, x, k: int, metric: str = "l2", ntotal=None, chunk: int = SCAN_CHUNK,
         codec: str = "raw", vmin=None, span=None, live=None):
     """Exact k-nearest-neighbor scan of a (possibly capacity-padded) corpus.
 

@@ -10,6 +10,7 @@ load types — which is where every kernel repair of the chip bring-up was.
 """
 
 import json
+import re
 import sys
 
 import jax
@@ -85,6 +86,21 @@ def lower_for_tpu(fn, sig, sds):
     return fn.trace(*args, **kwargs).lower(lowering_platforms=("tpu",))
 
 
+def exact_scan_sort_widths(sds):
+    """``flat768-batch``'s scan program (a 64-row window over 2^21 x 768
+    float32 rows, k = 10) compiled for a v5e: how many elements a row each
+    ``sort`` left in it sorts. On this chip ``lax.top_k`` over a wide row
+    is such a sort; the prefilter leaves the 512 segment maxima the widest."""
+    from distributed_faiss_tpu.ops import distance
+
+    text = distance._knn_scan.trace(
+        sds((64, 768), "float32"), sds((2 ** 21, 768), "float32"),
+        sds((), "int32"), k=10, metric="l2", chunk=distance.SCAN_CHUNK,
+    ).lower(lowering_platforms=("tpu",)).compile().as_text()
+    return [int(dims.split(",")[int(axis)]) for dims, axis in re.findall(
+        r"= \(?\w+\[([\d,]+)\][^=]*? sort\(.*?dimensions=\{(\d+)\}", text)]
+
+
 def main():
     """Compile every case for a v5e from its topology description alone."""
     from jax.experimental import topologies
@@ -108,6 +124,8 @@ def main():
             print(json.dumps({"case": name, "ok": False,
                               "error": f"{type(e).__name__}: {e}"[:600]}),
                   flush=True)
+    print(json.dumps({"exact_scan_sort_widths": exact_scan_sort_widths(sds)}),
+          flush=True)
     return 0
 
 

@@ -5,7 +5,8 @@ search branches; its launch ledger (docs/OPERATIONS.md#stage-ledger):
 ``engine.scan`` runs from the dispatch to the end of the wait for the scan,
 the per-block branch books ``engine.feed``, a flat rank's launch-loop
 stages add up to its batcher thread's wall clock; ``engine.scan_rows``
-books the store's capacity for every block scanned; ``engine.store_grow``
+books the store's capacity for every block scanned, ``engine.scan_prefilter``
+the scans whose per-chunk top-k took the prefilter; ``engine.store_grow``
 counts the reallocations of a ``DeviceVectorStore``. Nothing timed here is a
 speed.
 """
@@ -172,6 +173,30 @@ def test_scan_rows_stands_at_zero_beside_engine_scan_where_nothing_books_it(tmp_
     stats = idx.perf_stats()
     assert stats["engine.scan"]["count"] >= 1
     assert stats["engine.scan_rows"]["count"] == 0
+    assert stats["engine.scan_prefilter"]["count"] == 0
+
+
+def test_scan_prefilter_counts_the_scans_whose_top_k_chose_segments_first(tmp_path):
+    """The host books what the traced code does, by the same rule on
+    (k, chunk): the store's 8192 rows are one chunk, 64 segments, so k = 10
+    takes the prefilter (64 >= 4 x 10) and k = 20 the two-stage reduction."""
+    idx = engine_of(tmp_path, seeded())
+    assert idx.tpu_index.store.cap == 8192
+    assert flat.distance.topk_prefilters(10, 8192)
+    assert not flat.distance.topk_prefilters(20, 8192)
+    assert row_of(idx.perf_stats(), "engine.scan_prefilter")["count"] == 0
+    # a scan that did not take it: the row is served at zero, not left out
+    idx.search(seeded(4, seed=5), 20)
+    stats = idx.perf_stats()
+    assert stats["engine.scan"]["count"] == 1
+    assert stats["engine.scan_prefilter"]["count"] == 0
+    idx.search(seeded(16, seed=1), 10)
+    assert idx.perf_stats()["engine.scan_prefilter"]["count"] == 1
+    idx.search(seeded(2 * BLOCK + 1, seed=2), 10)  # one fused scan of four blocks
+    idx.search(seeded(16, seed=3), 20)
+    stats = idx.perf_stats()
+    assert stats["engine.scan"]["count"] == 4
+    assert stats["engine.scan_prefilter"]["count"] == 2
 
 
 def test_store_grow_counts_the_doublings_of_a_store_grown_past_min_cap():

@@ -210,3 +210,91 @@ def test_segmented_topk_pad_columns_yield_minus_one(rng):
     rows_ids = jnp.asarray(np.tile(np.arange(w, dtype=np.int32)[None, :], (nq, 1)))
     _, si2 = distance.segmented_topk_rows(jnp.asarray(s), k, rows_ids)
     assert (np.asarray(si2) <= w - 1).all()
+
+
+# ------------------------------------------- the prefilter branch of _seg_reduce
+
+_TOPK_WIDTHS = [4608, 5000, 8192, 51277, 65536]  # 5000 and 51277 pad a segment
+
+
+def _topk_case(rng, name, nq, w):
+    r = rng.standard_normal((nq, w)).astype(np.float32)
+    if name == "random":
+        return r
+    if name == "halves":  # heavy ties: positions must fall to the lower column
+        return np.round(r * 2) / 2
+    if name == "five_finite":
+        s = np.full((nq, w), -np.inf, np.float32)
+        for row in s:
+            row[rng.choice(w, 5, replace=False)] = rng.standard_normal(5)
+        return s
+    if name == "all_neg_inf":
+        return np.full((nq, w), -np.inf, np.float32)
+    assert name == "all_equal"
+    return np.full((nq, w), 1.5, np.float32)
+
+
+@pytest.mark.parametrize("w", _TOPK_WIDTHS)
+@pytest.mark.parametrize("k", [1, 10, 100])
+@pytest.mark.parametrize("case", ["random", "halves", "five_finite",
+                                  "all_neg_inf", "all_equal"])
+def test_seg_reduce_equals_top_k_bit_for_bit(rng, case, k, w):
+    """Values AND positions of ``lax.top_k``, on both sides of the rule: at
+    these widths k = 1 always takes the prefilter, k = 10 from 5000 up,
+    k = 100 from 51277 up; the rest is the two-stage reduction."""
+    import jax
+    import jax.numpy as jnp
+
+    assert distance.topk_prefilters(k, w) == (w >= {1: 0, 10: 5000, 100: 51277}[k])
+    s = jnp.asarray(_topk_case(rng, case, 3, w))
+    got_v, got_p = distance._seg_reduce(s, k)
+    want_v, want_p = jax.lax.top_k(s, k)
+    np.testing.assert_array_equal(np.asarray(got_v), np.asarray(want_v))
+    np.testing.assert_array_equal(np.asarray(got_p), np.asarray(want_p))
+
+
+@pytest.mark.parametrize("w", [5000, 51277])
+@pytest.mark.parametrize("case", ["five_finite", "all_neg_inf"])
+def test_prefilter_keeps_pad_columns_out_of_the_ids(rng, case, w):
+    """A row with fewer than k finite scores at a width the prefilter pads:
+    ``segmented_topk`` and ``segmented_topk_rows`` return the ids of real
+    (masked) columns or -1, never one made from the pad region."""
+    import jax
+    import jax.numpy as jnp
+
+    k = 10
+    assert distance.topk_prefilters(k, w) and w % 128
+    s = jnp.asarray(_topk_case(rng, case, 2, w))
+    _, want_p = jax.lax.top_k(s, k)
+    gids = jnp.arange(w, dtype=jnp.int32) + 7
+    _, si = distance.segmented_topk(s, k, gids)
+    np.testing.assert_array_equal(np.asarray(si), np.asarray(want_p) + 7)
+    rows_ids = jnp.tile(jnp.arange(w, dtype=jnp.int32)[None, :], (2, 1))
+    _, si2 = distance.segmented_topk_rows(s, k, rows_ids)
+    np.testing.assert_array_equal(np.asarray(si2), np.asarray(want_p))
+
+
+@pytest.mark.parametrize("k,w", [(80, 2048), (80, 4096), (80, 8192), (80, 32768),
+                                 (10, 4096)])
+def test_the_rule_keeps_todays_branch_at_the_benchmarks_other_shapes(k, w):
+    """``knnlm`` merges 80 of g x 1024 columns and ``ivfsq`` 10 of 4096: a
+    later edit of the rule must not move those cells' programs unseen. The
+    prefilter is the only branch that reduces a maximum."""
+    import jax
+    import jax.numpy as jnp
+
+    assert not distance.topk_prefilters(k, w)
+    text = str(jax.make_jaxpr(lambda s: distance._seg_reduce(s, k))(
+        jnp.zeros((8, w), jnp.float32)))
+    assert "reduce_max" not in text
+    assert text.count("top_k") == (1 if w <= 4096 else 2)
+
+
+def test_the_exact_scans_chunk_takes_the_prefilter():
+    import jax
+    import jax.numpy as jnp
+
+    assert distance.topk_prefilters(10, distance.SCAN_CHUNK)
+    text = str(jax.make_jaxpr(lambda s: distance._seg_reduce(s, 10))(
+        jnp.zeros((8, distance.SCAN_CHUNK), jnp.float32)))
+    assert "reduce_max" in text

@@ -30,11 +30,10 @@ def test_kernel_lowers_for_tpu(name, fn, sig):
     assert "tpu_custom_call" in lowered.as_text()
 
 
-def test_kernels_compile_for_v5e():
-    """Mosaic + XLA:TPU compile of every case from libtpu's compile-only
-    v5e topology, in a child (libtpu stays out of the test process). At the
-    parent commit: IEEE-half vector loads, 2 x 512 KB of scalar prefetch
-    against 1 MB of SMEM, and 35.6 MB of scoped VMEM in the nibble kernel."""
+@pytest.fixture(scope="module")
+def compiled_for_v5e():
+    """The rows ``pallas_tpu_cases.py`` prints, run once as a script in a
+    child (libtpu stays out of the test process)."""
     proc = subprocess.run(
         [sys.executable, os.path.join(REPO, "tests", "pallas_tpu_cases.py")],
         capture_output=True, text=True, timeout=600,
@@ -45,6 +44,27 @@ def test_kernels_compile_for_v5e():
     assert proc.returncode == 0 and rows, proc.stderr[-2000:]
     if "unavailable" in rows[0]:
         pytest.skip(f"no TPU compile-only topology: {rows[0]['unavailable']}")
+    return rows
+
+
+def test_kernels_compile_for_v5e(compiled_for_v5e):
+    """Mosaic + XLA:TPU compile of every case from libtpu's compile-only
+    v5e topology. At the parent commit: IEEE-half vector loads, 2 x 512 KB
+    of scalar prefetch against 1 MB of SMEM, and 35.6 MB of scoped VMEM in
+    the nibble kernel."""
+    rows = [r for r in compiled_for_v5e if "case" in r]
     assert len(rows) == len(pallas_tpu_cases.cases())
     refused = [r for r in rows if not r["ok"]]
     assert not refused, refused
+
+
+def test_the_exact_scan_compiled_for_v5e_sorts_no_wide_row(compiled_for_v5e):
+    """``lax.top_k`` over a wide row is a full sort of it on this chip, and
+    that sort was 79% of ``flat768-batch``'s device time (ledger, PR 28:
+    every 2048-wide segment of every chunk). With the prefilter the widest
+    row the scan program sorts is the 512 segment maxima of a chunk."""
+    from distributed_faiss_tpu.ops import distance
+
+    (row,) = [r for r in compiled_for_v5e if "exact_scan_sort_widths" in r]
+    assert row["exact_scan_sort_widths"], "no sort found: the parse is stale"
+    assert max(row["exact_scan_sort_widths"]) <= distance.SCAN_CHUNK // 128
