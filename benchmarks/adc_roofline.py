@@ -1,16 +1,17 @@
 """ADC kernel roofline: measured throughput vs v5e peaks, per variant.
 
 VERDICT r2 missing #3: nothing quantified device utilization for the kernel
-SURVEY §7 says "decides IVF-PQ QPS". This script times the ADC
-implementations (XLA one-hot einsum, Pallas one-hot, Pallas nibble, Pallas
-three-plane one-hot) at the flagship geometry and at the benchmark cells' own
+SURVEY §7 says "decides IVF-PQ QPS". This script times the two ADC
+implementations (the XLA one-hot einsum and the Pallas three-plane one-hot;
+the kernels that lost to it were deleted at PR 30, their timings are in
+PERF.md, PR 25) at the flagship geometry and at the benchmark cells' own
 (``knnlm``: one table a (query, probe) pair, 128 / 2048 / 8192 pairs of
 capacity-1024 lists) and prints, per variant:
 
   - codes/s (candidate rows x m scored per second)
   - achieved HBM bytes/s for the true input traffic (codes + lut + out)
-  - the VPU-side one-hot store traffic the kernel generates (the measured
-    bottleneck of the one-hot variant; the nibble variant cuts it 16x)
+  - the one-hot traffic the variant generates (f32 through HBM for XLA,
+    bf16 values handed to the MXU in the kernel)
   - % of v5e HBM peak (819 GB/s) for the true traffic
 
 Runs compiled on a TPU v5e only — the one chip its peaks describe — and
@@ -74,7 +75,6 @@ def one_geometry(nq, m, ksub, L, device_kind, peaks):
 
     rng = np.random.default_rng(0)
     lut = jnp.asarray(rng.standard_normal((nq, m, ksub)).astype(np.float32))
-    lut_bf16 = lut.astype(jnp.bfloat16)
     codes = jnp.asarray(rng.integers(0, 256, (nq, L, m)).astype(np.uint8))
 
     rows = nq * L
@@ -84,14 +84,6 @@ def one_geometry(nq, m, ksub, L, device_kind, peaks):
 
     variants = [
         ("xla-onehot", lambda: pq.adc_scan(lut, codes)),
-        ("pallas-onehot-f32",
-         lambda: adc_pallas.adc_scan_pallas(lut, codes)),
-        ("pallas-onehot-bf16",
-         lambda: adc_pallas.adc_scan_pallas(lut_bf16, codes)),
-        ("pallas-nibble-f32",
-         lambda: adc_pallas.adc_scan_pallas_nibble(lut, codes)),
-        ("pallas-nibble-bf16",
-         lambda: adc_pallas.adc_scan_pallas_nibble(lut_bf16, codes)),
         # f32 table values as three bf16 planes, bf16 one-hot, one MXU pass
         ("pallas-planes-f32",
          lambda: adc_pallas.adc_scan_pallas_planes(lut, codes)),
@@ -104,10 +96,8 @@ def one_geometry(nq, m, ksub, L, device_kind, peaks):
             print(json.dumps({"variant": name, "device_kind": device_kind,
                               "error": repr(e)[:200]}), flush=True)
             continue
-        lut_bytes = lut_bytes_f32 // (2 if "bf16" in name else 1)
-        true_bytes = code_bytes + lut_bytes + out_bytes
-        onehot_factor = 16 if "nibble" in name else ksub
-        onehot_bytes = 2 if "bf16" in name or "planes" in name else 4
+        true_bytes = code_bytes + lut_bytes_f32 + out_bytes
+        onehot_bytes = 2 if "planes" in name else 4
         row = {
             "variant": name,
             "device_kind": device_kind,
@@ -118,7 +108,7 @@ def one_geometry(nq, m, ksub, L, device_kind, peaks):
             "true_gbs": round(true_bytes / dt / 1e9, 2),
             "hbm_pct": round(100 * true_bytes / dt / 1e9 / peaks["hbm_gbs"], 2),
             "onehot_store_gbs": round(
-                rows * m * onehot_factor * onehot_bytes / dt / 1e9, 1),
+                rows * m * ksub * onehot_bytes / dt / 1e9, 1),
         }
         print(json.dumps(row), flush=True)
 

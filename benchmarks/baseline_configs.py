@@ -254,10 +254,9 @@ def run_knnlm(rng, small, opq=False):
     on_chip = on_tpu()
     # refine: exact fp16 rerank of the ADC shortlist — the config that takes
     # PQ past the recall@10 >= 0.95 bar BASELINE.md measures at. On TPU the
-    # serving mode is the compiled pallas kernel with the bf16 LUT; refine
-    # keeps final scores exact.
+    # serving mode is the compiled pallas kernel.
     idx = IVFPQIndex(d, nlist, m=m, metric="l2", kmeans_iters=8, pq_iters=10,
-                     refine_k_factor=16, use_pallas=on_chip, adc_lut_bf16=on_chip)
+                     refine_k_factor=16, use_pallas=on_chip)
     name = "knnlm"
     if opq:
         # OPQ balances per-subspace energy before PQ, which matters exactly

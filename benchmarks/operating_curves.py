@@ -76,7 +76,7 @@ def knnlm_curve(rng, size, recall_only=False):
     # refine store built at the largest factor we sweep; the factor itself
     # is a search-time knob (adc_k = k * factor)
     idx = IVFPQIndex(d, nlist, m=m, metric="l2", kmeans_iters=8, pq_iters=10,
-                     refine_k_factor=32, use_pallas=on_chip, adc_lut_bf16=on_chip)
+                     refine_k_factor=32, use_pallas=on_chip)
     t0 = time.time()
     idx.train(x[:min(n, 100_000)])
     idx.add(x)

@@ -6,12 +6,10 @@ through the Pallas interpreter on the CPU; this script runs them compiled
 by Mosaic (interpret=False) on the chip, at the geometries the deployments
 emit, and asserts parity with a plain numpy reference:
 
-  - ADC one-hot, shared-list and per-query (the probed-lists path), at the
-    knnlm geometry (m=64, ksub=256) and a small one, fp32 and bf16 LUTs
-  - ADC nibble at m=64 and m=8, fp32 and bf16 LUTs
   - ADC three-plane one-hot (fp32 table values in one bf16 MXU pass) at the
     benchmark cells' geometry (m=64, lists of capacity 1024) and at m=8,
-    on tables whose entries need all three planes
+    on tables whose entries need all three planes, and on a ragged list;
+    beside it the XLA one-hot (ops/pq.adc_scan), the oracle it is held to
   - fused flat list scan for the f32 / f16 / sq8 codecs x l2 / dot at
     d=512 (the ivfsq width), plus the bf16 scan mode
 
@@ -29,19 +27,12 @@ import numpy as np
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 
-def np_adc_shared(lut, codes):
-    nq, L = lut.shape[0], codes.shape[0]
-    out = np.zeros((nq, L), np.float64)  # the golden adds in float64
-    for mi in range(codes.shape[1]):
-        out += lut[:, mi, codes[:, mi].astype(np.int64)]
-    return out
-
-
 def np_adc_per_query(lut, codes):
-    nq, L = codes.shape[0], codes.shape[1]
-    out = np.zeros((nq, L), np.float64)
-    for qi in range(nq):
-        out[qi] = np_adc_shared(lut[qi:qi + 1], codes[qi])[0]
+    """lut (P, m, ksub), codes (P, L, m) -> (P, L); the golden adds in float64."""
+    out = np.zeros(codes.shape[:2], np.float64)
+    for mi in range(codes.shape[2]):
+        out += np.take_along_axis(lut[:, mi, :].astype(np.float64),
+                                  codes[:, :, mi].astype(np.int64), axis=1)
     return out
 
 
@@ -75,37 +66,29 @@ def _report(name, got, want, dt, rtol, atol, **extra):
 def adc_cases(rng):
     import jax.numpy as jnp
 
-    from distributed_faiss_tpu.ops import adc_pallas
+    from distributed_faiss_tpu.ops import adc_pallas, pq
 
     failures = 0
     cases = [
-        # (name, kernel, nq, m, L, lut dtype)
-        ("shared_default_tile", "shared", 64, 16, 5000, "float32"),
-        ("shared_knnlm_geometry", "shared", 32, 64, 4096, "float32"),
-        ("shared_tiny_L", "shared", 4, 8, 13, "float32"),
-        ("onehot_smoke", "onehot", 8, 16, 700, "float32"),
-        ("onehot_knnlm", "onehot", 16, 64, 512, "float32"),
-        ("onehot_knnlm_bf16", "onehot", 16, 64, 512, "bfloat16"),
-        ("nibble_knnlm", "nibble", 16, 64, 512, "float32"),
-        ("nibble_knnlm_bf16", "nibble", 16, 64, 512, "bfloat16"),
-        ("nibble_m8", "nibble", 16, 8, 1024, "float32"),
-        ("nibble_m8_bf16", "nibble", 16, 8, 1024, "bfloat16"),
-        ("nibble_ragged_L", "nibble", 8, 64, 700, "float32"),
+        # (name, kernel, pairs, m, L)
         # the served knnlm scan: one table a (query, probe) pair, capacity
         # 1024; a wrong split or a dropped plane shows only compiled (the
-        # interpreter multiplies in f32: PR 21's nibble finding)
-        ("planes_knnlm_cell", "planes", 128, 64, 1024, "float32"),
-        ("planes_exact_grid", "planes-grid", 16, 8, 1024, "float32"),
-        ("planes_knnlm_wide", "planes-wide", 32, 64, 1024, "float32"),
-        ("planes_m8_cap128", "planes-wide", 16, 8, 128, "float32"),
+        # interpreter multiplies in f32: PR 21's finding)
+        ("planes_knnlm_cell", "planes", 128, 64, 1024),
+        ("planes_exact_grid", "planes-grid", 16, 8, 1024),
+        ("planes_knnlm_wide", "planes-wide", 32, 64, 1024),
+        ("planes_m8_cap128", "planes-wide", 16, 8, 128),
+        ("planes_ragged_L", "planes", 8, 64, 700),
+        # the XLA arm, the oracle of the first-use check and of the guard
+        ("xla_knnlm_cell", "xla", 128, 64, 1024),
+        ("xla_knnlm_wide", "xla-wide", 32, 64, 1024),
     ]
     kernels = {
-        "shared": adc_pallas.adc_scan_shared_pallas,
-        "onehot": adc_pallas.adc_scan_pallas,
-        "nibble": adc_pallas.adc_scan_pallas_nibble,
-        "planes": adc_pallas.adc_scan_pallas_planes,
+        "planes": lambda lut, codes: adc_pallas.adc_scan_pallas_planes(
+            lut, codes, interpret=False),
+        "xla": pq.adc_scan,
     }
-    for name, kind, nq, m, L, dtype in cases:
+    for name, kind, nq, m, L in cases:
         ksub = 256
         kind, _, table_kind = kind.partition("-")
         table = rng.standard_normal((nq, m, ksub))
@@ -119,26 +102,21 @@ def adc_cases(rng):
             # the MXU adds in the result is the golden bit for bit — a
             # dropped or rounded plane cannot hide in accumulation noise
             table = rng.integers(-(1 << 20), 1 << 20, table.shape) * 2.0 ** -13
-        lut = jnp.asarray(table.astype(np.float32), dtype=dtype)
-        shape = (L, m) if kind == "shared" else (nq, L, m)
-        codes = rng.integers(0, ksub, shape).astype(np.uint8)
+        lut_np = table.astype(np.float32)
+        codes = rng.integers(0, ksub, (nq, L, m)).astype(np.uint8)
         t0 = time.time()
-        got = np.asarray(kernels[kind](lut, codes, interpret=False))
+        got = np.asarray(kernels[kind](jnp.asarray(lut_np), codes))
         dt = time.time() - t0
-        # the golden sums the LUT the kernel was given (bf16-rounded or not)
-        lut_np = np.asarray(lut.astype(jnp.float32))
-        want = (np_adc_shared if kind == "shared" else np_adc_per_query)(lut_np, codes)
+        want = np_adc_per_query(lut_np, codes)
         rtol, atol = 1e-4, 1e-4
         if table_kind == "grid":
             rtol = atol = 0.0
         elif table_kind == "wide":
             # entries of 1e3 leave f32 sums of m terms an error of up to
             # m * 2**-24 of the summed magnitudes, under HIGHEST as here
-            mag = (np_adc_shared if kind == "shared" else np_adc_per_query)(
-                np.abs(lut_np), codes)
+            mag = np_adc_per_query(np.abs(lut_np), codes)
             atol = atol + m * 2.0 ** -24 * mag
-        failures += _report(name, got, want, dt, rtol, atol,
-                            nq=nq, m=m, L=L, lut=dtype)
+        failures += _report(name, got, want, dt, rtol, atol, nq=nq, m=m, L=L)
     return failures
 
 

@@ -16,8 +16,8 @@ Phases (default, one rank):
   exact   upstream ``flat`` (config 1): L2, dim 128, 1e6 rows — ids equal to
           the numpy scan's, ties aside
   kernel  every Pallas kernel compiled by Mosaic at its deployment's
-          geometry: knnlm + pallas_adc (fp32 and bf16 LUT), ivfsq and
-          IVF1024,SQ8 at dim 512 + pallas_flat — same recall check, and no
+          geometry: knnlm with pallas_adc forced, ivfsq and IVF1024,SQ8
+          at dim 512 + pallas_flat — same recall check, and no
           demotion in ``ping()["kernels"]``; then benchmarks/tpu_validate.py
           in a child once the rank has exited (the direct-kernel parity run)
 
@@ -254,7 +254,7 @@ def require_tpu(pings):
 def check_no_demotion(client, label):
     for p in client.ping(timeout=60.0):
         check("kernels" in p, f"{label}: ping failed: {p}")
-        check(p["kernels"]["use_nibble"] is True and not p["kernels"]["pallas_degraded"],
+        check(not p["kernels"]["pallas_degraded"],
               f"{label}: a Pallas kernel was demoted at run time: {p['kernels']}")
 
 
@@ -412,16 +412,14 @@ def exact_phase(client, index_id, rng, wd, label, **extra):
 
 def kernel_phase(client, rng, x768, q768, wd):
     """Each Pallas kernel through the served path at its deployment's
-    geometry. m=64, ksub=256 dispatches the nibble ADC kernel; the one-hot
-    ADC kernel is only reached after a demotion here, so it is compiled
-    directly by tpu_validate.py once the rank is gone."""
+    geometry: the three-plane ADC kernel forced on (the main phase's index
+    chooses it itself on a chip), then the flat-scan kernel on both its
+    codecs. tpu_validate.py compiles them directly once the rank is gone."""
     x, q = x768[:ROWS_KERNEL], q768[-(257 + 2 * ROUNDS * ROUND_ROWS):]
     gt = reference(x, q, wd, "kernel knnlm")
-    for index_id, extra in (("knnlm-pallas", dict(pallas_adc=True)),
-                            ("knnlm-pallas-bf16", dict(pallas_adc=True,
-                                                       adc_lut_bf16=True))):
-        knnlm_phase(client, index_id, x, q, gt, wd, f"kernel {index_id}", **extra)
-        client.drop_index(index_id)
+    knnlm_phase(client, "knnlm-pallas", x, q, gt, wd, "kernel knnlm-pallas",
+                pallas_adc=True)
+    client.drop_index("knnlm-pallas")
     gen = lowrank_mixture(rng, 512, 2048)
     x, q = gen(ROWS_KERNEL), gen(257 + 2 * ROUNDS * ROUND_ROWS)
     gt = reference(x, q, wd, "kernel ivf 512-d")

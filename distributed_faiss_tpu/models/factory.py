@@ -134,7 +134,6 @@ def _build_knnlm(cfg: IndexCfg):
             probe_routing=_probe_routing(cfg),
             use_pallas=bool(cfg.extra.get("pallas_adc", False)),
             refine_k_factor=int(cfg.extra.get("refine_k_factor", 0)),
-            adc_lut_bf16=bool(cfg.extra.get("adc_lut_bf16", False)),
         )
     if _probe_routing(cfg):
         logging.getLogger().warning(
@@ -146,8 +145,7 @@ def _build_knnlm(cfg: IndexCfg):
                       kmeans_iters=_kmeans_iters(cfg),
                       # absent: the index chooses its ADC kernel; set: forced
                       use_pallas=cfg.extra.get("pallas_adc"),
-                      refine_k_factor=int(cfg.extra.get("refine_k_factor", 0)),
-                      adc_lut_bf16=bool(cfg.extra.get("adc_lut_bf16", False)))
+                      refine_k_factor=int(cfg.extra.get("refine_k_factor", 0)))
 
 
 def _build_ivfsq(cfg: IndexCfg) -> IVFFlatIndex:

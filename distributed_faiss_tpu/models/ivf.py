@@ -30,7 +30,6 @@ r4). Lists are rebuilt by one bulk append on load.
 
 import functools
 import logging
-import re
 from typing import Dict, Optional
 
 import jax
@@ -38,7 +37,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from distributed_faiss_tpu.models import base
-from distributed_faiss_tpu.ops import distance, kmeans, pq, sq
+from distributed_faiss_tpu.ops import adc_pallas, distance, kmeans, pq, sq
 from distributed_faiss_tpu.utils import sanitize, tracing, xfercheck
 
 logger = logging.getLogger()
@@ -206,11 +205,22 @@ def _ivf_flat_search(centroids, list_data, list_ids, list_sizes, q,
     return vals, ids
 
 
-@functools.partial(jax.jit, static_argnames=("k", "nprobe", "g", "metric", "use_pallas",
-                                             "lut_bf16"))
+def _adc_pair_scores(lut, codes, use_pallas: bool):
+    """ADC scores of P (query, probe) pairs: ``lut`` (P, m, ksub) f32 tables,
+    ``codes`` (P, L, m) uint8 -> (P, L) f32. The one place the PQ programs
+    (here and in parallel/mesh.py) pick between the fused three-plane kernel
+    and the XLA one-hot einsum; ``use_pallas`` is the index's answer
+    (IVFPQIndex._kernel_applies), taken before the trace."""
+    if use_pallas:
+        return adc_pallas.adc_scan_pallas_planes(
+            lut, codes, interpret=not adc_pallas.on_tpu())
+    return pq.adc_scan(lut, codes)
+
+
+@functools.partial(jax.jit, static_argnames=("k", "nprobe", "g", "metric", "use_pallas"))
 def _ivf_pq_search(centroids, codebooks, list_codes, list_ids, list_sizes, q,
                    k: int, nprobe: int, g: int, metric: str,
-                   use_pallas: bool = False, lut_bf16: bool = False):
+                   use_pallas: bool = False):
     q = q.astype(jnp.float32)
     nq = q.shape[0]
     cap = list_codes.shape[1]
@@ -240,31 +250,9 @@ def _ivf_pq_search(centroids, codebooks, list_codes, list_ids, list_sizes, q,
                 lut = lut.reshape(nq, g, m, ksub)
             else:
                 lut = jnp.broadcast_to(shared_lut[:, None], (nq, g, m, ksub))
-            if use_pallas:
-                # fused VMEM kernel: per-(query, probe) LUT vs its code tile.
-                # At f32 table values the three-plane kernel wherever its
-                # geometry holds (one bf16 MXU pass, exact: the cells' path,
-                # chip-timed in PERF.md PR 25); else the older dispatcher.
-                # lut_bf16 rounds the table itself (~0.4% rel), which only
-                # perturbs the ADC shortlist that refine_k_factor rescores
-                # exactly; its one-hot side is exact in bf16.
-                from distributed_faiss_tpu.ops import adc_pallas
-
-                lut2 = lut.reshape(nq * g, m, ksub)
-                codes2 = codes.reshape(nq * g, cap, m)
-                if not lut_bf16 and adc_pallas.planes_supported(m, ksub, cap):
-                    s = adc_pallas.adc_scan_pallas_planes(
-                        lut2, codes2, interpret=not adc_pallas.on_tpu())
-                else:
-                    s = adc_pallas.adc_scan_auto(
-                        lut2.astype(jnp.bfloat16 if lut_bf16 else jnp.float32),
-                        codes2)
-                s = s.reshape(nq, g, cap)
-            else:
-                iota = jnp.arange(ksub, dtype=jnp.int32)
-                onehot = (codes[..., None].astype(jnp.int32) == iota).astype(jnp.float32)
-                s = jnp.einsum("qgmj,qgcmj->qgc", lut, onehot, precision=_HIGHEST,
-                               preferred_element_type=jnp.float32)
+            s = _adc_pair_scores(lut.reshape(nq * g, m, ksub),
+                                 codes.reshape(nq * g, cap, m),
+                                 use_pallas).reshape(nq, g, cap)
             valid = (jnp.arange(cap)[None, None, :] < sizes[:, :, None]) & (ids >= 0)
             s = jnp.where(valid, s, distance.NEG_INF)
         return _merge_group(carry, s.reshape(nq, g * cap), ids.reshape(nq, g * cap), k), None
@@ -301,17 +289,16 @@ def _ivf_flat_search_fused(centroids, list_data, list_ids, list_sizes, refine_da
 
 
 @functools.partial(jax.jit, static_argnames=("k", "adc_k", "nprobe", "g", "metric",
-                                             "use_pallas", "lut_bf16", "refine"))
+                                             "use_pallas", "refine"))
 def _ivf_pq_search_fused(centroids, codebooks, list_codes, list_ids, list_sizes,
                          refine_data, q3, k: int, adc_k: int, nprobe: int, g: int,
-                         metric: str, use_pallas: bool, lut_bf16: bool,
-                         refine: bool):
+                         metric: str, use_pallas: bool, refine: bool):
     """Multi-block IVF-PQ search in one launch (see _ivf_flat_search_fused)."""
 
     def body(qb):
         vals, ids = _ivf_pq_search(centroids, codebooks, list_codes, list_ids,
                                    list_sizes, qb, adc_k, nprobe, g, metric,
-                                   use_pallas=use_pallas, lut_bf16=lut_bf16)
+                                   use_pallas=use_pallas)
         if refine:
             vals, ids = _rerank_exact(refine_data, qb, ids, k, metric)
         return vals, ids
@@ -543,8 +530,7 @@ class IVFFlatIndex(_IVFBase):
         if scan_bf16 and not refine_k_factor:
             raise ValueError(
                 "scan_bf16 perturbs scan scores (bf16 MXU pass) and is only "
-                "legal with refine_k_factor > 0 so the shortlist is rescored "
-                "exactly (the lut_bf16 precedent, ops/adc_pallas.py)"
+                "legal with refine_k_factor > 0 so the shortlist is rescored exactly"
             )
         self.refine_k_factor = int(refine_k_factor)
         self.refine_store = (
@@ -619,9 +605,15 @@ class IVFFlatIndex(_IVFBase):
                 f"({self.norm_lists.cap} != {self.lists.cap})")
         return self.norm_lists.data
 
+    _PALLAS_KERNEL = "flat scan"
+
+    def _kernel_applies(self) -> bool:
+        """pallas_guarded's question: the flat-scan kernel runs where asked."""
+        return self.use_pallas
+
     def _validate_flat_pallas(self, scan) -> None:
         self._pallas_flat_validated = True
-        _first_use_check(self, scan, self._pallas_probe, "flat-scan", 1e-3)
+        _first_use_check(self, scan, self._pallas_probe, self._PALLAS_KERNEL, 1e-3)
 
     def search(self, q: np.ndarray, k: int):
         if self._n == 0:
@@ -657,8 +649,7 @@ class IVFFlatIndex(_IVFBase):
             self._validate_flat_pallas(scan)
 
         def run(b):
-            return pallas_guarded(
-                self, lambda p: scan(b, p), 0, 0, shape=tuple(b.shape))
+            return pallas_guarded(self, lambda p: scan(b, p))
 
         def refine(b, ids):
             return _rerank_exact(self.refine_store.data, b, ids, k, self.metric)
@@ -675,7 +666,6 @@ class IVFFlatIndex(_IVFBase):
                     refine=bool(self.refine_k_factor), list_norms=norms,
                     use_pallas=p, scan_bf16=self.scan_bf16, **extra,
                 ),
-                0, 0, shape=tuple(q3.shape),
             )
 
         return self._search_blocks(
@@ -757,217 +747,32 @@ class IVFFlatIndex(_IVFBase):
         return idx
 
 
-from distributed_faiss_tpu.ops import adc_pallas as _adc_pallas  # noqa: E402
+def pallas_guarded(index, call):
+    """Run ``call(use_pallas)`` on the ladder kernel -> XLA oracle -> demote.
 
-_adc_pallas.NIBBLE_JIT_CONSUMERS += [_ivf_pq_search, _ivf_pq_search_fused]
-
-
-def disable_nibble(m: int, ksub: int) -> bool:
-    """Turn off the nibble ADC kernel process-wide (one-way, idempotent).
-
-    Flipping adc_pallas.USE_NIBBLE alone is not enough: the dispatch is read
-    at trace time, so every compiled variant that baked the nibble kernel in
-    (adc_pallas.NIBBLE_JIT_CONSUMERS — the unsharded AND sharded programs)
-    must be dropped or a later call hits the stale executable and re-faults.
-    The lock makes concurrent demotions clear the caches exactly once; the
-    flag is never restored (monotone), which is what makes the at-call-time
-    attribution in pallas_guarded sound under concurrency.
+    The kernel runs where the index wants it (``index._kernel_applies()``)
+    and this process has not demoted it. If that attempt raises, the XLA
+    path runs as a side-effect-free oracle. It raises too: the request
+    itself is bad (a dim mismatch fails in the shared coarse scoring) —
+    re-raise, no flag flipped, no cache cleared, so one misbehaving client
+    cannot cost the others their kernel. It returns: the kernel is at fault
+    — log, demote it for the rest of this process (``_pallas_runtime_ok``;
+    never persisted, listed in ``ping()["kernels"]["pallas_degraded"]``) and
+    serve the oracle's result. Every attempt runs under
+    ``jax.block_until_ready`` so an asynchronous kernel abort surfaces here,
+    not at a later np.asarray.
     """
-    if not _adc_pallas.nibble_supported(m, ksub):
-        return False
-    with _adc_pallas.NIBBLE_LOCK:
-        if not _adc_pallas.USE_NIBBLE:
-            return False  # already demoted; caches already cleared
-        _adc_pallas.USE_NIBBLE = False
-        _adc_pallas.NIBBLE_SWEEP_EPOCH += 1
-        for fn in _adc_pallas.NIBBLE_JIT_CONSUMERS:
-            fn.clear_cache()
-    return True
-
-
-def _norm_msg(e: Exception) -> str:
-    """Exception text with the unstable parts (hex addresses, digit runs —
-    buffer ids, byte counts) masked out."""
-    return re.sub(r"0x[0-9a-fA-F]+|\d+", "#", str(e))
-
-
-def _same_failure(a: Exception, b: Exception) -> bool:
-    """Conservative "same failure" test for oracle-vs-kernel attribution.
-
-    One bad request can raise with differently-phrased text on the pallas
-    and XLA jit variants (backend wording, embedded addresses / buffer ids),
-    so raw string equality under-matches and a single bad client request
-    could demote the nibble kernel process-wide and trigger a full
-    clear_cache sweep (ADVICE r4). Compare the exception type plus the
-    normalized message.
-    """
-    return type(a) is type(b) and _norm_msg(a) == _norm_msg(b)
-
-
-# pallas_guarded (oracle-failure branch): normalized signatures of every
-# request on which BOTH paths failed while the nibble kernel was on. A
-# repeat of a seen signature demotes the nibble kernel (a broken kernel
-# fails identically every time, and a set survives unrelated bad requests
-# interleaving with it); distinct signatures never accumulate toward a
-# demotion. The signature includes the request's query/batch shape (ADVICE
-# r5): _norm_msg masks every digit run, so two bad requests differing only
-# in numerics used to normalize equal and spuriously demote — a broken
-# kernel repeats on the SAME compiled shape, while distinct-shape bad
-# requests are now distinct signatures. The residual tradeoff (a client
-# retrying one malformed request demotes) is bounded cost (one sweep,
-# monotone), accepted to keep a broken kernel whose oracle failure mirrors
-# it from re-faulting forever. Capped: a process accumulating 16 distinct
-# both-failed signatures with nibble on is systematically unhealthy —
-# treat overflow as a repeat.
-_BOTH_FAILED_SIGS = set()
-_BOTH_FAILED_CAP = 16
-
-
-def pallas_wanted(index) -> bool:
-    """The index's kernel intent: an explicit ``use_pallas`` forces either
-    way; None leaves the choice to what the index can see of itself
-    (``IVFPQIndex._fused_adc_applies``)."""
-    want = index.use_pallas
-    return index._fused_adc_applies() if want is None else bool(want)
-
-
-def pallas_guarded(index, call, m: int, ksub: int, shape=None):
-    """Run ``call(use_pallas)`` with kernel-fault attribution (ADVICE r3: a
-    nibble failure must not abandon the proven one-hot kernel).
-
-    On failure the XLA path runs first as a side-effect-free ORACLE: if it
-    fails too, the request itself is bad — re-raise with no flag flips and
-    no cache wipes (a misbehaving client must not evict healthy compiled
-    variants). If XLA succeeds, a kernel is at fault; which one is decided
-    by the nibble state captured BEFORE the call: USE_NIBBLE is monotone
-    (never restored), so nibble_was_on means the failing executable may
-    have baked the nibble kernel in — demote nibble only and let the next
-    search try the one-hot pallas kernel; nibble_was_off may still be a
-    stale pre-demotion executable (an in-flight trace started before a
-    concurrent demotion can re-insert one after the sweep) — excused when
-    the sweep epoch moved since this call started (any number of in-flight
-    pre-demotion calls) or via the one NIBBLE_SWEPT excuse (a post-sweep
-    call hitting a late re-inserted executable): sweep again, serve the
-    XLA result, and let the next search run a fresh trace. A failure that
-    started after the latest sweep with the excuse spent blames the
-    one-hot kernel itself, and a bounded excuse budget
-    (NIBBLE_EXCUSES_LEFT) keeps concurrent excuse sweeps from excusing
-    each other forever. A broken one-hot behind a broken nibble therefore
-    converges within NIBBLE_EXCUSES_LEFT + 2 failing searches even under
-    constant concurrency, each serving its caller from the XLA result in
-    hand, with no synchronous re-trace inside any request.
-    ``index`` provides use_pallas/_pallas_runtime_ok; every attempt runs
-    under ``jax.block_until_ready`` so asynchronous kernel aborts surface
-    here, not at a later np.asarray. ``shape`` is the request's query/batch
-    shape, folded into the both-failed signature (see _BOTH_FAILED_SIGS).
-
-    The flat-scan kernel (ops/flat_pallas.py) and the three-plane ADC
-    kernel (no nibble rung above it) reuse this guard with m=ksub=0:
-    nibble_supported is then False, which reduces the ladder to exactly
-    "pallas kernel -> XLA oracle -> demote _pallas_runtime_ok".
-    """
-    with_pallas = pallas_wanted(index) and index._pallas_runtime_ok
-    nibble_was_on = _adc_pallas.USE_NIBBLE
-    epoch0 = _adc_pallas.NIBBLE_SWEEP_EPOCH
+    with_pallas = index._kernel_applies() and index._pallas_runtime_ok
     try:
-        out = call(with_pallas)
-        jax.block_until_ready(out)
-        return out
-    except Exception as kernel_err:
+        return jax.block_until_ready(call(with_pallas))
+    except Exception:
         if not with_pallas:
             raise
-        nibble_eligible = _adc_pallas.nibble_supported(m, ksub)
-        # XLA oracle: side-effect-free arbiter of "bad request" vs "bad
-        # kernel"
-        try:
-            out = call(False)
-            jax.block_until_ready(out)
-        except Exception as oracle_err:
-            # the same failure on both paths = the request itself is bad
-            # (a dim mismatch raises in the shared coarse-scoring prefix):
-            # re-raise with no flag flips and no cache wipes, so ONE
-            # misbehaving client request cannot evict healthy compiled
-            # variants. A DIFFERENT oracle failure (say the XLA path OOMs
-            # materializing the one-hot the pallas kernel exists to avoid)
-            # does NOT exonerate the nibble kernel — demote it so the next
-            # search tries the one-hot pallas rung instead of re-faulting
-            # forever. _same_failure is a textual heuristic, so a kernel
-            # fault whose oracle failure mirrors it after normalization
-            # (e.g. two OOMs differing only in byte counts) can look like
-            # a bad request: grant that reading once PER SIGNATURE, then
-            # demote when a seen signature repeats — never-demoting would
-            # re-fault every search forever, while a spurious demotion (a
-            # client retrying one malformed request, or two same-kind bad
-            # requests whose numerics normalize equal — see
-            # _BOTH_FAILED_SIGS) costs one cache sweep per process,
-            # bounded by the monotone flag.
-            if nibble_eligible and nibble_was_on:
-                sig = (type(kernel_err).__name__, _norm_msg(kernel_err), shape)
-                with _adc_pallas.NIBBLE_LOCK:
-                    repeat = (sig in _BOTH_FAILED_SIGS
-                              or len(_BOTH_FAILED_SIGS) >= _BOTH_FAILED_CAP)
-                    _BOTH_FAILED_SIGS.add(sig)
-                if not _same_failure(oracle_err, kernel_err) or repeat:
-                    disable_nibble(m, ksub)
-                    logger.exception(
-                        "pallas ADC failure plus an XLA-oracle failure "
-                        "(distinct or repeated): nibble demoted; the "
-                        "one-hot pallas kernel runs from the next search on"
-                    )
-            raise
-        if nibble_eligible and nibble_was_on:
-            disable_nibble(m, ksub)
-            logger.exception(
-                "pallas ADC failure with the nibble kernel eligible: nibble "
-                "demoted for this process; the one-hot pallas kernel runs "
-                "from the next search on (this request served via XLA)"
-            )
-            return out
-        if nibble_eligible:
-            # nibble was already off at call time — but an executable traced
-            # BEFORE a concurrent demotion can land in the cache after its
-            # sweep, still baking the nibble kernel in. Excuse the failure
-            # (sweep the caches again and serve the XLA result already in
-            # hand — ADVICE r4: a synchronous pallas re-trace here inflated
-            # the request's latency by multi-second compiles just to probe
-            # kernel health) when this call may have raced such a stale
-            # executable: either a sweep happened after this call started
-            # (epoch moved — covers ANY number of in-flight pre-demotion
-            # calls), or the once-per-process NIBBLE_SWEPT excuse is unused
-            # (covers a call that started after the sweep but hit an
-            # executable re-inserted by a completing pre-demotion trace,
-            # which the epoch cannot see). A call that started after the
-            # latest sweep with the excuse spent ran a genuinely fresh
-            # one-hot trace — fall through to the pallas demotion below.
-            with _adc_pallas.NIBBLE_LOCK:
-                # the excuse budget bounds the epoch rule under concurrency:
-                # each excuse sweep moves the epoch, which would excuse every
-                # call that entered before it — without the cap, >=2 requests
-                # permanently in flight against a genuinely broken one-hot
-                # kernel would excuse each other forever (r5 review)
-                excused = ((_adc_pallas.NIBBLE_SWEEP_EPOCH > epoch0
-                            or not _adc_pallas.NIBBLE_SWEPT)
-                           and _adc_pallas.NIBBLE_EXCUSES_LEFT > 0)
-                if excused:
-                    _adc_pallas.NIBBLE_EXCUSES_LEFT -= 1
-                    _adc_pallas.NIBBLE_SWEPT = True
-                    _adc_pallas.NIBBLE_SWEEP_EPOCH += 1
-                    for fn in _adc_pallas.NIBBLE_JIT_CONSUMERS:
-                        fn.clear_cache()
-            if excused:
-                logger.exception(
-                    "pallas ADC failure with nibble already demoted — "
-                    "possibly a stale pre-demotion executable; caches "
-                    "swept, this request served via XLA, the next search "
-                    "runs a fresh one-hot trace"
-                )
-                return out
+        out = jax.block_until_ready(call(False))  # raises: a bad request
         logger.exception(
             "pallas kernel (%s) failed on this backend; using the XLA path "
             "for the rest of this process (persisted use_pallas intent is "
-            "unchanged)",
-            "ADC one-hot" if ksub else getattr(index, "_PALLAS_KERNEL", "flat scan"),
-        )
+            "unchanged)", index._PALLAS_KERNEL)
         index._pallas_runtime_ok = False
         return out
 
@@ -981,8 +786,7 @@ class IVFPQIndex(_IVFBase):
 
     def __init__(self, dim: int, nlist: int, m: int = 64, nbits: int = 8,
                  metric: str = "l2", kmeans_iters: int = 10, pq_iters: int = 15,
-                 use_pallas: Optional[bool] = None, refine_k_factor: int = 0,
-                 adc_lut_bf16: bool = False):
+                 use_pallas: Optional[bool] = None, refine_k_factor: int = 0):
         super().__init__(dim, nlist, metric, kmeans_iters)
         if dim % m != 0:
             raise ValueError(f"dim {dim} not divisible by PQ m={m}")
@@ -992,15 +796,9 @@ class IVFPQIndex(_IVFBase):
         self.nbits = nbits
         self.pq_iters = pq_iters
         # fused ADC kernel instead of the XLA one-hot: None = the index
-        # chooses from what it can see (_fused_adc_applies); True / False
-        # force either path (tests, A/B runs, the knnlm builder's
-        # ``pallas_adc`` extra)
+        # chooses from what it can see; True / False force (tests, A/B runs,
+        # the knnlm builder's ``pallas_adc`` extra) — _kernel_applies
         self.use_pallas = None if use_pallas is None else bool(use_pallas)
-        # bf16 LUT inside the pallas kernel, where use_pallas is forced on
-        # (a rounded table is never the index's own choice); pair with
-        # refine_k_factor to keep final scores exact. No effect on the XLA
-        # path.
-        self.adc_lut_bf16 = adc_lut_bf16
         self._pallas_runtime_ok = True  # runtime disable, not persisted
         self._adc_validated = False  # first fused scan checked against XLA
         # refine_k_factor > 0: keep fp16 raw rows in HBM and exactly rescore
@@ -1023,19 +821,35 @@ class IVFPQIndex(_IVFBase):
 
     _PALLAS_KERNEL = "ADC three-plane"
 
-    def _planes_geometry(self) -> bool:
-        """The three-plane kernel takes this index's lists as they are now
-        (the capacity grows with the lists, so ask at every search)."""
-        return self.lists is not None and _adc_pallas.planes_supported(
-            self.m, 1 << self.nbits, self.lists.cap)
+    def _kernel_applies(self) -> bool:
+        """Does the fused ADC kernel run for this search? The one place that
+        is decided, asked at every search since the capacity grows with the
+        lists. ``use_pallas`` False: never. Else only at a geometry the
+        three-plane kernel compiles for (ksub 256, capacity in whole 128-row
+        tiles, a table of m inside the VMEM model); anything else runs the
+        XLA one-hot. There None takes the kernel where the code can see a
+        TPU (elsewhere it would run in the interpreter) and True wherever
+        the geometry holds (tests, the sharded index, chip_smoke.py's A/B)."""
+        if self.use_pallas is False or self.lists is None:
+            return False
+        return (adc_pallas.planes_supported(self.m, 1 << self.nbits, self.lists.cap)
+                and (self.use_pallas is True or adc_pallas.on_tpu()))
 
-    def _fused_adc_applies(self) -> bool:
-        """``use_pallas=None``: take the fused kernel where the code can see
-        that it applies — a TPU backend (elsewhere the kernel would run in
-        the interpreter) and a geometry the three-plane kernel compiles for
-        (ksub 256, capacity in whole 128-row tiles, a table of m inside the
-        VMEM model). Anything else runs the XLA one-hot, as before."""
-        return _adc_pallas.on_tpu() and self._planes_geometry()
+    def _guarded_scan(self, call):
+        """pallas_guarded, plus the count row that says the scan ran the
+        fused kernel (``engine.scan_fused``, beside the ``engine.scan``
+        stage this runs in): the last path tried is the one whose result is
+        served."""
+        tried = []
+
+        def attempt(with_pallas):
+            tried.append(with_pallas)
+            return call(with_pallas)
+
+        out = pallas_guarded(self, attempt)
+        if tried[-1]:
+            tracing.count("engine.scan_fused")
+        return out
 
     def train(self, x: np.ndarray) -> None:
         x = np.asarray(x, np.float32)
@@ -1074,11 +888,6 @@ class IVFPQIndex(_IVFBase):
         g = probe_group_size(
             nprobe, pq_probe_payload_bytes(self.lists.cap, self.m, nq_block=rows))
         adc_k = k * self.refine_k_factor if self.refine_k_factor else k
-        lut_bf16 = self.use_pallas is True and self.adc_lut_bf16
-        # the three-plane kernel has no nibble rung above it: its guard is
-        # the plain "kernel -> XLA oracle -> demote" ladder (m = ksub = 0)
-        planes = not lut_bf16 and self._planes_geometry()
-        geom = (0, 0) if planes else (self.m, self.codebooks.shape[1])
 
         def adc(b, with_pallas):
             return sanitize.maybe_checked(
@@ -1086,11 +895,10 @@ class IVFPQIndex(_IVFBase):
                 self.centroids, self.codebooks, self.lists.data, self.lists.ids,
                 self.lists.sizes, b, k=adc_k, nprobe=nprobe, g=g,
                 metric=self.metric, use_pallas=with_pallas,
-                lut_bf16=with_pallas and lut_bf16,
             )
 
-        if (planes and not self._adc_validated and self._pallas_runtime_ok
-                and pallas_wanted(self)):
+        if (self._kernel_applies() and self._pallas_runtime_ok
+                and not self._adc_validated):
             # first fused scan of this index (warm-up, in a served rank):
             # its ADC scores against the XLA path's on one small block
             self._adc_validated = True
@@ -1099,24 +907,8 @@ class IVFPQIndex(_IVFBase):
                 jax.device_put(distance.pad_rows(np.asarray(q[:8], np.float32), 8)),
                 self._PALLAS_KERNEL, 1e-4)
 
-        def guarded(call, shape):
-            """pallas_guarded, plus the count row that says the block's
-            scan ran the fused kernel (``engine.scan_fused``, beside the
-            ``engine.scan`` stage this runs in): the last path tried is the
-            one whose result is served."""
-            tried = []
-
-            def attempt(with_pallas):
-                tried.append(with_pallas)
-                return call(with_pallas)
-
-            out = pallas_guarded(self, attempt, *geom, shape=shape)
-            if tried[-1]:
-                tracing.count("engine.scan_fused")
-            return out
-
         def run(b):
-            return guarded(lambda p: adc(b, p), tuple(b.shape))
+            return self._guarded_scan(lambda p: adc(b, p))
 
         def refine(b, ids):
             return _rerank_exact(self.refine_store.data, b, ids, k, self.metric)
@@ -1129,13 +921,11 @@ class IVFPQIndex(_IVFBase):
                 self.refine_store.data if self.refine_k_factor else None,
                 q3, k=k, adc_k=adc_k, nprobe=nprobe, g=g, metric=self.metric,
                 use_pallas=with_pallas,
-                lut_bf16=with_pallas and lut_bf16,
                 refine=bool(self.refine_k_factor),
             )
 
         def run_fused(q3):
-            # same degrade ladder as the per-block path
-            return guarded(lambda p: adc_fused(q3, p), tuple(q3.shape))
+            return self._guarded_scan(lambda p: adc_fused(q3, p))
 
         return self._search_blocks(
             q, k, run, block=nb, fused_fn=run_fused,
@@ -1162,12 +952,8 @@ class IVFPQIndex(_IVFBase):
             "nprobe": self.nprobe,
             "trained": self.is_trained,
             "refine_k_factor": self.refine_k_factor,
-            # ``pallas_adc`` is the intent as held (None = choose, True /
-            # False = forced); ``use_pallas`` stays a plain bool for the
-            # sharded subclass's loader and for older readers
-            "use_pallas": bool(self.use_pallas),
+            # the kernel intent as held: None = choose, True / False = forced
             "pallas_adc": self.use_pallas,
-            "adc_lut_bf16": self.adc_lut_bf16,
         }
         if self.is_trained:
             state["centroids"] = np.asarray(self.centroids)
@@ -1178,18 +964,23 @@ class IVFPQIndex(_IVFBase):
                 state["refine_rows"] = self.refine_store.all_rows()
         return state
 
+    @staticmethod
+    def _saved_kernel_intent(state) -> Optional[bool]:
+        """``use_pallas`` as a snapshot holds it. One from before
+        ``pallas_adc`` holds a bool ``use_pallas`` only, and its False was
+        the default of the time, not a choice: it loads as "choose" and must
+        not pin the XLA path; its True was asked for. An old snapshot's
+        ``adc_lut_bf16`` (a rounded table) is not read: exact tables serve."""
+        if "pallas_adc" in state:
+            return state["pallas_adc"]
+        return True if state.get("use_pallas") else None
+
     @classmethod
     def from_state_dict(cls, state) -> "IVFPQIndex":
-        # a snapshot from before ``pallas_adc`` holds use_pallas only, and
-        # its False was the default of the time, not a choice: it loads as
-        # "choose" and must not pin the XLA path; its True was asked for
-        forced = (state["pallas_adc"] if "pallas_adc" in state
-                  else (True if state.get("use_pallas") else None))
         idx = cls(int(state["dim"]), int(state["nlist"]), int(state["m"]),
                   int(state["nbits"]), str(state["metric"]),
-                  use_pallas=forced,
-                  refine_k_factor=int(state.get("refine_k_factor", 0)),
-                  adc_lut_bf16=bool(state.get("adc_lut_bf16", False)))
+                  use_pallas=cls._saved_kernel_intent(state),
+                  refine_k_factor=int(state.get("refine_k_factor", 0)))
         idx.nprobe = int(state["nprobe"])
         if not bool(state["trained"]):
             return idx

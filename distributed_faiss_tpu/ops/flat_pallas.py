@@ -14,9 +14,8 @@ the size/ids validity mask is applied before the masked ``(nq, g, cap)``
 score block is written out.
 
 ``scan_bf16=True`` runs the MXU dot in native bf16 (halving the kernel's
-VMEM compute traffic, the measured bottleneck class — see the adc_pallas
-``lut_bf16`` precedent); models gate it behind ``refine_k_factor > 0`` so
-the shortlist is always rescored exactly.
+VMEM compute traffic, the measured bottleneck class); models gate it behind
+``refine_k_factor > 0`` so the shortlist is always rescored exactly.
 
 ``interpret=True`` (automatic off-TPU) runs the same kernel through the
 Pallas interpreter so CPU tests cover the exact kernel code path.
@@ -105,7 +104,7 @@ def _flat_kernel(metric: str, codec: str, scan_bf16: bool, stored_norms: bool,
         x = x.astype(jnp.float32)
     if scan_bf16:
         # native bf16 MXU pass, fp32 accumulation (HIGHEST's multi-pass
-        # trick only exists for f32 operands — see adc_pallas._adc_matmul)
+        # trick only exists for f32 operands)
         ip = jax.lax.dot_general(
             qf.astype(jnp.bfloat16), x.astype(jnp.bfloat16),
             (((1,), (1,)), ((), ())),

@@ -1065,12 +1065,11 @@ def _sharded_ivf_flat_search_fused(centroids, list_data, list_ids, list_sizes, q
 
 
 @functools.partial(jax.jit, static_argnames=("mesh", "k", "nprobe", "g", "metric",
-                                             "use_pallas", "adc_k", "lut_bf16"))
+                                             "use_pallas", "adc_k"))
 def _sharded_ivf_pq_search_fused(centroids, codebooks, list_codes, list_ids,
                                  list_sizes, q3, mesh, k: int, nprobe: int,
                                  g: int, metric: str, use_pallas: bool = False,
-                                 adc_k: int = 0, raw_data=None,
-                                 lut_bf16: bool = False):
+                                 adc_k: int = 0, raw_data=None):
     """Multi-block masked sharded IVF-PQ in one launch (see
     _sharded_ivf_flat_search_fused)."""
 
@@ -1078,18 +1077,17 @@ def _sharded_ivf_pq_search_fused(centroids, codebooks, list_codes, list_ids,
         return _sharded_ivf_pq_search(centroids, codebooks, list_codes,
                                       list_ids, list_sizes, qb, mesh, k,
                                       nprobe, g, metric, use_pallas=use_pallas,
-                                      adc_k=adc_k, raw_data=raw_data,
-                                      lut_bf16=lut_bf16)
+                                      adc_k=adc_k, raw_data=raw_data)
 
     return jax.lax.map(body, q3)
 
 
 @functools.partial(jax.jit, static_argnames=("mesh", "k", "nprobe", "g", "metric",
-                                             "use_pallas", "adc_k", "lut_bf16"))
+                                             "use_pallas", "adc_k"))
 def _sharded_ivf_pq_search(centroids, codebooks, list_codes, list_ids, list_sizes,
                            q, mesh, k: int, nprobe: int, g: int, metric: str,
                            use_pallas: bool = False, adc_k: int = 0,
-                           raw_data=None, lut_bf16: bool = False):
+                           raw_data=None):
     """IVF-PQ with mesh-sharded code lists: per-chip ADC over owned probes
     (residual LUTs for l2 computed locally against replicated centroids),
     ICI all_gather merge. Same ownership masking trade-off as
@@ -1142,19 +1140,9 @@ def _sharded_ivf_pq_search(centroids, codebooks, list_codes, list_ids, list_size
                 lut = lut.reshape(nq, g, m, ksub)
             else:
                 lut = jnp.broadcast_to(shared_lut[:, None], (nq, g, m, ksub))
-            if use_pallas:
-                from distributed_faiss_tpu.ops import adc_pallas
-
-                s = adc_pallas.adc_scan_auto(
-                    lut.reshape(nq * g, m, ksub).astype(
-                        jnp.bfloat16 if lut_bf16 else jnp.float32),
-                    codes.reshape(nq * g, cap, m),
-                ).reshape(nq, g, cap)
-            else:
-                iota = jnp.arange(ksub, dtype=jnp.int32)
-                onehot = (codes[..., None].astype(jnp.int32) == iota).astype(jnp.float32)
-                s = jnp.einsum("qgmj,qgcmj->qgc", lut, onehot, precision=_HIGHEST,
-                               preferred_element_type=jnp.float32)
+            s = ivfmod._adc_pair_scores(lut.reshape(nq * g, m, ksub),
+                                        codes.reshape(nq * g, cap, m),
+                                        use_pallas).reshape(nq, g, cap)
             valid = (jnp.arange(cap)[None, None, :] < sizes[:, :, None])
             valid = valid & (ids >= 0) & mine[:, :, None]
             s = jnp.where(valid, s, distance.NEG_INF)
@@ -1216,11 +1204,11 @@ class ShardedIVFPQIndex(IVFPQIndex):
                  metric: str = "l2", mesh: Optional[Mesh] = None,
                  kmeans_iters: int = 10, pq_iters: int = 15,
                  probe_routing: bool = False, use_pallas: bool = False,
-                 refine_k_factor: int = 0, adc_lut_bf16: bool = False):
+                 refine_k_factor: int = 0):
         super().__init__(dim, nlist, m=m, nbits=nbits, metric=metric,
                          kmeans_iters=kmeans_iters, pq_iters=pq_iters,
-                         use_pallas=use_pallas, refine_k_factor=refine_k_factor,
-                         adc_lut_bf16=adc_lut_bf16)
+                         use_pallas=bool(use_pallas),
+                         refine_k_factor=refine_k_factor)
         # the single-device refine store the parent builds is replaced by a
         # mesh-sharded raw-row store laid out exactly like the code lists
         # (persistence reads it back through the shared id -> (list, pos)
@@ -1281,7 +1269,6 @@ class ShardedIVFPQIndex(IVFPQIndex):
                 _replicated(self.mesh, np.int32(n)), self.mesh, k,
                 nprobe, bucket, group, self.metric, use_pallas=pallas_on,
                 adc_k=adc_k, raw_data=raw,
-                lut_bf16=pallas_on and self.adc_lut_bf16,
             )
 
         nb = base.pick_query_block(
@@ -1296,21 +1283,14 @@ class ShardedIVFPQIndex(IVFPQIndex):
                 self.lists.sizes, _replicated(self.mesh, b), self.mesh, k,
                 nprobe, g, self.metric,
                 use_pallas=pallas_on, adc_k=adc_k, raw_data=raw,
-                lut_bf16=pallas_on and self.adc_lut_bf16,
             )
 
         def guarded(call, *args):
-            # same degrade ladder as the unsharded path: nibble pallas ->
-            # one-hot pallas -> XLA, one rung per proven failure; the first
-            # arg is always the query block/stack, whose shape keys the
-            # both-failed signature (ADVICE r5). launches counts INSIDE the
-            # ladder so a proven-failure XLA re-dispatch is a second counted
-            # launch (the perf rows must expose the degrade, not hide it)
-            return ivfmod.pallas_guarded(
-                self, _counted(self, lambda p: call(*args, p)),
-                self.m, self.codebooks.shape[1],
-                shape=tuple(args[0].shape),
-            )
+            # the unsharded path's ladder (kernel -> XLA oracle -> demote).
+            # launches counts INSIDE it so a proven-failure XLA re-dispatch
+            # is a second counted launch (the perf rows must expose the
+            # degrade, not hide it)
+            return self._guarded_scan(_counted(self, lambda p: call(*args, p)))
 
         if self.probe_routing:
             return _routed_search_blocks(
@@ -1327,7 +1307,6 @@ class ShardedIVFPQIndex(IVFPQIndex):
                 self.lists.sizes, _replicated(self.mesh, q3), self.mesh, k,
                 nprobe, g, self.metric,
                 use_pallas=pallas_on, adc_k=adc_k, raw_data=raw,
-                lut_bf16=pallas_on and self.adc_lut_bf16,
             )
 
         return self._search_blocks(
@@ -1358,9 +1337,8 @@ class ShardedIVFPQIndex(IVFPQIndex):
         idx = cls(int(state["dim"]), int(state["nlist"]), m=int(state["m"]),
                   nbits=int(state["nbits"]), metric=str(state["metric"]),
                   probe_routing=bool(state.get("probe_routing", False)),
-                  use_pallas=bool(state.get("use_pallas", False)),
-                  refine_k_factor=int(state.get("refine_k_factor", 0)),
-                  adc_lut_bf16=bool(state.get("adc_lut_bf16", False)))
+                  use_pallas=cls._saved_kernel_intent(state),
+                  refine_k_factor=int(state.get("refine_k_factor", 0)))
         idx.nprobe = int(state["nprobe"])
         if not bool(state["trained"]):
             return idx
@@ -1604,13 +1582,12 @@ def _sharded_ivf_flat_search_routed(centroids, list_data, list_ids, list_sizes, 
 
 @functools.partial(jax.jit, static_argnames=("mesh", "k", "nprobe", "pair_bucket",
                                              "group", "metric", "use_pallas",
-                                             "adc_k", "lut_bf16"))
+                                             "adc_k"))
 def _sharded_ivf_pq_search_routed(centroids, codebooks, list_codes, list_ids,
                                   list_sizes, q, nq_real, mesh, k: int,
                                   nprobe: int, pair_bucket: int, group: int,
                                   metric: str, use_pallas: bool = False,
-                                  adc_k: int = 0, raw_data=None,
-                                  lut_bf16: bool = False):
+                                  adc_k: int = 0, raw_data=None):
     """Probe-routed sharded IVF-PQ: per-pair residual LUTs + ADC (one-hot
     einsum or fused pallas kernel) over owned pairs only (same scaffold as
     the flat variant). adc_k/raw_data enable pre-merge exact refine — see
@@ -1622,7 +1599,6 @@ def _sharded_ivf_pq_search_routed(centroids, codebooks, list_codes, list_ids,
     _, probes = distance.segmented_argtopk(coarse, nprobe)
     cap = list_codes.shape[1]
     S = mesh.shape[AXIS]
-    m, ksub, _ = codebooks.shape
     refine = raw_data is not None
 
     def local(q, probes, nq_real, codes_local, ids_local, sizes_local, raw_local):
@@ -1637,17 +1613,7 @@ def _sharded_ivf_pq_search_routed(centroids, codebooks, list_codes, list_ids,
                 r = qv
             lut = pqops.adc_lut(r, codebooks, metric=metric)  # (g, m, ksub)
             codes = codes_local[slot]                    # (g, cap, m)
-            if use_pallas:
-                from distributed_faiss_tpu.ops import adc_pallas
-
-                s = adc_pallas.adc_scan_auto(
-                    lut.astype(jnp.bfloat16 if lut_bf16 else jnp.float32),
-                    codes)  # (g, cap)
-            else:
-                iota = jnp.arange(ksub, dtype=jnp.int32)
-                onehot = (codes[..., None].astype(jnp.int32) == iota).astype(jnp.float32)
-                s = jnp.einsum("gmj,gcmj->gc", lut, onehot, precision=_HIGHEST,
-                               preferred_element_type=jnp.float32)
+            s = ivfmod._adc_pair_scores(lut, codes, use_pallas)  # (g, cap)
             ids = ids_local[slot]
             sizes = sizes_local[slot]
             ok = (jnp.arange(cap)[None, :] < sizes[:, None]) & (ids >= 0)
@@ -1768,14 +1734,3 @@ def routed_pair_bucket(nq: int, nprobe: int, S: int, group: int, slack: float = 
     """Fixed per-chip pair budget: slack x the uniform share, group-aligned."""
     b = max(group, int(-(-nq * nprobe * slack // S)))
     return -(-b // group) * group
-
-
-# these sharded programs bake the adc_scan_auto nibble dispatch in at trace
-# time; disable_nibble (models/ivf.py) must be able to drop their cached
-# variants along with the unsharded ones
-from distributed_faiss_tpu.ops import adc_pallas as _adc_pallas  # noqa: E402
-
-_adc_pallas.NIBBLE_JIT_CONSUMERS += [
-    _sharded_ivf_pq_search, _sharded_ivf_pq_search_fused,
-    _sharded_ivf_pq_search_routed,
-]

@@ -730,18 +730,16 @@ class IndexServer:
         indexes_lock so a long device call on one index can't stall the
         registry (and with it every other RPC).
 
-        ``kernels`` surfaces ADC runtime demotions (models/ivf.py
-        pallas_guarded): ``use_nibble`` is the process-wide nibble-kernel
-        flag, ``pallas_degraded`` lists indexes whose configured pallas
-        intent fell back to XLA on this backend — an operator's cue to
-        check the rank's logs before trusting its serving throughput.
+        ``kernels`` surfaces runtime kernel demotions (models/ivf.py
+        pallas_guarded, _first_use_check): ``pallas_degraded`` lists the
+        indexes whose pallas kernel fell back to XLA on this backend — an
+        operator's cue to check the rank's logs before trusting its
+        serving throughput.
         ``device`` is :func:`device_report`: the platform and chips this
         rank really runs on."""
         with self.indexes_lock:
             snapshot = list(self.indexes.items())
         states = {iid: idx.get_state().name for iid, idx in snapshot}
-        from distributed_faiss_tpu.ops import adc_pallas
-
         degraded = []
         for iid, idx in snapshot:
             tpu_index = getattr(idx, "tpu_index", None)
@@ -752,8 +750,7 @@ class IndexServer:
         return {
             "rank": self.rank,
             "indexes": states,
-            "kernels": {"use_nibble": adc_pallas.USE_NIBBLE,
-                        "pallas_degraded": degraded},
+            "kernels": {"pallas_degraded": degraded},
             "device": device_report(),
         }
 

@@ -81,21 +81,6 @@ REGISTRY = (
     # --- ops/adc_pallas.py -----------------------------------------------
     {"path": "distributed_faiss_tpu/ops/adc_pallas.py",
      "import": "distributed_faiss_tpu.ops.adc_pallas",
-     "qualname": "adc_scan_shared_pallas",
-     "trace": True, "spec": "spec_adc_scan_shared_pallas",
-     "buckets": None, "budget": 0, "hot": True},
-    {"path": "distributed_faiss_tpu/ops/adc_pallas.py",
-     "import": "distributed_faiss_tpu.ops.adc_pallas",
-     "qualname": "adc_scan_pallas",
-     "trace": True, "spec": "spec_adc_scan_pallas",
-     "buckets": None, "budget": 0, "hot": True},
-    {"path": "distributed_faiss_tpu/ops/adc_pallas.py",
-     "import": "distributed_faiss_tpu.ops.adc_pallas",
-     "qualname": "adc_scan_pallas_nibble",
-     "trace": True, "spec": "spec_adc_scan_pallas_nibble",
-     "buckets": None, "budget": 0, "hot": True},
-    {"path": "distributed_faiss_tpu/ops/adc_pallas.py",
-     "import": "distributed_faiss_tpu.ops.adc_pallas",
      "qualname": "adc_scan_pallas_planes",
      "trace": True, "spec": "spec_adc_scan_pallas",
      "buckets": None, "budget": 0, "hot": True},
@@ -258,7 +243,7 @@ _CORPUS = 4096   # flat corpus rows (pow2 — WRITE_BUCKET grown)
 _NLIST = 64      # IVF lists (pow2 padded)
 _CAP = 64        # per-list capacity (pow2 grown)
 _NPROBE = 8
-_M = 8           # PQ subspaces (nibble path needs m % 8 == 0)
+_M = 8           # PQ subspaces
 _KSUB = 256
 _L = 512         # ADC candidate-list length
 
@@ -326,19 +311,7 @@ def spec_flat_list_scan_pallas():
     ]
 
 
-def spec_adc_scan_shared_pallas():
-    lut = _sds((_K, _M, _KSUB), "float32")
-    codes = _sds((_L, _M), "uint8")
-    return [((lut, codes), dict(interpret=True))]
-
-
 def spec_adc_scan_pallas():
-    lut = _sds((_K, _M, _KSUB), "float32")
-    codes = _sds((_K, _L, _M), "uint8")
-    return [((lut, codes), dict(interpret=True))]
-
-
-def spec_adc_scan_pallas_nibble():
     lut = _sds((_K, _M, _KSUB), "float32")
     codes = _sds((_K, _L, _M), "uint8")
     return [((lut, codes), dict(interpret=True))]
@@ -451,11 +424,7 @@ def spec_ivf_pq_search():
     sizes = _sds((_NLIST,), "int32")
     q = _sds((_NQ, _D), "float32")
     stat = dict(k=_K, nprobe=_NPROBE, g=_NPROBE, metric="l2")
-    return [
-        ((cents, _codebooks(), codes, ids, sizes, q), stat),
-        ((cents, _codebooks(), codes, ids, sizes, q),
-         dict(stat, lut_bf16=True)),
-    ]
+    return [((cents, _codebooks(), codes, ids, sizes, q), stat)]
 
 
 def spec_ivf_flat_search_fused():
@@ -477,7 +446,7 @@ def spec_ivf_pq_search_fused():
     q3 = _sds((_NBLOCKS, _NQ, _D), "float32")
     return [((cents, _codebooks(), codes, ids, sizes, refine, q3),
              dict(k=_K, adc_k=4 * _K, nprobe=_NPROBE, g=_NPROBE, metric="l2",
-                  use_pallas=False, lut_bf16=False, refine=True))]
+                  use_pallas=False, refine=True))]
 
 
 def _sharded_flat_operands():
@@ -563,8 +532,6 @@ def spec_sharded_ivf_pq_search():
         ((cents, _codebooks(), codes, ids, sizes, q), stat),
         ((cents, _codebooks(), codes, ids, sizes, q),
          dict(stat, adc_k=4 * _K, raw_data=raw)),
-        ((cents, _codebooks(), codes, ids, sizes, q),
-         dict(stat, lut_bf16=True)),
     ]
 
 

@@ -36,18 +36,13 @@ def _flat(codec, metric, d, nq, g, cap, nlist=1024, scan_bf16=False):
     return name + (" bf16" if scan_bf16 else ""), flat_pallas.flat_list_scan_pallas, sig
 
 
-def _adc(kind, m, lut_dtype, nq, L):
-    fn = {"shared": adc_pallas.adc_scan_shared_pallas,
-          "onehot": adc_pallas.adc_scan_pallas,
-          "nibble": adc_pallas.adc_scan_pallas_nibble,
-          "planes": adc_pallas.adc_scan_pallas_planes}[kind]
-
+def _adc(m, pairs, L):
     def sig(sds):
-        codes = (L, m) if kind == "shared" else (nq, L, m)
-        return ((sds((nq, m, 256), lut_dtype), sds(codes, "uint8")),
+        return ((sds((pairs, m, 256), "float32"), sds((pairs, L, m), "uint8")),
                 dict(interpret=False))
 
-    return f"adc {kind} m={m} {lut_dtype} nq={nq} L={L}", fn, sig
+    return (f"adc planes m={m} float32 nq={pairs} L={L}",
+            adc_pallas.adc_scan_pallas_planes, sig)
 
 
 def cases():
@@ -63,21 +58,26 @@ def cases():
     # the widest scalar prefetch the block picker can ask for: 1024 queries
     # x 8 probes (ivf_simple width) — two of these must fit SMEM
     out.append(_flat("f32", "l2", 128, 1024, 8, 1024))
-    # knnlm: 64 x 8-bit codes, lists of 512, a 1024-query block of LUTs; and
-    # the smallest nibble-eligible m
-    for lut in ("float32", "bfloat16"):
-        out.append(_adc("nibble", 64, lut, 1024, 512))
-        out.append(_adc("onehot", 64, lut, 1024, 512))
-        out.append(_adc("shared", 64, lut, 32, 4096))
-        for kind in ("nibble", "onehot", "shared"):
-            out.append(_adc(kind, 8, lut, 64, 1024))
-    # the three-plane kernel at the benchmark cells' shapes: one table a
+    # the three-plane ADC kernel at the benchmark cells' shapes: one table a
     # (query, probe) pair of an online window (4 x 32), a 64-row and a
     # 256-row window, lists of capacity 1024; and its smallest geometry
     for pairs in (128, 2048, 8192):
-        out.append(_adc("planes", 64, "float32", pairs, 1024))
-    out.append(_adc("planes", 8, "float32", 64, 128))
-    out.append(_adc("planes", 128, "float32", 16, 1024))  # twice the cells' table
+        out.append(_adc(64, pairs, 1024))
+    out.append(_adc(8, 64, 128))
+    out.append(_adc(128, 16, 1024))  # twice the cells' table
+    # and along the edges of planes_supported: the table's width at the
+    # cells' capacity (256 is the widest compiled), the capacity at the
+    # cells' table (4096 is ivfsq's, 512 the lists the older kernels were
+    # compiled at), the narrowest table at a short and a long list, one
+    # sublane tile of pairs (a single-query bucket), and wide with long
+    for m in (16, 32, 256):
+        out.append(_adc(m, 64, 1024))
+    for L in (128, 256, 512, 2048, 4096):
+        out.append(_adc(64, 64, L))
+    for L in (1024, 4096):
+        out.append(_adc(8, 64, L))
+    out.append(_adc(64, 8, 1024))
+    out.append(_adc(128, 16, 4096))
     return out
 
 

@@ -4,364 +4,190 @@ code path the TPU runs compiled)."""
 import numpy as np
 import pytest
 
-from distributed_faiss_tpu.ops import adc_pallas, pq
+from distributed_faiss_tpu.ops import adc_pallas
 from distributed_faiss_tpu.utils import tracing
+from tools.graftlint.ir.harness import _walk_eqns
 
 
-@pytest.fixture
-def problem(rng):
-    nq, m, ksub, L = 8, 4, 256, 700  # L deliberately not a tile multiple
-    lut = rng.standard_normal((nq, m, ksub)).astype(np.float32)
-    codes = rng.integers(0, 256, (L, m)).astype(np.uint8)
-    return lut, codes
+# ------------------------------------------------------------ the one guard
 
 
-def np_adc(lut, codes):
-    nq = lut.shape[0]
-    L = codes.shape[0]
-    out = np.zeros((nq, L), np.float32)
-    for mi in range(codes.shape[1]):
-        out += lut[:, mi, codes[:, mi].astype(np.int64)]
-    return out
-
-
-def test_shared_kernel_golden(problem):
-    lut, codes = problem
-    got = np.asarray(adc_pallas.adc_scan_shared_pallas(lut, codes, tile=128, interpret=True))
-    np.testing.assert_allclose(got, np_adc(lut, codes), rtol=1e-5, atol=1e-5)
-
-
-def test_shared_kernel_matches_xla_path(problem):
-    lut, codes = problem
-    got = np.asarray(adc_pallas.adc_scan_shared_auto(lut, codes, tile=256))
-    want = np.asarray(pq.adc_scan_shared(lut, codes))
-    np.testing.assert_allclose(got, want, rtol=1e-4, atol=1e-4)
-
-
-def test_per_query_kernel_golden(rng):
-    nq, m, ksub, L = 5, 8, 256, 300
-    lut = rng.standard_normal((nq, m, ksub)).astype(np.float32)
-    codes = rng.integers(0, 256, (nq, L, m)).astype(np.uint8)
-    got = np.asarray(adc_pallas.adc_scan_pallas(lut, codes, tile=128, interpret=True))
-    want = np.asarray(pq.adc_scan(lut, codes))
-    np.testing.assert_allclose(got, want, rtol=1e-4, atol=1e-4)
-
-
-def test_bf16_lut_close_to_f32(problem):
-    """bf16 LUT (the fast serving mode, 1.5x on TPU v5e): one-hot side is
-    exact, so error is bounded by bf16 rounding of the LUT entries."""
-    import jax.numpy as jnp
-
-    lut, codes = problem
-    got = np.asarray(adc_pallas.adc_scan_shared_pallas(
-        jnp.asarray(lut).astype(jnp.bfloat16), codes, tile=128, interpret=True))
-    want = np_adc(lut, codes)
-    # m=4 sums of bf16-rounded values (~0.4% rel each)
-    np.testing.assert_allclose(got, want, rtol=2e-2, atol=2e-2)
-
-
-def test_bf16_lut_ivfpq_with_refine_recall(rng):
-    """End-to-end: adc_lut_bf16 + refine matches the f32 pipeline's recall
-    (the refine stage rescores the shortlist exactly either way)."""
+def small_pq(rng, kind="single", n=3000, **kw):
+    """A trained IVF-PQ index of ``kind`` whose lists the three-plane kernel
+    takes (n = 3000) or does not (n = 200: the starting capacity, 64)."""
     from distributed_faiss_tpu.models.ivf import IVFPQIndex
+    from distributed_faiss_tpu.parallel.mesh import ShardedIVFPQIndex
 
-    n, d = 3000, 32
+    d = 32
     x = rng.standard_normal((n, d)).astype(np.float32)
-    q = rng.standard_normal((16, d)).astype(np.float32)
-
-    def build(**kw):
-        idx = IVFPQIndex(d, 16, m=8, metric="l2", kmeans_iters=4, pq_iters=4,
-                         refine_k_factor=4, **kw)
-        idx.train(x[:2000])
-        idx.add(x)
-        idx.set_nprobe(8)
-        return idx
-
-    _, ids_f32 = build(use_pallas=True).search(q, 10)
-    _, ids_bf16 = build(use_pallas=True, adc_lut_bf16=True).search(q, 10)
-    overlap = np.mean([
-        len(set(ids_f32[i]) & set(ids_bf16[i])) / 10 for i in range(len(q))
-    ])
-    assert overlap >= 0.9, overlap
+    cls = {"single": IVFPQIndex, "sharded": ShardedIVFPQIndex}[kind]
+    idx = cls(d, 8, m=8, metric="l2", kmeans_iters=3, pq_iters=3,
+              refine_k_factor=4, **kw)
+    idx.train(rng.standard_normal((2000, d)).astype(np.float32))
+    idx.add(x)
+    idx.set_nprobe(4)
+    assert idx.lists.cap % 128 == 0 or n < 1000
+    return idx, x
 
 
-def test_tiny_list(rng):
-    lut = rng.standard_normal((2, 4, 256)).astype(np.float32)
-    codes = rng.integers(0, 256, (3, 4)).astype(np.uint8)
-    got = np.asarray(adc_pallas.adc_scan_shared_pallas(lut, codes, interpret=True))
-    np.testing.assert_allclose(got, np_adc(lut, codes), rtol=1e-5, atol=1e-5)
+def xla_twin(idx):
+    """The same trained index, forced onto the XLA one-hot."""
+    ref = type(idx).from_state_dict({**idx.state_dict(), "pallas_adc": False})
+    assert ref.use_pallas is False
+    return ref
 
 
-def test_nibble_kernel_golden(rng):
-    nq, m, ksub, L = 5, 8, 256, 300  # L not a tile multiple
-    lut = rng.standard_normal((nq, m, ksub)).astype(np.float32)
-    codes = rng.integers(0, 256, (nq, L, m)).astype(np.uint8)
-    got = np.asarray(adc_pallas.adc_scan_pallas_nibble(lut, codes, tile=128, interpret=True))
-    want = np.zeros((nq, L), np.float32)
-    for qi in range(nq):
-        for mi in range(m):
-            want[qi] += lut[qi, mi, codes[qi, :, mi].astype(np.int64)]
-    np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-5)
+def _spy_on_the_program(kind, monkeypatch):
+    """Record ``use_pallas`` of every launch of the index's scan program;
+    returns (the record, the jitted program)."""
+    from distributed_faiss_tpu.models import ivf as ivfmod
+    from distributed_faiss_tpu.parallel import mesh as meshmod
+
+    mod, name = {"single": (ivfmod, "_ivf_pq_search"),
+                 "sharded": (meshmod, "_sharded_ivf_pq_search")}[kind]
+    program, launched = getattr(mod, name), []
+
+    def spy(*args, **kw):
+        launched.append(kw["use_pallas"])
+        return program(*args, **kw)
+
+    monkeypatch.setattr(mod, name, spy)
+    return launched, program
 
 
-def test_nibble_matches_onehot_kernel(rng):
-    """Nibble decomposition must reproduce the one-hot kernel (same rounding
-    class: f32 accumulation of exact LUT values)."""
-    nq, m, ksub, L = 4, 64, 256, 520  # flagship m
-    lut = rng.standard_normal((nq, m, ksub)).astype(np.float32)
-    codes = rng.integers(0, 256, (nq, L, m)).astype(np.uint8)
-    a = np.asarray(adc_pallas.adc_scan_pallas_nibble(lut, codes, tile=256, interpret=True))
-    b = np.asarray(adc_pallas.adc_scan_pallas(lut, codes, tile=256, interpret=True))
-    np.testing.assert_allclose(a, b, rtol=1e-5, atol=1e-5)
+def _ping(idx):
+    """``ping()["kernels"]`` of a rank that serves ``idx`` as index "i"."""
+    import threading
+    from types import SimpleNamespace
+
+    from distributed_faiss_tpu.parallel.server import IndexServer
+    from distributed_faiss_tpu.utils.state import IndexState
+
+    rank = SimpleNamespace(
+        rank=0, indexes_lock=threading.Lock(),
+        indexes={"i": SimpleNamespace(tpu_index=idx,
+                                      get_state=lambda: IndexState.TRAINED)})
+    return IndexServer.ping(rank)["kernels"]
 
 
-def test_nibble_bf16_lut(rng):
-    nq, m, ksub, L = 3, 16, 256, 200
-    import jax.numpy as jnp
-
-    lut = rng.standard_normal((nq, m, ksub)).astype(np.float32)
-    codes = rng.integers(0, 256, (nq, L, m)).astype(np.uint8)
-    got = np.asarray(adc_pallas.adc_scan_pallas_nibble(
-        jnp.asarray(lut).astype(jnp.bfloat16), codes, tile=128, interpret=True))
-    want = np.asarray(pq.adc_scan(lut, codes))
-    # bf16 LUT rounding only (~0.4% rel)
-    np.testing.assert_allclose(got, want, rtol=2e-2, atol=2e-2)
+def _boom(*a, **k):
+    raise RuntimeError("kernel abort (injected)")
 
 
-def test_nibble_auto_dispatch(rng, monkeypatch):
-    """adc_scan_auto picks nibble when geometry allows, one-hot otherwise."""
-    calls = []
-    orig_nib = adc_pallas.adc_scan_pallas_nibble
-    orig_old = adc_pallas.adc_scan_pallas
+@pytest.mark.parametrize("outcome", ["kernel-returns", "kernel-raises",
+                                     "both-raise", "kernel-off-raises"])
+@pytest.mark.parametrize("kind", ["single", "sharded"])
+def test_the_guards_contract(rng, monkeypatch, kind, outcome):
+    """pallas_guarded(index, call): kernel -> XLA oracle -> demote, and
+    nothing else — no process-wide flag, no jit cache cleared."""
+    idx, x = small_pq(rng, kind, use_pallas=outcome != "kernel-off-raises")
+    idx._adc_validated = True  # the first-use check has tests of its own
+    q, bad = x[:6], np.zeros((2, x.shape[1] + 1), np.float32)
+    assert idx._kernel_applies() is (outcome != "kernel-off-raises")
+    want_d, want_i = xla_twin(idx).search(q, 5)
+    launched, program = _spy_on_the_program(kind, monkeypatch)
 
-    def spy_nib(*a, **k):
-        calls.append("nibble")
-        return orig_nib(*a, **k)
+    if outcome == "kernel-returns":
+        _, got_i = idx.search(q, 5)
+        assert launched == [True], "the oracle ran beside a healthy kernel"
+        np.testing.assert_array_equal(got_i, want_i)
+        assert idx._pallas_runtime_ok and _ping(idx) == {"pallas_degraded": []}
+    elif outcome == "kernel-raises":
+        program.clear_cache()  # so that the injected failure is traced
+        monkeypatch.setattr(adc_pallas, "adc_scan_pallas_planes", _boom)
+        got_d, got_i = idx.search(q, 5)
+        assert launched == [True, False]
+        np.testing.assert_array_equal(got_i, want_i)  # served from the oracle
+        np.testing.assert_array_equal(got_d, want_d)
+        assert idx._pallas_runtime_ok is False
+        assert _ping(idx) == {"pallas_degraded": ["i"]}
+        idx.search(q, 5)
+        assert launched == [True, False, False], "a demoted kernel was tried again"
+        assert idx.use_pallas is True, "the demotion reached the persisted intent"
+    elif outcome == "both-raise":
+        idx.search(q, 5)
+        cached = program._cache_size()
+        with pytest.raises(Exception):
+            idx.search(bad, 5)
+        assert launched == [True, True, False]
+        assert idx._pallas_runtime_ok, "a bad request demoted a healthy kernel"
+        assert _ping(idx) == {"pallas_degraded": []}
+        # no compiled program was dropped (a failed call leaves an entry of
+        # its own behind): the next good search traces nothing
+        assert program._cache_size() >= cached > 0
+        from distributed_faiss_tpu.models import ivf as ivfmod
 
-    def spy_old(*a, **k):
-        calls.append("onehot")
-        return orig_old(*a, **k)
-
-    monkeypatch.setattr(adc_pallas, "adc_scan_pallas_nibble", spy_nib)
-    monkeypatch.setattr(adc_pallas, "adc_scan_pallas", spy_old)
-    lut8 = rng.standard_normal((2, 8, 256)).astype(np.float32)
-    codes8 = rng.integers(0, 256, (2, 64, 8)).astype(np.uint8)
-    adc_pallas.adc_scan_auto(lut8, codes8)
-    lut4 = rng.standard_normal((2, 4, 256)).astype(np.float32)
-    codes4 = rng.integers(0, 256, (2, 64, 4)).astype(np.uint8)
-    adc_pallas.adc_scan_auto(lut4, codes4)  # m=4 -> one-hot fallback
-    assert calls == ["nibble", "onehot"]
-
-
-def test_auto_forwards_explicit_tile(rng, monkeypatch):
-    """An explicit tile reaches whichever kernel dispatches; tile=None lets
-    each kernel use its own tuned default (ADVICE r3)."""
-    seen = {}
-    orig_nib = adc_pallas.adc_scan_pallas_nibble
-
-    def spy_nib(lut, codes, **k):
-        seen.update(k)
-        return orig_nib(lut, codes, **k)
-
-    monkeypatch.setattr(adc_pallas, "adc_scan_pallas_nibble", spy_nib)
-    lut = rng.standard_normal((1, 8, 256)).astype(np.float32)
-    codes = rng.integers(0, 256, (1, 64, 8)).astype(np.uint8)
-    adc_pallas.adc_scan_auto(lut, codes)
-    assert "tile" not in seen
-    adc_pallas.adc_scan_auto(lut, codes, tile=256)
-    assert seen["tile"] == 256
+        monkeypatch.setattr(ivfmod, "_adc_pair_scores", _boom)
+        idx.search(q, 5)
+        assert launched[3:] == [True]
+    else:
+        with pytest.raises(Exception):
+            idx.search(bad, 5)
+        assert launched == [False], "a failing XLA call was retried"
+        assert idx._pallas_runtime_ok
 
 
 def test_pallas_degrade_ladder(rng, monkeypatch):
-    """A nibble-kernel failure falls back to the one-hot pallas kernel, not
-    straight to XLA; a one-hot failure then falls to XLA (ADVICE r3)."""
+    """The ladder on a dot-metric index, one request after another: a bad
+    request leaves the kernel alone, a kernel fault is served from the XLA
+    path and demotes the kernel for this index only."""
     from distributed_faiss_tpu.models import ivf as ivfmod
     from distributed_faiss_tpu.models.ivf import IVFPQIndex
 
     n, d, m = 1500, 32, 8
     x = rng.standard_normal((n, d)).astype(np.float32)
     q = rng.standard_normal((6, d)).astype(np.float32)
-    idx = IVFPQIndex(d, 8, m=m, metric="dot", kmeans_iters=3, pq_iters=3,
-                     use_pallas=True)
-    idx.train(x)
-    idx.add(x)
-    idx.set_nprobe(4)
-    ref = IVFPQIndex(d, 8, m=m, metric="dot", kmeans_iters=3, pq_iters=3,
-                     use_pallas=False)
-    ref.centroids, ref.codebooks = idx.centroids, idx.codebooks
-    ref.lists = idx.lists
-    ref._n = idx._n
-    ref.set_nprobe(4)
-    want_d, want_i = ref.search(q, 5)
 
-    def boom(*a, **k):
-        raise RuntimeError("kernel abort (injected)")
+    def build(use_pallas):
+        idx = IVFPQIndex(d, 8, m=m, metric="dot", kmeans_iters=3, pq_iters=3,
+                         use_pallas=use_pallas)
+        idx.train(x)
+        idx.add(x)
+        idx.set_nprobe(4)
+        return idx
 
-    # this ladder is the older dispatcher's (adc_scan_auto: nibble, then
-    # one-hot), which a forced index still runs wherever the three-plane
-    # kernel does not take its geometry — so refuse every geometry here
-    monkeypatch.setattr(adc_pallas, "planes_supported", lambda m, ksub, L: False)
+    idx, other = build(True), build(True)
+    assert idx._kernel_applies()
+    want_d, want_i = xla_twin(idx).search(q, 5)
+    other.search(q, 5)
+
     # drop compiled variants so the injected failure is actually reached
     ivfmod._ivf_pq_search.clear_cache()
-    monkeypatch.setattr(adc_pallas, "USE_NIBBLE", True)
-    monkeypatch.setattr(adc_pallas, "NIBBLE_SWEPT", False)
-    monkeypatch.setattr(adc_pallas, "NIBBLE_EXCUSES_LEFT", 8)
-    monkeypatch.setattr(ivfmod, "_BOTH_FAILED_SIGS", set())
-    monkeypatch.setattr(adc_pallas, "adc_scan_pallas_nibble", boom)
+    monkeypatch.setattr(adc_pallas, "adc_scan_pallas_planes", _boom)
 
-    # a user error (bad dim) re-raises from the XLA oracle with every
-    # kernel flag untouched — no demotion, no cache wipe
+    # a user error (bad dim) re-raises from the XLA oracle: no demotion
     with pytest.raises(Exception):
         idx.search(rng.standard_normal((2, d + 1)).astype(np.float32), 5)
-    assert adc_pallas.USE_NIBBLE is True
     assert idx._pallas_runtime_ok
 
     got_d, got_i = idx.search(q, 5)
-    assert adc_pallas.USE_NIBBLE is False, "nibble not demoted"
-    assert idx._pallas_runtime_ok, "one-hot pallas abandoned with the nibble"
-    np.testing.assert_array_equal(got_i, want_i)
-    np.testing.assert_allclose(got_d, want_d, rtol=1e-4, atol=1e-4)
-
-    # now the one-hot kernel breaks too. The first failure is excused as a
-    # possible stale pre-demotion executable (ADVICE r4: caches swept, the
-    # request served from the XLA result in hand, NO synchronous re-trace);
-    # the second failure — necessarily a fresh trace — demotes pallas.
-    ivfmod._ivf_pq_search.clear_cache()
-    monkeypatch.setattr(adc_pallas, "adc_scan_pallas", boom)
-    got_d, got_i = idx.search(q, 5)
-    assert idx._pallas_runtime_ok, "demoted on the excusable first failure"
-    assert adc_pallas.NIBBLE_SWEPT is True
-    np.testing.assert_array_equal(got_i, want_i)
-    np.testing.assert_allclose(got_d, want_d, rtol=1e-4, atol=1e-4)
-    got_d, got_i = idx.search(q, 5)
     assert not idx._pallas_runtime_ok
+    assert other._pallas_runtime_ok, "one index's fault demoted another's kernel"
     np.testing.assert_array_equal(got_i, want_i)
     np.testing.assert_allclose(got_d, want_d, rtol=1e-4, atol=1e-4)
+    got_d, got_i = idx.search(q, 5)  # the XLA path from here on
+    np.testing.assert_array_equal(got_i, want_i)
+    ivfmod._ivf_pq_search.clear_cache()  # the injected kernel is in the traces
 
 
-def test_nibble_consumer_registry_complete():
-    """Every jitted program that bakes the adc_scan_auto dispatch in at
-    trace time must be registered, or disable_nibble leaves a stale
-    nibble executable behind and the ladder misattributes the next fault."""
-    from distributed_faiss_tpu.models import ivf as ivfmod
-    from distributed_faiss_tpu.parallel import mesh as meshmod
-
-    registered = {id(f) for f in adc_pallas.NIBBLE_JIT_CONSUMERS}
-    expected = [
-        ivfmod._ivf_pq_search, ivfmod._ivf_pq_search_fused,
-        meshmod._sharded_ivf_pq_search, meshmod._sharded_ivf_pq_search_fused,
-        meshmod._sharded_ivf_pq_search_routed,
-    ]
-    assert all(id(f) in registered for f in expected)
-    assert len(adc_pallas.NIBBLE_JIT_CONSUMERS) == len(expected)
-
-    # tripwire against silent drift: a NEW adc_scan_auto call site means a
-    # new (possibly unregistered) consumer — this count forces whoever adds
-    # one to register its enclosing jitted program(s) and update both lists
-    import inspect
-
-    sites = sum(inspect.getsource(mod).count("adc_scan_auto(")
-                for mod in (ivfmod, meshmod))
-    assert sites == 3, (
-        "adc_scan_auto call-site count changed: register the new consumer "
-        "in NIBBLE_JIT_CONSUMERS and update this test")
-
-
-def test_both_failed_repeat_demotes_nibble(monkeypatch):
-    """When kernel AND oracle fail with messages that normalize equal (e.g.
-    OOMs differing only in byte counts), the first request is read as 'bad
-    request' (no demotion, no cache wipe), but a repeat of the SAME failure
-    signature demotes the nibble kernel — never-demoting would re-fault
-    every search forever. Distinct bad requests never accumulate."""
-    from distributed_faiss_tpu.models import ivf as ivfmod
-
-    class FakeIdx:
-        use_pallas = True
-        _pallas_runtime_ok = True
-
-    def oom_call(use_pallas):
-        if use_pallas:
-            raise RuntimeError("RESOURCE_EXHAUSTED allocating 8589934592 bytes")
-        raise RuntimeError("RESOURCE_EXHAUSTED allocating 17179869184 bytes")
-
-    def other_bad_call(use_pallas):
-        raise RuntimeError("dim mismatch: got 33, want 32")
-
-    monkeypatch.setattr(adc_pallas, "USE_NIBBLE", True)
-    monkeypatch.setattr(ivfmod, "_BOTH_FAILED_SIGS", set())
-    assert adc_pallas.nibble_supported(8, 256)
-
-    with pytest.raises(RuntimeError):
-        ivfmod.pallas_guarded(FakeIdx(), oom_call, 8, 256)
-    assert adc_pallas.USE_NIBBLE is True, "one bad request must not demote"
-
-    # a DIFFERENT bad request in between must not count toward the repeat
-    with pytest.raises(RuntimeError):
-        ivfmod.pallas_guarded(FakeIdx(), other_bad_call, 8, 256)
-    assert adc_pallas.USE_NIBBLE is True, "distinct signatures accumulated"
-
-    # the OOM signature repeating demotes — the interleaved unrelated bad
-    # request must NOT have displaced it (signature set, not single slot)
-    with pytest.raises(RuntimeError):
-        ivfmod.pallas_guarded(FakeIdx(), oom_call, 8, 256)
-    assert adc_pallas.USE_NIBBLE is False, "repeated signature must demote"
-
-    # genuinely distinct failures demote immediately (reset state first)
-    monkeypatch.setattr(adc_pallas, "USE_NIBBLE", True)
-    monkeypatch.setattr(ivfmod, "_BOTH_FAILED_SIGS", set())
-
-    def distinct_call(use_pallas):
-        if use_pallas:
-            raise RuntimeError("kernel abort")
-        raise ValueError("one-hot materialization OOM")
-
-    with pytest.raises(ValueError):
-        ivfmod.pallas_guarded(FakeIdx(), distinct_call, 8, 256)
-    assert adc_pallas.USE_NIBBLE is False
-
-
-def test_stale_executable_excuse_covers_concurrent_inflight(monkeypatch):
-    """Two in-flight searches whose traces predate a concurrent nibble
-    demotion must BOTH be excused (served via XLA, pallas kept) — the sweep
-    epoch moves under the first excuse, covering the second (r5 review)."""
-    from distributed_faiss_tpu.models import ivf as ivfmod
-
-    class FakeIdx:
-        use_pallas = True
-        _pallas_runtime_ok = True
-
-    monkeypatch.setattr(adc_pallas, "USE_NIBBLE", False)  # demotion landed
-    monkeypatch.setattr(adc_pallas, "NIBBLE_SWEPT", True)  # excuse spent
-    monkeypatch.setattr(adc_pallas, "NIBBLE_EXCUSES_LEFT", 2)
-    epoch0 = adc_pallas.NIBBLE_SWEEP_EPOCH
-    monkeypatch.setattr(adc_pallas, "NIBBLE_SWEEP_EPOCH", epoch0)
-
-    # pallas_guarded captures the epoch at entry; emulate "this call's trace
-    # started before the concurrent demotion's sweep" by rewinding the epoch
-    # before each entry and bumping it from inside the failing pallas call
-    # (the moment the demotion sweep would land)
-    def stale_exec(use_pallas):
-        if use_pallas:
-            adc_pallas.NIBBLE_SWEEP_EPOCH = epoch0 + 1
-            raise RuntimeError("stale nibble executable abort")
-        return "xla-result"
-
-    idx_a, idx_b = FakeIdx(), FakeIdx()
-    adc_pallas.NIBBLE_SWEEP_EPOCH = epoch0
-    assert ivfmod.pallas_guarded(idx_a, stale_exec, 8, 256) == "xla-result"
-    assert idx_a._pallas_runtime_ok, "in-flight stale executable demoted pallas"
-    adc_pallas.NIBBLE_SWEEP_EPOCH = epoch0
-    assert ivfmod.pallas_guarded(idx_b, stale_exec, 8, 256) == "xla-result"
-    assert idx_b._pallas_runtime_ok, "second in-flight victim demoted pallas"
-
-    # budget exhausted: a further "stale-looking" failure is no longer
-    # excused — a genuinely broken one-hot kernel under constant concurrency
-    # must converge to the XLA path, not excuse itself forever (r5 review)
-    assert adc_pallas.NIBBLE_EXCUSES_LEFT == 0
-    idx_c = FakeIdx()
-    adc_pallas.NIBBLE_SWEEP_EPOCH = epoch0
-    assert ivfmod.pallas_guarded(idx_c, stale_exec, 8, 256) == "xla-result"
-    assert idx_c._pallas_runtime_ok is False, "budget spent yet still excused"
+@pytest.mark.parametrize("kind", ["single", "sharded"])
+def test_a_forced_kernel_at_a_geometry_it_does_not_take_serves_xla(
+        rng, monkeypatch, kind):
+    """``use_pallas=True`` on lists of capacity 64: the XLA arm serves, no
+    fused scan is booked, and nothing is degraded — no kernel ever ran."""
+    idx, x = small_pq(rng, kind, n=200, use_pallas=True)
+    assert idx.lists.cap == 64 and not idx._kernel_applies()
+    monkeypatch.setattr(adc_pallas, "adc_scan_pallas_planes", _boom)
+    want_d, want_i = xla_twin(idx).search(x[:6], 5)
+    launched, _ = _spy_on_the_program(kind, monkeypatch)
+    sink = tracing.LatencyStats()
+    with tracing.stage("engine.launch", sink=sink):
+        got_d, got_i = idx.search(x[:6], 5)
+    assert launched == [False]
+    assert "engine.scan_fused" not in sink.summary()
+    np.testing.assert_array_equal(got_i, want_i)
+    np.testing.assert_array_equal(got_d, want_d)
+    assert idx._pallas_runtime_ok and _ping(idx) == {"pallas_degraded": []}
 
 
 # ------------------------------------------------- three-plane kernel (PR 25)
@@ -383,12 +209,27 @@ def wide_tables(rng, shape):
             * 10.0 ** rng.uniform(-3, 3, shape)).astype(np.float32)
 
 
-@pytest.mark.parametrize("m", [8, 64])
-@pytest.mark.parametrize("cap", [128, 1024])
-def test_planes_kernel_golden(rng, m, cap):
-    lut = wide_tables(rng, (3, m, 256))
-    codes = rng.integers(0, 256, (3, cap, m)).astype(np.uint8)
+def steep_tables(rng, shape):
+    """As an l2 table is: every entry negative, magnitudes up to 1e6."""
+    return (-(10.0 ** rng.uniform(0, 6, shape))).astype(np.float32)
+
+
+# (m, list length, table): whole tiles at the cells' m and the smallest; a
+# list shorter than a tile and a ragged one (the kernel pads both, whatever
+# planes_supported says of serving them); m off the cells'; an l2-like table
+@pytest.mark.parametrize("m,L,tables", [
+    (8, 128, wide_tables), (64, 128, wide_tables),
+    (8, 1024, wide_tables), (64, 1024, wide_tables),
+    (8, 8, wide_tables), (8, 200, wide_tables),
+    (4, 256, wide_tables), (16, 256, wide_tables),
+    (8, 256, steep_tables),
+], ids=["m8-L128", "m64-L128", "m8-L1024", "m64-L1024", "short-L8",
+        "ragged-L200", "m4", "m16", "negative-1e6"])
+def test_planes_kernel_golden(rng, m, L, tables):
+    lut = tables(rng, (3, m, 256))
+    codes = rng.integers(0, 256, (3, L, m)).astype(np.uint8)
     got = np.asarray(adc_pallas.adc_scan_pallas_planes(lut, codes, interpret=True))
+    assert got.shape == (3, L)
     np.testing.assert_allclose(got, np_adc_f64(lut, codes), rtol=1e-4, atol=1e-4)
 
 
@@ -416,6 +257,88 @@ def test_planes_kernel_is_exact_where_f32_sums_are(rng):
     np.testing.assert_array_equal(got, np_adc_f64(lut, codes).astype(np.float32))
 
 
+def _first_m_over_budget():
+    m = 1
+    while adc_pallas._planes_vmem_bytes(m, 256, 128) <= adc_pallas._ONEHOT_VMEM_BUDGET:
+        m += 1
+    return m
+
+
+def _tile_chosen(m, L):
+    """The candidate tile adc_scan_pallas_planes picks, read off its grid."""
+    import jax
+
+    S = jax.ShapeDtypeStruct
+    jaxpr = jax.make_jaxpr(lambda a, b: adc_pallas.adc_scan_pallas_planes(
+        a, b, interpret=True))(S((2, m, 256), np.float32), S((2, L, m), np.uint8))
+    (call,) = [e for e in _walk_eqns(jaxpr.jaxpr) if e.primitive.name == "pallas_call"]
+    pairs, tiles = call.params["grid_mapping"].grid
+    assert pairs == 2 and L % tiles == 0
+    return L // tiles
+
+
+# planes_supported's truth table: what it admits the kernel has a tile for
+# inside its VMEM budget (the max() over tiles is never over an empty set
+# and 128 is never taken over the budget); at its boundaries it refuses
+@pytest.mark.parametrize("m,ksub,L,admits", [
+    (None, 256, None, True),                  # a sweep of what it admits
+    (64, 256, 127, False), (64, 256, 129, False), (64, 256, 1000, False),
+    (64, 16, 1024, False),                    # 4-bit codes
+    ("first-over-budget", 256, 1024, False),
+], ids=["admitted", "L127", "L129", "L1000", "ksub16", "m-over-budget"])
+def test_what_planes_supported_admits_the_kernel_has_a_tile_for(m, ksub, L, admits):
+    budget = adc_pallas._ONEHOT_VMEM_BUDGET
+    if m == "first-over-budget":
+        m = _first_m_over_budget()
+        assert adc_pallas.planes_supported(m - 1, ksub, L), "not the boundary"
+    if not admits:
+        assert not adc_pallas.planes_supported(m, ksub, L)
+        return
+    top = _first_m_over_budget() - 1
+    for m, L in [(1, 128), (1, 4096), (8, 640), (64, 128), (64, 1024),
+                 (64, 4096), (64, 8192 + 128), (top, 128), (top, 4096)]:
+        assert adc_pallas.planes_supported(m, 256, L), (m, L)
+        tile = _tile_chosen(m, L)
+        assert tile % 128 == 0 and tile <= adc_pallas._PLANES_TILE
+        assert adc_pallas._planes_vmem_bytes(m, 256, tile) <= budget, (m, L, tile)
+
+
+def _onehots_outside_the_kernel(eqns):
+    """The XLA arm's mark: a compare whose result is (.., rows, m, ksub);
+    the kernel's own compares are (ksub, tile)."""
+    return [e for e in eqns if e.primitive.name == "eq"
+            and e.outvars[0].aval.ndim >= 4 and e.outvars[0].aval.shape[-1] == 256]
+
+
+# the knnlm cells' program (d 768, m 64, capacity 1024, k 10 x 8, nprobe 32)
+# at a 64-row and a 256-row block; top_k counts are the parent's (bba2e24)
+@pytest.mark.parametrize("rows,top_ks", [(64, 4), (256, 3)])
+def test_the_served_program_holds_one_kernel_and_no_onehot(rows, top_ks):
+    import jax
+
+    from distributed_faiss_tpu.models import ivf as ivfmod
+
+    d, m, cap, k, nprobe, nlist = 768, 64, 1024, 80, 32, 4096
+    g = ivfmod.probe_group_size(
+        nprobe, ivfmod.pq_probe_payload_bytes(cap, m, nq_block=rows))
+    S = jax.ShapeDtypeStruct
+    args = (S((nlist, d), np.float32), S((m, 256, d // m), np.float32),
+            S((nlist, cap, m), np.uint8), S((nlist, cap), np.int32),
+            S((nlist,), np.int32), S((rows, d), np.float32))
+
+    def eqns(use_pallas):
+        return list(_walk_eqns(jax.make_jaxpr(lambda *a: ivfmod._ivf_pq_search(
+            *a, k=k, nprobe=nprobe, g=g, metric="l2",
+            use_pallas=use_pallas))(*args).jaxpr))
+
+    fused, xla = eqns(True), eqns(False)
+    count = lambda es, name: sum(e.primitive.name == name for e in es)
+    assert count(fused, "pallas_call") == 1 and count(xla, "pallas_call") == 0
+    assert _onehots_outside_the_kernel(xla), "the detector is stale"
+    assert not _onehots_outside_the_kernel(fused)
+    assert count(fused, "top_k") == top_ks == count(xla, "top_k")
+
+
 class _Lists:
     def __init__(self, cap):
         self.cap = cap
@@ -430,40 +353,18 @@ class _Lists:
     (True, 8, 64, None, False),      # the smallest capacity lists start at
     (True, 512, 1024, None, False),  # a table the VMEM model refuses
     (False, 8, 1024, True, True),    # an explicit True forces (tests, A/B)
+    (True, 8, 96, True, False),      # but only where the kernel takes the lists
     (True, 64, 1024, False, False),  # an explicit False forces
-], ids=["cpu", "tpu", "tpu-m64", "cap96", "cap64", "m512", "force-on", "force-off"])
+], ids=["cpu", "tpu", "tpu-m64", "cap96", "cap64", "m512", "force-on",
+        "force-on-cap96", "force-off"])
 def test_the_index_chooses_its_adc_kernel(monkeypatch, tpu, m, cap, forced, fused):
     from distributed_faiss_tpu.models import ivf as ivfmod
 
     monkeypatch.setattr(adc_pallas, "on_tpu", lambda: tpu)
     idx = ivfmod.IVFPQIndex(2 * m, 4, m=m, use_pallas=forced)
+    assert idx._kernel_applies() is False  # no lists yet
     idx.lists = _Lists(cap)
-    assert ivfmod.pallas_wanted(idx) is fused
-    if forced is None:
-        assert idx._fused_adc_applies() is fused
-
-
-def small_pq(rng, **kw):
-    from distributed_faiss_tpu.models.ivf import IVFPQIndex
-
-    n, d = 3000, 32
-    x = rng.standard_normal((n, d)).astype(np.float32)
-    idx = IVFPQIndex(d, 8, m=8, metric="l2", kmeans_iters=3, pq_iters=3,
-                     refine_k_factor=4, **kw)
-    idx.train(x[:2000])
-    idx.add(x)
-    idx.set_nprobe(4)
-    assert idx.lists.cap % 128 == 0
-    return idx, x
-
-
-def xla_twin(idx):
-    """The same trained index, forced onto the XLA one-hot."""
-    from distributed_faiss_tpu.models.ivf import IVFPQIndex
-
-    ref = IVFPQIndex.from_state_dict({**idx.state_dict(), "pallas_adc": False})
-    assert ref.use_pallas is False
-    return ref
+    assert idx._kernel_applies() is fused
 
 
 def test_a_chosen_index_runs_the_planes_kernel_and_matches_xla(rng, monkeypatch):
@@ -492,22 +393,38 @@ def test_a_chosen_index_runs_the_planes_kernel_and_matches_xla(rng, monkeypatch)
     np.testing.assert_allclose(got_d, want_d, rtol=1e-4, atol=1e-4)
 
 
+@pytest.fixture(scope="module")
+def trained_state():
+    idx, x = small_pq(np.random.default_rng(7))
+    state = idx.state_dict()
+    assert sorted(k for k in state if "pallas" in k or "lut" in k) == ["pallas_adc"]
+    del state["pallas_adc"]
+    return state, x[:8], xla_twin(idx).search(x[:8], 5)
+
+
 @pytest.mark.parametrize("saved,loads_as", [
     ({"use_pallas": False}, None),                       # the old default: choose
     ({"use_pallas": True}, True),                        # the old pallas_adc=True
-    ({"use_pallas": False, "pallas_adc": None}, None),   # today's default
-    ({"use_pallas": False, "pallas_adc": False}, False), # today's forced off
-    ({"use_pallas": True, "pallas_adc": True}, True),
-], ids=["old-default", "old-forced", "choose", "off", "on"])
-def test_a_snapshots_kernel_intent(saved, loads_as):
+    # PR 25 to 29 wrote both keys, and the rounded-table mode beside them: it
+    # loads at exact table values
+    ({"use_pallas": True, "pallas_adc": True, "adc_lut_bf16": True}, True),
+    ({"use_pallas": False, "pallas_adc": None}, None),
+    ({"pallas_adc": None}, None),                        # today's default
+    ({"pallas_adc": False}, False),                      # today's forced off
+    ({"pallas_adc": True}, True),
+], ids=["old-default", "old-forced", "old-bf16-table", "pr25-choose", "choose",
+        "off", "on"])
+def test_a_snapshots_kernel_intent(trained_state, saved, loads_as):
     from distributed_faiss_tpu.models.ivf import IVFPQIndex
 
-    state = IVFPQIndex(16, 4, m=4).state_dict()
-    del state["use_pallas"], state["pallas_adc"]
+    state, q, (want_d, want_i) = trained_state
     idx = IVFPQIndex.from_state_dict({**state, **saved})
     assert idx.use_pallas is loads_as
     again = IVFPQIndex.from_state_dict(idx.state_dict())
     assert again.use_pallas is loads_as
+    got_d, got_i = idx.search(q, 5)
+    np.testing.assert_array_equal(got_i, want_i)
+    np.testing.assert_allclose(got_d, want_d, rtol=1e-5, atol=1e-5)
 
 
 def test_the_factory_leaves_the_choice_to_the_index():
@@ -522,12 +439,11 @@ def test_the_factory_leaves_the_choice_to_the_index():
     assert build().use_pallas is None
     assert build(pallas_adc=True).use_pallas is True
     assert build(pallas_adc=False).use_pallas is False
-    assert build(adc_lut_bf16=False).adc_lut_bf16 is False
 
 
 def test_first_fused_scan_with_wrong_scores_demotes_and_serves_xla(rng, monkeypatch):
     """A kernel that runs and returns wrong numbers (one bf16 pass where
-    three were meant: PR 21's nibble finding) raises nothing for
+    three were meant: PR 21's finding) raises nothing for
     pallas_guarded to catch: the first-use check does, before a caller
     sees a score."""
     from distributed_faiss_tpu.models import ivf as ivfmod

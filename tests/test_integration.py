@@ -341,9 +341,8 @@ def test_ping_health(cluster, rng, request):
     assert sorted(h["rank"] for h in health) == [0, 1, 2, 3]
     # every server must report the index (add only hit one, create hit all)
     assert all(h["indexes"].get(index_id) == "TRAINED" for h in health)
-    # ADC kernel observability: no demotions on a healthy interpreter run
-    assert all(h["kernels"]["pallas_degraded"] == [] for h in health)
-    assert all(isinstance(h["kernels"]["use_nibble"], bool) for h in health)
+    # kernel observability: no demotions on a healthy interpreter run
+    assert all(h["kernels"] == {"pallas_degraded": []} for h in health)
     client.close()
 
 

@@ -49,9 +49,9 @@ def compiled_for_v5e():
 
 def test_kernels_compile_for_v5e(compiled_for_v5e):
     """Mosaic + XLA:TPU compile of every case from libtpu's compile-only
-    v5e topology. At the parent commit: IEEE-half vector loads, 2 x 512 KB
-    of scalar prefetch against 1 MB of SMEM, and 35.6 MB of scoped VMEM in
-    the nibble kernel."""
+    v5e topology. What it refused at the chip bring-up: IEEE-half vector
+    loads, 2 x 512 KB of scalar prefetch against 1 MB of SMEM, and 35.6 MB
+    of scoped VMEM in an ADC kernel."""
     rows = [r for r in compiled_for_v5e if "case" in r]
     assert len(rows) == len(pallas_tpu_cases.cases())
     refused = [r for r in rows if not r["ok"]]

@@ -113,7 +113,7 @@ def test_scan_bf16_requires_refine():
 
 
 def test_scan_bf16_with_refine_recall(rng):
-    """bf16 scan + exact refine (the lut_bf16 precedent): final results
+    """bf16 scan + exact refine: final results
     match the fp32 pipeline on virtually every query — the shortlist is
     rescored exactly, so only genuine shortlist churn can differ."""
     idx, x = build(rng, "f16", "l2", refine_k_factor=4, scan_bf16=True)

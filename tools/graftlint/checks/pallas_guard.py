@@ -1,9 +1,9 @@
 """pallas-guard: every route into ``pl.pallas_call`` passes pallas_guarded.
 
 The runtime contract (models/ivf.py:pallas_guarded): a Pallas kernel fault
-must be attributed (bad kernel vs bad request), demoted one rung at a time
-(nibble -> one-hot -> XLA), and never crash a serving request that the XLA
-oracle could have answered. That only holds if NO public code path reaches
+must be attributed (bad kernel vs bad request: the XLA path runs as the
+oracle), demote that index's kernel (kernel -> XLA oracle -> demote), and
+never crash a serving request that the XLA oracle could have answered. That only holds if NO public code path reaches
 a kernel without the guard.
 
 Static approximation (unit = every def/lambda, nested separately):
