@@ -1777,13 +1777,17 @@ class Index:
         the ``engine.scan`` blocks of an exact scan whose per-chunk top-k
         chose its segments by their maxima before sorting
         (``ops/distance.topk_prefilters``; models/flat.py books it);
+        ``engine.scan_listmajor`` (a count row, shown the same way) counts
+        the ``engine.scan`` blocks of an IVF-flat index whose probe scan
+        took the list-major order (``models/ivf.listmajor_tiling``;
+        ``IVFFlatIndex`` books it);
         ``engine.store_grow`` is one record a reallocation of a
         ``DeviceVectorStore`` (models/base.py), allocation to the end of
         the copy."""
         out = self.perf.summary(raw=raw)
         if "engine.scan" in out:
             for name in ("engine.scan_fused", "engine.scan_rows",
-                         "engine.scan_prefilter"):
+                         "engine.scan_prefilter", "engine.scan_listmajor"):
                 out.setdefault(name, tracing.zero_row())
         return out
 

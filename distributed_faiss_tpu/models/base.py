@@ -462,7 +462,7 @@ def query_blocks(q: np.ndarray, block: int = 256):
 
 
 def blocked_search(q: np.ndarray, k: int, metric: str, fn, block: int = 256,
-                   fused_fn=None, refine_fn=None):
+                   fused_fn=None, refine_fn=None, with_counts: bool = False):
     """THE blocked search driver (shared by the IVF family and the mesh
     indexes — one implementation so the bucketing/padding policy cannot
     drift between them).
@@ -500,6 +500,11 @@ def blocked_search(q: np.ndarray, k: int, metric: str, fn, block: int = 256,
     dispatch where the index refines outside the scan program, then the
     result fetch and ``finalize_results``). The boundaries sit where the
     host already waits: no sync is added for them.
+
+    ``with_counts``: the scan callables also get the real rows of what
+    they are handed, as a device int32 — ``fn(chunk, n)`` a scalar,
+    ``fused_fn(q3, counts)`` one count a block — so a program that can skip
+    the zero padding (the list-major IVF scan) knows where it starts.
     """
     q = np.asarray(q, np.float32)
     nq = q.shape[0]
@@ -514,8 +519,10 @@ def blocked_search(q: np.ndarray, k: int, metric: str, fn, block: int = 256,
             nblocks = _next_pow2(-(-nq // block), 1)
             qp = np.pad(q, ((0, nblocks * block - nq), (0, 0)))
             q3 = jax.device_put(qp.reshape(nblocks, block, -1))
+            rows = np.clip(nq - block * np.arange(nblocks), 0, block)
+            counts = (jax.device_put(rows.astype(np.int32)),) if with_counts else ()
         with tracing.stage("engine.scan"):
-            vals, ids = fused_fn(q3)
+            vals, ids = fused_fn(q3, *counts)
         with tracing.stage("engine.refine_fetch"):
             with xfercheck.explicit("blocked_search fused result fetch"):
                 out_s = np.asarray(vals).reshape(nblocks * block, -1)[:nq]
@@ -527,8 +534,9 @@ def blocked_search(q: np.ndarray, k: int, metric: str, fn, block: int = 256,
         with tracing.stage("engine.feed"):
             n, chunk = _padded_block(q, s, block)
             chunk = jax.device_put(chunk)
+            counts = (jax.device_put(np.int32(n)),) if with_counts else ()
         with tracing.stage("engine.scan"):
-            vals, ids = fn(chunk)
+            vals, ids = fn(chunk, *counts)
         with tracing.stage("engine.refine_fetch"):
             if refine_fn is not None:
                 vals, ids = refine_fn(chunk, ids)

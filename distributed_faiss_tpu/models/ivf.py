@@ -5,15 +5,19 @@ Replaces FAISS ``IndexIVFFlat`` / ``IndexIVFScalarQuantizer`` /
 distributed_faiss/index.py:36-68).
 
 TPU-first search path (one jitted program per variant):
-  coarse einsum (nq, nlist) -> top-nprobe -> lax.scan over probes, each step
-  gathering one (nq, cap, ...) list block from HBM, scoring it on the MXU
-  (raw/fp16/sq8 dequant fused into the einsum; PQ via ADC LUT), masking the
-  padded tail, and merging into a running top-k carry. The flat/sq8 l2 scan
+  coarse einsum (nq, nlist) -> top-nprobe -> the probe scan. PQ: lax.scan
+  over probes, each step gathering one (nq, g, cap, m) code block from HBM,
+  scoring it by ADC LUT, masking the padded tail and merging into a running
+  top-k carry. Flat/fp16/sq8: list-major (_listmajor_scan) — the (query,
+  probe) pairs are sorted by list on the device and cut into tiles, each
+  probed list is gathered once per tile of queries that probe it and
+  multiplied against all of them on the MXU (dequant fused into the
+  einsum), a top-k per pair, then one per query. The flat/sq8 l2 scan
   gathers STORED fp32 row norms (a (nlist, cap) sidecar filled at
   add/encode time, bit-identical to an in-scan recompute) instead of
   running a second elementwise pass over the block; with use_pallas the
-  whole gather+decode+dot+mask step runs in a fused VMEM kernel
-  (ops/flat_pallas.py) and the fp32 gathered block never exists in HBM.
+  whole gather+decode+dot+mask step runs query-major in a fused VMEM
+  kernel (ops/flat_pallas.py) and no gathered block exists in HBM.
 
 Coarse assignment follows the reference's quantizer choice (get_quantizer,
 index.py:25-33): argmax inner product for metric=dot, argmin L2 otherwise.
@@ -87,16 +91,22 @@ def _rerank_exact(store, q, cand_ids, k: int, metric: str):
         return best, jnp.take_along_axis(cand_ids, pos, axis=1)
 
 
-def _mask_block(s, ids, sizes):
-    cap = s.shape[1]
-    valid = jnp.arange(cap)[None, :] < sizes[:, None]
-    return jnp.where(valid & (ids >= 0), s, distance.NEG_INF)
-
-
 # paired bound: base._QUERY_PAYLOAD_BUDGET = 2x this, so even when one
 # probe's block-payload exceeds this budget (g floors at 1) the gather
 # transient stays within 2x, not unbounded
 _GROUP_BYTE_BUDGET = 128 * 1024 * 1024
+# list-major scan (listmajor_tiling). A step's gathered block stays under
+# _LIST_BLOCK_BYTES in the storage dtype: at 64 MiB XLA keeps it in the
+# v5e's 128 MiB of VMEM, past it a step costs a third more. The score
+# buffer of a block stays under _SCORE_BYTES. The gather takes its rows in
+# slices of at most _GATHER_SLICE_BYTES: a slice of a whole (cap, d) list is
+# past what XLA:TPU gathers in place, and it then copies the WHOLE store
+# into slabs in every loop step (PERF.md section 6, PR 31).
+_MAX_TILE = 64
+_MAX_GROUP = 64
+_LIST_BLOCK_BYTES = 64 * 1024 * 1024
+_SCORE_BYTES = 1024 * 1024 * 1024
+_GATHER_SLICE_BYTES = 256 * 1024
 
 
 def probe_group_size(nprobe: int, per_probe_bytes: int) -> int:
@@ -132,21 +142,212 @@ def _merge_group(carry, s, ids, k):
         return distance.merge_topk(best_v, best_i, cv, cids, k)
 
 
+def listmajor_tiling(rows: int, nprobe: int, nlist: int, cap: int, dim: int,
+                     itemsize: int):
+    """(T, G) of the list-major probe scan for a block of ``rows`` query
+    rows over lists of ``cap`` rows of ``dim`` x ``itemsize`` bytes, from
+    static shapes alone: T query slots a tile, G tiles a loop step. The ONE
+    rule — ``IVFFlatIndex._scan_tiling`` asks it before the trace.
+
+    T follows the expected reuse of a probed list, ``rows * nprobe /
+    nlist`` pairs. Up to 1 a tile is a pair (T = 1): the scan is then the
+    pair-major one, a matrix-vector product a pair, bound by the read of
+    the list. Past it a tile is a power of two from 8 (one sublane tile of
+    queries; fewer cost the same) up to 4 x the reuse: a tile costs the
+    gather of its list and a product whose 128 MXU columns are paid for
+    whether 8 or 64 are filled, so it is wide enough that a list's pairs
+    nearly always fit one tile — as long as the block's score buffer,
+    ``(pairs + nlist * T) * cap * 4`` bytes, stays inside ``_SCORE_BYTES``.
+    G keeps a step's gathered block inside ``_LIST_BLOCK_BYTES``. Every
+    number is a v5e's at d 512, float16, capacities 512 to 4096: PERF.md
+    section 6, PR 31."""
+    pairs = rows * nprobe
+    tile = 1
+    if pairs > nlist:
+        tile = 8
+        while tile < min(_MAX_TILE, 4 * pairs / nlist):
+            tile *= 2
+        while tile > 8 and (pairs + nlist * tile) * cap * 4 > _SCORE_BYTES:
+            tile //= 2
+    group = 1
+    while (group < _MAX_GROUP
+           and 2 * group * cap * dim * itemsize <= _LIST_BLOCK_BYTES):
+        group *= 2
+    return tile, group
+
+
+def listmajor_tile_bound(npairs: int, nlist: int, tile: int) -> int:
+    """Tiles that ``npairs`` (query, probe) pairs can make, whatever the
+    probes: every full tile holds ``tile`` pairs and each probed list adds
+    at most one partial tile, and no tile is empty."""
+    return min(npairs, -(-npairs // tile) + min(nlist, npairs))
+
+
+def _listmajor_plan(probes, nlist: int, tile: int, ntiles: int, nvalid):
+    """Invert ``probes`` (nq, nprobe) into tiles, on the device: sort the
+    pairs by list id, cut every list's run into tiles of ``tile`` query
+    slots. Pairs of rows at or past ``nvalid`` (a block's zero padding)
+    sort behind every list and make no tile.
+
+    Returns ``tile_list`` (ntiles,) the list a tile scans, ``tile_q``
+    (ntiles, tile) the query row of each slot (an arbitrary live row in a
+    slot past the list's run: its scores are never read), ``where`` (nq,
+    nprobe) the ``tile * T + slot`` each pair's result lands at,
+    ``pair_live`` (nq, nprobe) and the traced number of tiles in use."""
+    nq, nprobe = probes.shape
+    npairs = nq * nprobe
+    pair = jnp.arange(npairs, dtype=jnp.int32)
+    key = probes.reshape(npairs).astype(jnp.int32)
+    if nvalid is not None:
+        key = jnp.where(pair // nprobe < nvalid, key, nlist)
+    order = jnp.argsort(key).astype(jnp.int32)
+    skey = key[order]
+    start = jnp.searchsorted(
+        skey, jnp.arange(nlist + 1, dtype=jnp.int32)).astype(jnp.int32)
+    ntile = -(-(start[1:] - start[:-1]) // tile)  # tiles of each list
+    tend = jnp.cumsum(ntile)
+    tfirst = tend - ntile
+    t = jnp.arange(ntiles, dtype=jnp.int32)
+    tile_list = jnp.minimum(
+        jnp.searchsorted(tend, t, side="right"), nlist - 1).astype(jnp.int32)
+    pos = ((start[tile_list] + (t - tfirst[tile_list]) * tile)[:, None]
+           + jnp.arange(tile, dtype=jnp.int32)[None, :])
+    tile_q = order[jnp.minimum(pos, npairs - 1)] // nprobe
+    slist = jnp.minimum(skey, nlist - 1)
+    where = jnp.zeros((npairs,), jnp.int32).at[order].set(
+        tfirst[slist] * tile + pair - start[slist], unique_indices=True)
+    return (tile_list, tile_q, where.reshape(nq, nprobe),
+            (key < nlist).reshape(nq, nprobe), tend[-1])
+
+
+def _decode_block(block, codec: str, vmin, span):
+    """Stored list rows (..., d) -> fp32."""
+    if codec == "sq8":
+        return vmin + block.astype(jnp.float32) * (span / 255.0)
+    return block.astype(jnp.float32)
+
+
+def _gather_lists(list_data, lists):
+    """``list_data[lists]``, (G, cap, d) in the storage dtype, gathered
+    through a view of the store in slices of at most _GATHER_SLICE_BYTES
+    (the reshape is a bitcast: whole sublane tiles of rows stay together)."""
+    nlist, cap, d = list_data.shape
+    sub = cap
+    while (sub % 64 == 0
+           and sub * d * list_data.dtype.itemsize > _GATHER_SLICE_BYTES):
+        sub //= 2
+    parts = cap // sub
+    view = list_data.reshape(nlist * parts, sub, d)
+    idx = lists[:, None] * parts + jnp.arange(parts, dtype=lists.dtype)[None, :]
+    return view[idx.reshape(-1)].reshape(lists.shape[0], cap, d)
+
+
+def _listmajor_scan(list_data, list_ids, list_sizes, q, qn, probes, k: int,
+                    metric: str, codec: str, vmin, span, list_norms,
+                    scan_bf16: bool, tile: int, group: int, nvalid):
+    """The probe scan in list-major order: each probed list is gathered
+    once per ``tile`` queries that probe it and multiplied against all of
+    them, ``einsum("gtd,gcd->gtc")``, where the query-major scan gathers
+    one (cap, d) block per (query, probe) pair for a matrix-vector product.
+
+    Every row of every probed list is scored for every query that probes
+    it, at the query-major scan's precision. NO PAIR IS EVER DROPPED: the
+    tile arrays are sized by ``listmajor_tile_bound``, which no set of
+    probes can pass, so there is no overflow and no retry; the loop's trip
+    count is the traced number of tile groups in use, so the bound costs
+    nothing when the probes are spread.
+
+    The loop only scores: a tile's masked scores land in a ``(slots, cap)``
+    buffer at ``tile * T + slot``. The top-k is taken once a query, over
+    its ``nprobe`` score rows side by side in probe order — the
+    query-major scan's candidates in the query-major scan's order, so ties
+    fall as there (earlier probe, then lower position). A ``top_k`` a pair
+    inside the loop is a sort of every ``cap``-wide slot row on a v5e, 60%
+    of a launch (PERF.md section 6, PR 31); over a query's whole row
+    ``_seg_reduce`` picks the few segments that can hold a neighbour
+    first. The buffer is ``(pairs + nlist * T) * cap * 4`` bytes at most."""
+    nq, nprobe = probes.shape
+    nlist, cap = list_data.shape[0], list_data.shape[1]
+    ntiles = listmajor_tile_bound(nq * nprobe, nlist, tile)
+    ntiles = -(-ntiles // group) * group
+    with jax.named_scope("coarse"):
+        tile_list, tile_q, where, pair_live, used = _listmajor_plan(
+            probes, nlist, tile, ntiles, nvalid)
+        # a tile's row mask and stored norms, gathered for all tiles at once:
+        # sixteen rows a loop step is a gather bound by its latency
+        tile_valid = ((jnp.arange(cap)[None, :] < list_sizes[tile_list][:, None])
+                      & (list_ids[tile_list] >= 0))  # (ntiles, cap)
+        tile_norms = None if list_norms is None else list_norms[tile_list]
+
+    def body(i, scores):
+        with jax.named_scope("list_scan"):
+            t0 = i * group
+            tl = jax.lax.dynamic_slice_in_dim(tile_list, t0, group)  # (G,)
+            tq = jax.lax.dynamic_slice_in_dim(tile_q, t0, group)  # (G, T)
+            block = _decode_block(_gather_lists(list_data, tl),
+                                  codec, vmin, span)  # (G, cap, d)
+            qs = q[tq]  # (G, T, d)
+            if scan_bf16:
+                ip = jnp.einsum("gtd,gcd->gtc", qs.astype(jnp.bfloat16),
+                                block.astype(jnp.bfloat16),
+                                preferred_element_type=jnp.float32)
+            else:
+                ip = jnp.einsum("gtd,gcd->gtc", qs, block, precision=_HIGHEST,
+                                preferred_element_type=jnp.float32)
+            if metric == "dot":
+                s = ip
+            else:
+                bn = (jax.lax.dynamic_slice_in_dim(tile_norms, t0, group)
+                      if tile_norms is not None else base.row_norms_f32(block))
+                s = -(qn[tq] - 2.0 * ip + bn[:, None, :])
+            valid = jax.lax.dynamic_slice_in_dim(tile_valid, t0, group)
+            s = jnp.where(valid[:, None, :], s, distance.NEG_INF)
+            return jax.lax.dynamic_update_slice_in_dim(
+                scores, s.reshape(group * tile, cap), t0 * tile, axis=0)
+
+    # every slot a live pair reads is written by a tile in use: no fill
+    scores = jax.lax.fori_loop(0, -(-used // group), body,
+                               jax.lax.empty((ntiles * tile, cap), jnp.float32))
+    with jax.named_scope("merge_topk"):
+        row = jnp.where(pair_live[:, :, None], scores[where], distance.NEG_INF)
+        vals, pos = distance.segmented_argtopk(row.reshape(nq, nprobe * cap), k)
+        if vals.shape[1] < k:  # fewer columns than k: the tail stays empty
+            pad = ((0, 0), (0, k - vals.shape[1]))
+            vals = jnp.pad(vals, pad, constant_values=distance.NEG_INF)
+            pos = jnp.pad(pos, pad, constant_values=-1)
+        found = (pos >= 0) & (vals > distance.NEG_INF)
+        pos = jnp.where(found, pos, 0)
+        lists = jnp.take_along_axis(probes, pos // cap, axis=1)
+        ids = list_ids[lists, pos % cap].astype(jnp.int32)
+        return vals, jnp.where(found, ids, -1)
+
+
 @functools.partial(jax.jit, static_argnames=("k", "nprobe", "g", "metric", "codec",
-                                             "use_pallas", "scan_bf16"))
+                                             "use_pallas", "scan_bf16", "tile",
+                                             "group"))
 def _ivf_flat_search(centroids, list_data, list_ids, list_sizes, q,
                      k: int, nprobe: int, g: int, metric: str, codec: str,
                      vmin=None, span=None, list_norms=None,
-                     use_pallas: bool = False, scan_bf16: bool = False):
+                     use_pallas: bool = False, scan_bf16: bool = False,
+                     tile: int = 1, group: int = 1, nvalid=None):
     """IVF-Flat/SQ8 probe scan.
+
+    The XLA arm scans list-major (_listmajor_scan): tiles of ``tile`` query
+    slots, ``group`` tiles a loop step, both chosen by the index from
+    static shapes (``listmajor_tiling``); the defaults are the pair-major
+    scan, one pair a step. The Pallas arm scans query-major, ``g`` probes
+    of every query a step.
+    nvalid: traced int32, the block's real rows; the rest is zero padding
+    whose pairs the list-major scan leaves out (their result rows are
+    empty). None: every row is real.
 
     list_norms: (nlist, cap) fp32 stored ``||x||^2`` of the decoded rows
     (computed once at add/encode time — see base.row_norms_f32); None falls
     back to recomputing them from the gathered block every query (the
     pre-stored-norms behavior, kept as the A/B/golden reference).
     use_pallas: fused VMEM kernel (ops/flat_pallas.py) — the probed tiles
-    stream HBM->VMEM via a scalar-prefetched gather and the fp32
-    ``(nq, g, cap, d)`` block transient never exists.
+    stream HBM->VMEM via a scalar-prefetched gather and no gathered block
+    exists in HBM.
     scan_bf16: bf16 MXU scan (halved compute-operand traffic); models gate
     it behind refine_k_factor > 0 so final scores stay exact.
     """
@@ -159,8 +360,13 @@ def _ivf_flat_search(centroids, list_data, list_ids, list_sizes, q,
         coarse = distance.pairwise_scores(q, centroids, metric)
         _, probes = distance.segmented_argtopk(coarse, nprobe)  # (nq, nprobe)
         qn = jnp.sum(q * q, axis=1, keepdims=True)
-        groups = probes.reshape(nq, nprobe // g, g).transpose(1, 0, 2)  # (ng, nq, g)
+    if not use_pallas:
+        return _listmajor_scan(list_data, list_ids, list_sizes, q, qn, probes,
+                               k, metric, codec, vmin, span, list_norms,
+                               scan_bf16, tile, group, nvalid)
+    from distributed_faiss_tpu.ops import flat_pallas
 
+    groups = probes.reshape(nq, nprobe // g, g).transpose(1, 0, 2)  # (ng, nq, g)
     init = (
         jnp.full((nq, k), distance.NEG_INF, jnp.float32),
         jnp.full((nq, k), -1, jnp.int32),
@@ -169,36 +375,10 @@ def _ivf_flat_search(centroids, list_data, list_ids, list_sizes, q,
     def body(carry, li):  # li: (nq, g)
         with jax.named_scope("list_scan"):
             ids = list_ids[li]  # (nq, g, cap)
-            sizes = list_sizes[li]  # (nq, g)
-            if use_pallas:
-                from distributed_faiss_tpu.ops import flat_pallas
-
-                s = flat_pallas.flat_list_scan_auto(
-                    q, list_data, list_ids, li, sizes, list_norms, vmin, span,
-                    metric=metric, codec=codec, scan_bf16=scan_bf16,
-                )  # (nq, g, cap), size/ids mask already applied in-kernel
-            else:
-                block = list_data[li]  # (nq, g, cap, d) storage dtype
-                if codec == "sq8":
-                    block = vmin[None, None, None, :] + block.astype(jnp.float32) \
-                        * (span[None, None, None, :] / 255.0)
-                else:
-                    block = block.astype(jnp.float32)
-                if scan_bf16:
-                    ip = jnp.einsum("qd,qgcd->qgc", q.astype(jnp.bfloat16),
-                                    block.astype(jnp.bfloat16),
-                                    preferred_element_type=jnp.float32)
-                else:
-                    ip = jnp.einsum("qd,qgcd->qgc", q, block, precision=_HIGHEST,
-                                    preferred_element_type=jnp.float32)
-                if metric == "dot":
-                    s = ip
-                else:
-                    bn = (list_norms[li] if list_norms is not None
-                          else base.row_norms_f32(block))
-                    s = -(qn[:, :, None] - 2.0 * ip + bn)
-                valid = (jnp.arange(cap)[None, None, :] < sizes[:, :, None]) & (ids >= 0)
-                s = jnp.where(valid, s, distance.NEG_INF)
+            s = flat_pallas.flat_list_scan_auto(
+                q, list_data, list_ids, li, list_sizes[li], list_norms, vmin, span,
+                metric=metric, codec=codec, scan_bf16=scan_bf16,
+            )  # (nq, g, cap), size/ids mask already applied in-kernel
         return _merge_group(carry, s.reshape(nq, g * cap), ids.reshape(nq, g * cap), k), None
 
     (vals, ids), _ = jax.lax.scan(body, init, groups)
@@ -263,29 +443,34 @@ def _ivf_pq_search(centroids, codebooks, list_codes, list_ids, list_sizes, q,
 
 @functools.partial(jax.jit, static_argnames=("k", "scan_k", "nprobe", "g", "metric",
                                              "codec", "refine", "use_pallas",
-                                             "scan_bf16"))
+                                             "scan_bf16", "tile", "group"))
 def _ivf_flat_search_fused(centroids, list_data, list_ids, list_sizes, refine_data,
                            q3, k: int, scan_k: int, nprobe: int, g: int,
                            metric: str, codec: str, refine: bool,
                            vmin=None, span=None, list_norms=None,
-                           use_pallas: bool = False, scan_bf16: bool = False):
+                           use_pallas: bool = False, scan_bf16: bool = False,
+                           tile: int = 1, group: int = 1, counts=None):
     """Whole multi-block search in ONE device launch.
 
-    q3: (nblocks, block, d). ``lax.map`` runs the per-block program
-    sequentially on device, so the transient-memory budgets sized for one
-    block still hold — but the host pays a single dispatch for the
-    entire batch instead of one per block (benchmarks/profile_ivf.py)."""
+    q3: (nblocks, block, d); counts: (nblocks,) int32 real rows of each
+    block (``_ivf_flat_search``'s ``nvalid``), or None. ``lax.map`` runs
+    the per-block program sequentially on device, so the transient-memory
+    budgets sized for one block still hold — but the host pays a single
+    dispatch for the entire batch instead of one per block
+    (benchmarks/profile_ivf.py)."""
 
-    def body(qb):
+    def body(block):
+        qb, nvalid = block
         vals, ids = _ivf_flat_search(centroids, list_data, list_ids, list_sizes,
                                      qb, scan_k, nprobe, g, metric, codec,
                                      vmin, span, list_norms,
-                                     use_pallas=use_pallas, scan_bf16=scan_bf16)
+                                     use_pallas=use_pallas, scan_bf16=scan_bf16,
+                                     tile=tile, group=group, nvalid=nvalid)
         if refine:
             vals, ids = _rerank_exact(refine_data, qb, ids, k, metric)
         return vals, ids
 
-    return jax.lax.map(body, q3)
+    return jax.lax.map(body, (q3, counts))
 
 
 @functools.partial(jax.jit, static_argnames=("k", "adc_k", "nprobe", "g", "metric",
@@ -428,7 +613,7 @@ class _IVFBase(base.TpuIndex):
         return out
 
     def _search_blocks(self, q: np.ndarray, k: int, fn, block: int = 256,
-                       fused_fn=None, refine_fn=None):
+                       fused_fn=None, refine_fn=None, with_counts: bool = False):
         """Blocked search driver — see ``models.base.blocked_search`` (the
         single shared implementation: one launch per block by default;
         with ``fused_fn`` a multi-block batch runs in ONE lax.map launch,
@@ -436,7 +621,7 @@ class _IVFBase(base.TpuIndex):
         there; ``refine_fn`` is the per-block exact rerank, dispatched
         after ``fn``'s scan has finished)."""
         return base.blocked_search(q, k, self.metric, fn, block, fused_fn,
-                                   refine_fn)
+                                   refine_fn, with_counts)
 
     def _empty_results(self, nq: int, k: int):
         d = np.full((nq, k), np.inf if self.metric == "l2" else -np.inf, np.float32)
@@ -615,23 +800,35 @@ class IVFFlatIndex(_IVFBase):
         self._pallas_flat_validated = True
         _first_use_check(self, scan, self._pallas_probe, self._PALLAS_KERNEL, 1e-3)
 
+    def _scan_tiling(self, rows: int, nprobe: int):
+        """(tile, group) of the XLA probe scan for a block of ``rows`` query
+        rows: the one place they are decided, asked at every search since
+        the capacity grows with the lists (``listmajor_tiling`` is the
+        rule)."""
+        return listmajor_tiling(rows, nprobe, self.nlist, self.lists.cap,
+                                self.dim, np.dtype(self.lists.dtype).itemsize)
+
     def search(self, q: np.ndarray, k: int):
         if self._n == 0:
             return self._empty_results(q.shape[0], k)
         nprobe = min(self.nprobe, self.nlist)
-        # group payload: the gathered fp32 (nb, g, cap, d) block; nb chosen
-        # launch-bound-aware (see base.pick_query_block). The pallas kernel
-        # never materializes that block, but sizing for the XLA fallback
-        # keeps the budgets valid on whichever path actually runs.
+        # nb: rows a block, launch-bound-aware (see base.pick_query_block);
+        # g: probes a step of the Pallas arm, sized as for a gathered fp32
+        # (nb, g, cap, d) block it never materializes. The XLA arm scans
+        # list-major under (tile, group).
         nb = base.pick_query_block(self.lists.cap * self.dim * 4)
         g = probe_group_size(nprobe, nb * self.lists.cap * self.dim * 4)
-        extra = {}
+        # the block this call launches: a batch under one block pads to its
+        # own pow2 bucket, not to nb
+        rows = nb if q.shape[0] > nb else distance.bucket_size(q.shape[0])
+        tile, group = self._scan_tiling(rows, nprobe)
+        extra = dict(tile=tile, group=group)
         if self.codec == "sq8":
-            extra = dict(vmin=self.sq_params["vmin"], span=self.sq_params["span"])
+            extra.update(vmin=self.sq_params["vmin"], span=self.sq_params["span"])
         norms = self._scan_norms()
         scan_k = k * self.refine_k_factor if self.refine_k_factor else k
 
-        def scan(b, with_pallas):
+        def scan(b, with_pallas, nvalid=None):
             # maybe_checked = GRAFT_SANITIZE=1 checkify wrapper (identity
             # when off); scalar knobs ride as kwargs so the sanitizer can
             # partial-bind them before checkify abstracts the operands
@@ -640,7 +837,7 @@ class IVFFlatIndex(_IVFBase):
                 self.centroids, self.lists.data, self.lists.ids, self.lists.sizes,
                 b, k=scan_k, nprobe=nprobe, g=g, metric=self.metric,
                 codec=self.codec, list_norms=norms, use_pallas=with_pallas,
-                scan_bf16=self.scan_bf16, **extra,
+                scan_bf16=self.scan_bf16, nvalid=nvalid, **extra,
             )
 
         if self.use_pallas and self._pallas_runtime_ok and not self._pallas_flat_validated:
@@ -648,15 +845,31 @@ class IVFFlatIndex(_IVFBase):
                 distance.pad_rows(np.asarray(q[:8], np.float32), 8))
             self._validate_flat_pallas(scan)
 
-        def run(b):
-            return pallas_guarded(self, lambda p: scan(b, p))
+        def guarded(call):
+            """pallas_guarded, plus the count row that says the scan's
+            program took the list-major order (``engine.scan_listmajor``,
+            beside the ``engine.scan`` stage this runs in): the XLA arm
+            does, the Pallas kernel scans query-major; the last path tried
+            is the one served."""
+            tried = []
+
+            def attempt(with_pallas):
+                tried.append(with_pallas)
+                return call(with_pallas)
+
+            out = pallas_guarded(self, attempt)
+            if not tried[-1]:
+                tracing.count("engine.scan_listmajor")
+            return out
+
+        def run(b, n):
+            return guarded(lambda p: scan(b, p, n))
 
         def refine(b, ids):
             return _rerank_exact(self.refine_store.data, b, ids, k, self.metric)
 
-        def run_fused(q3):
-            return pallas_guarded(
-                self,
+        def run_fused(q3, counts):
+            return guarded(
                 lambda p: sanitize.maybe_checked(
                     _ivf_flat_search_fused,
                     self.centroids, self.lists.data, self.lists.ids, self.lists.sizes,
@@ -664,13 +877,14 @@ class IVFFlatIndex(_IVFBase):
                     q3, k=k, scan_k=scan_k, nprobe=nprobe, g=g,
                     metric=self.metric, codec=self.codec,
                     refine=bool(self.refine_k_factor), list_norms=norms,
-                    use_pallas=p, scan_bf16=self.scan_bf16, **extra,
-                ),
-            )
+                    use_pallas=p, scan_bf16=self.scan_bf16, counts=counts,
+                    **extra,
+                ))
 
         return self._search_blocks(
             q, k, run, block=nb, fused_fn=run_fused,
-            refine_fn=refine if self.refine_k_factor else None)
+            refine_fn=refine if self.refine_k_factor else None,
+            with_counts=True)
 
     def reconstruct_batch(self, ids: np.ndarray) -> np.ndarray:
         rows = self._device_rows(ids)

@@ -405,15 +405,28 @@ def _ivf_flat_operands(codec="f16"):
             _sds((_NLIST,), "int32"))           # list_sizes
 
 
+def _listmajor_tiling():
+    from distributed_faiss_tpu.models import ivf
+
+    tile, group = ivf.listmajor_tiling(_NQ, _NPROBE, _NLIST, _CAP, _D, 2)
+    return dict(tile=tile, group=group)
+
+
 def spec_ivf_flat_search():
     cents, data, ids, sizes = _ivf_flat_operands()
     q = _sds((_NQ, _D), "float32")
     norms = _sds((_NLIST, _CAP), "float32")
-    stat = dict(k=_K, nprobe=_NPROBE, g=_NPROBE, metric="l2", codec="f16")
+    # the XLA arm as IVFFlatIndex.search launches it: list-major under the
+    # index's own (tile, group) rule, the block's real rows a traced scalar
+    stat = dict(k=_K, nprobe=_NPROBE, g=_NPROBE, metric="l2", codec="f16",
+                **_listmajor_tiling())
+    dyn = dict(list_norms=norms, nvalid=_sds((), "int32"))
     return [
-        ((cents, data, ids, sizes, q), dict(stat, list_norms=norms)),
-        ((cents, data, ids, sizes, q), dict(stat, list_norms=norms,
-                                            scan_bf16=True)),
+        ((cents, data, ids, sizes, q), dict(stat, **dyn)),
+        ((cents, data, ids, sizes, q), dict(stat, **dyn, scan_bf16=True)),
+        # a one-row request's bucket: a tile is a pair
+        ((cents, data, ids, sizes, _sds((8, _D), "float32")),
+         dict(stat, **dyn, tile=1)),
     ]
 
 
@@ -434,7 +447,8 @@ def spec_ivf_flat_search_fused():
     norms = _sds((_NLIST, _CAP), "float32")
     return [((cents, data, ids, sizes, refine, q3),
              dict(k=_K, scan_k=4 * _K, nprobe=_NPROBE, g=_NPROBE,
-                  metric="l2", codec="f16", refine=True, list_norms=norms))]
+                  metric="l2", codec="f16", refine=True, list_norms=norms,
+                  counts=_sds((_NBLOCKS,), "int32"), **_listmajor_tiling()))]
 
 
 def spec_ivf_pq_search_fused():

@@ -1,4 +1,5 @@
-"""Every Pallas kernel at the geometries chip_smoke.py makes it emit, as
+"""Every Pallas kernel at the geometries chip_smoke.py makes it emit, and
+the XLA scan program of ``ivfsq-batch`` (``listmajor_programs``), as
 abstract signatures — shared by tests/test_pallas_tpu_lowering.py, which
 lowers each for the ``tpu`` platform in-process, and by this file run as a
 script, which compiles each for a v5e with no chip attached (libtpu's
@@ -81,6 +82,32 @@ def cases():
     return out
 
 
+def listmajor_programs():
+    """``ivfsq-batch``'s scan program (PR 31: the XLA arm, list-major, its
+    loop's trip count traced) at the cell's geometry — d 512, capacity
+    4096, 1024 float16 lists, nprobe 64, k 10 — for the 128- and the
+    256-row bucket, under the index's own tiling: [(name, fn, sig)]."""
+    from distributed_faiss_tpu.models import ivf
+
+    d, cap, nlist, nprobe = 512, 4096, 1024, 64
+
+    def program(rows):
+        tile, group = ivf.listmajor_tiling(rows, nprobe, nlist, cap, d, 2)
+
+        def sig(sds):
+            args = (sds((nlist, d), "float32"), sds((nlist, cap, d), "float16"),
+                    sds((nlist, cap), "int32"), sds((nlist,), "int32"),
+                    sds((rows, d), "float32"))
+            return args, dict(k=10, nprobe=nprobe, g=1, metric="l2", codec="f16",
+                              list_norms=sds((nlist, cap), "float32"),
+                              tile=tile, group=group, nvalid=sds((), "int32"))
+
+        return (f"ivf flat list-major rows={rows} T={tile} G={group}",
+                ivf._ivf_flat_search, sig)
+
+    return [program(128), program(256)]
+
+
 def lower_for_tpu(fn, sig, sds):
     args, kwargs = sig(sds)
     return fn.trace(*args, **kwargs).lower(lowering_platforms=("tpu",))
@@ -122,6 +149,15 @@ def main():
             print(json.dumps({"case": name, "ok": True}), flush=True)
         except Exception as e:
             print(json.dumps({"case": name, "ok": False,
+                              "error": f"{type(e).__name__}: {e}"[:600]}),
+                  flush=True)
+    for name, fn, sig in listmajor_programs():
+        try:
+            mem = lower_for_tpu(fn, sig, sds).compile().memory_analysis()
+            print(json.dumps({"program": name, "ok": True,
+                              "temp_bytes": mem.temp_size_in_bytes}), flush=True)
+        except Exception as e:
+            print(json.dumps({"program": name, "ok": False,
                               "error": f"{type(e).__name__}: {e}"[:600]}),
                   flush=True)
     print(json.dumps({"exact_scan_sort_widths": exact_scan_sort_widths(sds)}),

@@ -501,8 +501,9 @@ def test_per_block_scans_each_count(rng, monkeypatch):
     blocked = base.blocked_search
     monkeypatch.setattr(
         base, "blocked_search",
-        lambda q, k, metric, fn, block=256, fused_fn=None, refine_fn=None:
-        blocked(q, k, metric, fn, block, None, refine_fn))
+        lambda q, k, metric, fn, block=256, fused_fn=None, refine_fn=None,
+        with_counts=False:
+        blocked(q, k, metric, fn, block, None, refine_fn, with_counts))
     sink = tracing.LatencyStats()
     with tracing.stage("engine.launch", sink=sink):
         idx.search(x[:20], 5)

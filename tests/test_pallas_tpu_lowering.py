@@ -68,3 +68,15 @@ def test_the_exact_scan_compiled_for_v5e_sorts_no_wide_row(compiled_for_v5e):
     (row,) = [r for r in compiled_for_v5e if "exact_scan_sort_widths" in r]
     assert row["exact_scan_sort_widths"], "no sort found: the parse is stale"
     assert max(row["exact_scan_sort_widths"]) <= distance.SCAN_CHUNK // 128
+
+
+def test_the_list_major_scan_compiles_for_v5e_and_copies_no_store(compiled_for_v5e):
+    """``ivfsq-batch``'s program, with its traced trip count, at the 128-
+    and the 256-row bucket. Its scratch is the score buffer, 0.5 and 1.0 GB:
+    a gather slice of a whole (4096, 512) list makes XLA:TPU copy the 4.3 GB
+    store into slabs in every loop step (``temp_size`` 4.3 GB: the parent's
+    1.1 s a launch, PERF.md section 6, PR 31); the 256 KB view avoids it."""
+    rows = [r for r in compiled_for_v5e if "program" in r]
+    assert len(rows) == len(pallas_tpu_cases.listmajor_programs()) == 2
+    assert all(r["ok"] for r in rows), rows
+    assert max(r["temp_bytes"] for r in rows) < 2 << 30, rows
