@@ -76,6 +76,7 @@ def one_geometry(nq, m, ksub, L, device_kind, peaks):
     rng = np.random.default_rng(0)
     lut = jnp.asarray(rng.standard_normal((nq, m, ksub)).astype(np.float32))
     codes = jnp.asarray(rng.integers(0, 256, (nq, L, m)).astype(np.uint8))
+    full = jnp.full((nq,), L, jnp.int32)  # every list at its capacity
 
     rows = nq * L
     code_bytes = rows * m  # true codes traffic
@@ -86,7 +87,7 @@ def one_geometry(nq, m, ksub, L, device_kind, peaks):
         ("xla-onehot", lambda: pq.adc_scan(lut, codes)),
         # f32 table values as three bf16 planes, bf16 one-hot, one MXU pass
         ("pallas-planes-f32",
-         lambda: adc_pallas.adc_scan_pallas_planes(lut, codes)),
+         lambda: adc_pallas.adc_scan_pallas_planes(lut, codes, full)),
     ]
 
     for name, fn in variants:

@@ -9,7 +9,9 @@ emit, and asserts parity with a plain numpy reference:
   - ADC three-plane one-hot (fp32 table values in one bf16 MXU pass) at the
     benchmark cells' geometry (m=64, lists of capacity 1024) and at m=8,
     on tables whose entries need all three planes, and on a ragged list;
-    beside it the XLA one-hot (ops/pq.adc_scan), the oracle it is held to
+    beside it the XLA one-hot (ops/pq.adc_scan), the oracle it is held to;
+    and with lists that end short of their capacity (PR 35): bit-equal to
+    its own scan of whole lists up to each list's last sub-tile, -inf past it
   - fused flat list scan for the f32 / f16 / sq8 codecs x l2 / dot at
     d=512 (the ivfsq width), plus the bf16 scan mode
 
@@ -85,7 +87,8 @@ def adc_cases(rng):
     ]
     kernels = {
         "planes": lambda lut, codes: adc_pallas.adc_scan_pallas_planes(
-            lut, codes, interpret=False),
+            lut, codes, jnp.full((codes.shape[0],), codes.shape[1], jnp.int32),
+            interpret=False),
         "xla": pq.adc_scan,
     }
     for name, kind, nq, m, L in cases:
@@ -117,6 +120,44 @@ def adc_cases(rng):
             mag = np_adc_per_query(np.abs(lut_np), codes)
             atol = atol + m * 2.0 ** -24 * mag
         failures += _report(name, got, want, dt, rtol, atol, nq=nq, m=m, L=L)
+    return failures + adc_sized_cases(rng)
+
+
+def adc_sized_cases(rng):
+    """The kernel stops at the end of each pair's list (PR 35): against its
+    own scan of whole lists, compiled, a column of a sub-tile that holds a
+    row is the same bits and a sub-tile past the list is -inf; at the cells'
+    geometry, with every boundary size and a mix like the probed lists'."""
+    import jax.numpy as jnp
+
+    from distributed_faiss_tpu.ops import adc_pallas
+
+    failures = 0
+    for name, pairs, m, L in (("planes_sized_knnlm_cell", 512, 64, 1024),
+                              ("planes_sized_m8_cap256", 64, 8, 256),
+                              ("planes_sized_cap4096", 32, 64, 4096)):
+        lut = jnp.asarray(rng.standard_normal((pairs, m, 256)).astype(np.float32))
+        codes = jnp.asarray(rng.integers(0, 256, (pairs, L, m)).astype(np.uint8))
+        sizes = (80 * rng.integers(0, L // 80 + 1, pairs)).astype(np.int32)
+        sizes[:8] = [0, 1, 127, 128, 129, L - 1, L, L // 2]
+        t0 = time.time()
+        got = np.asarray(adc_pallas.adc_scan_pallas_planes(
+            lut, codes, jnp.asarray(sizes), interpret=False))
+        dt = time.time() - t0
+        whole = np.asarray(adc_pallas.adc_scan_pallas_planes(
+            lut, codes, jnp.full((pairs,), L, jnp.int32), interpret=False))
+        sub = adc_pallas._SUB_TILE
+        computed = np.arange(L)[None, :] < (-(-sizes // sub) * sub)[:, None]
+        ok = bool(np.array_equal(got[computed], whole[computed])
+                  and np.all(got[~computed] == -np.inf)
+                  and int(adc_pallas.scanned_columns(jnp.asarray(sizes), L))
+                  == int(computed.sum()))
+        print(json.dumps({
+            "case": name, "nq": pairs, "m": m, "L": L, "sub_tile": sub,
+            "compiled": True, "computed_share": round(float(computed.mean()), 4),
+            "bit_equal_to_whole_lists": ok, "ok": ok, "first_call_s": round(dt, 2),
+        }), flush=True)
+        failures += 0 if ok else 1
     return failures
 
 

@@ -1781,13 +1781,20 @@ class Index:
         the ``engine.scan`` blocks of an IVF-flat index whose probe scan
         took the list-major order (``models/ivf.listmajor_tiling``;
         ``IVFFlatIndex`` books it);
+        ``engine.scan_adc_cols`` and ``engine.scan_adc_cols_skipped`` (count
+        rows, shown the same way) sum, one record an ``engine.scan`` of an
+        IVF-PQ index, the candidate columns of its (query, probe) pairs'
+        whole capacity and those of them the ADC scan did not compute (the
+        fused kernel stops at the end of each list; the XLA one-hot skips
+        none; ``IVFPQIndex._book_adc_cols`` books both);
         ``engine.store_grow`` is one record a reallocation of a
         ``DeviceVectorStore`` (models/base.py), allocation to the end of
         the copy."""
         out = self.perf.summary(raw=raw)
         if "engine.scan" in out:
             for name in ("engine.scan_fused", "engine.scan_rows",
-                         "engine.scan_prefilter", "engine.scan_listmajor"):
+                         "engine.scan_prefilter", "engine.scan_listmajor",
+                         "engine.scan_adc_cols", "engine.scan_adc_cols_skipped"):
                 out.setdefault(name, tracing.zero_row())
         return out
 

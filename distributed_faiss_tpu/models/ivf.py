@@ -385,22 +385,30 @@ def _ivf_flat_search(centroids, list_data, list_ids, list_sizes, q,
     return vals, ids
 
 
-def _adc_pair_scores(lut, codes, use_pallas: bool):
+def _adc_pair_scores(lut, codes, sizes, use_pallas: bool):
     """ADC scores of P (query, probe) pairs: ``lut`` (P, m, ksub) f32 tables,
-    ``codes`` (P, L, m) uint8 -> (P, L) f32. The one place the PQ programs
-    (here and in parallel/mesh.py) pick between the fused three-plane kernel
-    and the XLA one-hot einsum; ``use_pallas`` is the index's answer
-    (IVFPQIndex._kernel_applies), taken before the trace."""
+    ``codes`` (P, L, m) uint8, ``sizes`` (P,) int32 the rows each pair's list
+    holds (0 for a pair the caller will mask whole) -> ((P, L) f32, the
+    candidate columns scored as an int32 scalar). The one place the PQ
+    programs (here and in parallel/mesh.py) pick between the fused
+    three-plane kernel, which scores a list's whole sub-tiles and leaves the
+    rest at -inf, and the XLA one-hot einsum, which scores every column;
+    the caller masks past ``sizes`` either way. ``use_pallas`` is the
+    index's answer (IVFPQIndex._kernel_applies), taken before the trace."""
+    P, L, m = codes.shape
     if use_pallas:
-        return adc_pallas.adc_scan_pallas_planes(
-            lut, codes, interpret=not adc_pallas.on_tpu())
-    return pq.adc_scan(lut, codes)
+        return (adc_pallas.adc_scan_pallas_planes(
+                    lut, codes, sizes, interpret=not adc_pallas.on_tpu()),
+                adc_pallas.scanned_columns(sizes, L))
+    return pq.adc_scan(lut, codes), jnp.int32(P * L)
 
 
 @functools.partial(jax.jit, static_argnames=("k", "nprobe", "g", "metric", "use_pallas"))
 def _ivf_pq_search(centroids, codebooks, list_codes, list_ids, list_sizes, q,
                    k: int, nprobe: int, g: int, metric: str,
                    use_pallas: bool = False):
+    """-> (vals, ids, the candidate columns the scan computed ADC sums for:
+    an int32 scalar, ``nq * nprobe * cap`` on the XLA arm)."""
     q = q.astype(jnp.float32)
     nq = q.shape[0]
     cap = list_codes.shape[1]
@@ -430,15 +438,16 @@ def _ivf_pq_search(centroids, codebooks, list_codes, list_ids, list_sizes, q,
                 lut = lut.reshape(nq, g, m, ksub)
             else:
                 lut = jnp.broadcast_to(shared_lut[:, None], (nq, g, m, ksub))
-            s = _adc_pair_scores(lut.reshape(nq * g, m, ksub),
-                                 codes.reshape(nq * g, cap, m),
-                                 use_pallas).reshape(nq, g, cap)
+            s, cols = _adc_pair_scores(lut.reshape(nq * g, m, ksub),
+                                       codes.reshape(nq * g, cap, m),
+                                       sizes.reshape(nq * g), use_pallas)
+            s = s.reshape(nq, g, cap)
             valid = (jnp.arange(cap)[None, None, :] < sizes[:, :, None]) & (ids >= 0)
             s = jnp.where(valid, s, distance.NEG_INF)
-        return _merge_group(carry, s.reshape(nq, g * cap), ids.reshape(nq, g * cap), k), None
+        return _merge_group(carry, s.reshape(nq, g * cap), ids.reshape(nq, g * cap), k), cols
 
-    (vals, ids), _ = jax.lax.scan(body, init, groups)
-    return vals, ids
+    (vals, ids), cols = jax.lax.scan(body, init, groups)
+    return vals, ids, jnp.sum(cols)
 
 
 @functools.partial(jax.jit, static_argnames=("k", "scan_k", "nprobe", "g", "metric",
@@ -478,15 +487,16 @@ def _ivf_flat_search_fused(centroids, list_data, list_ids, list_sizes, refine_da
 def _ivf_pq_search_fused(centroids, codebooks, list_codes, list_ids, list_sizes,
                          refine_data, q3, k: int, adc_k: int, nprobe: int, g: int,
                          metric: str, use_pallas: bool, refine: bool):
-    """Multi-block IVF-PQ search in one launch (see _ivf_flat_search_fused)."""
+    """Multi-block IVF-PQ search in one launch (see _ivf_flat_search_fused);
+    the third output is ``_ivf_pq_search``'s, one count a block."""
 
     def body(qb):
-        vals, ids = _ivf_pq_search(centroids, codebooks, list_codes, list_ids,
-                                   list_sizes, qb, adc_k, nprobe, g, metric,
-                                   use_pallas=use_pallas)
+        vals, ids, cols = _ivf_pq_search(centroids, codebooks, list_codes, list_ids,
+                                         list_sizes, qb, adc_k, nprobe, g, metric,
+                                         use_pallas=use_pallas)
         if refine:
             vals, ids = _rerank_exact(refine_data, qb, ids, k, metric)
-        return vals, ids
+        return vals, ids, cols
 
     return jax.lax.map(body, q3)
 
@@ -658,7 +668,7 @@ def _first_use_check(index, scan, probe, kernel: str, tol: float) -> None:
     where BOTH paths fail is a bad request — leave the kernel alone and let
     the real search surface the error through pallas_guarded."""
     try:
-        pv, _ = scan(probe, True)
+        pv = scan(probe, True)[0]
         jax.block_until_ready(pv)
     except Exception:
         try:
@@ -670,7 +680,7 @@ def _first_use_check(index, scan, probe, kernel: str, tol: float) -> None:
             "pallas %s kernel failed its first-use oracle check; using the "
             "XLA scan for the rest of this process", kernel)
         return
-    xv, _ = scan(probe, False)
+    xv = scan(probe, False)[0]
     with xfercheck.explicit("first-use oracle check fetch"):
         pv, xv = np.asarray(pv), np.asarray(xv)
     finite = np.isfinite(xv)
@@ -1065,6 +1075,23 @@ class IVFPQIndex(_IVFBase):
             tracing.count("engine.scan_fused")
         return out
 
+    @staticmethod
+    def _book_adc_cols(counts) -> None:
+        """Two count rows a scan, beside ``engine.scan_fused``, from
+        ``counts``' (columns of the scan's pairs at their whole capacity,
+        the program's third output): ``engine.scan_adc_cols`` and
+        ``engine.scan_adc_cols_skipped`` (those the ADC scan did not compute:
+        the kernel stops at the end of each list, the XLA one-hot skips
+        none). Called once the search's results are on the host: a
+        device-to-host read is 0.4 ms of latency on a v5e even for four
+        ready bytes (PERF.md, PR 35), and by then the counts, whose copies
+        started with their launches, have landed."""
+        for adc_cols, cols in counts:
+            with xfercheck.explicit("ADC column count fetch"):
+                scored = int(np.sum(np.asarray(cols), dtype=np.int64))
+            tracing.count("engine.scan_adc_cols", float(adc_cols))
+            tracing.count("engine.scan_adc_cols_skipped", float(adc_cols - scored))
+
     def train(self, x: np.ndarray) -> None:
         x = np.asarray(x, np.float32)
         self._train_centroids(x)
@@ -1103,13 +1130,26 @@ class IVFPQIndex(_IVFBase):
             nprobe, pq_probe_payload_bytes(self.lists.cap, self.m, nq_block=rows))
         adc_k = k * self.refine_k_factor if self.refine_k_factor else k
 
+        counts = []  # (capacity columns, columns scored) of every scan
+
+        def launched(out):
+            """A scan program's outputs, its count on the way to the host."""
+            with xfercheck.explicit("ADC column count, started with the launch"):
+                out[2].copy_to_host_async()
+            return out
+
+        def counted(out, rows):
+            vals, ids, cols = out
+            counts.append((rows * nprobe * self.lists.cap, cols))
+            return vals, ids
+
         def adc(b, with_pallas):
-            return sanitize.maybe_checked(
+            return launched(sanitize.maybe_checked(
                 _ivf_pq_search,
                 self.centroids, self.codebooks, self.lists.data, self.lists.ids,
                 self.lists.sizes, b, k=adc_k, nprobe=nprobe, g=g,
                 metric=self.metric, use_pallas=with_pallas,
-            )
+            ))
 
         if (self._kernel_applies() and self._pallas_runtime_ok
                 and not self._adc_validated):
@@ -1122,13 +1162,13 @@ class IVFPQIndex(_IVFBase):
                 self._PALLAS_KERNEL, 1e-4)
 
         def run(b):
-            return self._guarded_scan(lambda p: adc(b, p))
+            return counted(self._guarded_scan(lambda p: adc(b, p)), b.shape[0])
 
         def refine(b, ids):
             return _rerank_exact(self.refine_store.data, b, ids, k, self.metric)
 
         def adc_fused(q3, with_pallas):
-            return sanitize.maybe_checked(
+            return launched(sanitize.maybe_checked(
                 _ivf_pq_search_fused,
                 self.centroids, self.codebooks, self.lists.data, self.lists.ids,
                 self.lists.sizes,
@@ -1136,14 +1176,17 @@ class IVFPQIndex(_IVFBase):
                 q3, k=k, adc_k=adc_k, nprobe=nprobe, g=g, metric=self.metric,
                 use_pallas=with_pallas,
                 refine=bool(self.refine_k_factor),
-            )
+            ))
 
         def run_fused(q3):
-            return self._guarded_scan(lambda p: adc_fused(q3, p))
+            return counted(self._guarded_scan(lambda p: adc_fused(q3, p)),
+                           q3.shape[0] * q3.shape[1])
 
-        return self._search_blocks(
+        out = self._search_blocks(
             q, k, run, block=nb, fused_fn=run_fused,
             refine_fn=refine if self.refine_k_factor else None)
+        self._book_adc_cols(counts)
+        return out
 
     def reconstruct_batch(self, ids: np.ndarray) -> np.ndarray:
         ids = np.asarray(ids, np.int64)

@@ -1140,9 +1140,11 @@ def _sharded_ivf_pq_search(centroids, codebooks, list_codes, list_ids, list_size
                 lut = lut.reshape(nq, g, m, ksub)
             else:
                 lut = jnp.broadcast_to(shared_lut[:, None], (nq, g, m, ksub))
-            s = ivfmod._adc_pair_scores(lut.reshape(nq * g, m, ksub),
-                                        codes.reshape(nq * g, cap, m),
-                                        use_pallas).reshape(nq, g, cap)
+            # a pair this chip does not own costs the kernel nothing
+            s, _ = ivfmod._adc_pair_scores(
+                lut.reshape(nq * g, m, ksub), codes.reshape(nq * g, cap, m),
+                jnp.where(mine, sizes, 0).reshape(nq * g), use_pallas)
+            s = s.reshape(nq, g, cap)
             valid = (jnp.arange(cap)[None, None, :] < sizes[:, :, None])
             valid = valid & (ids >= 0) & mine[:, :, None]
             s = jnp.where(valid, s, distance.NEG_INF)
@@ -1613,9 +1615,10 @@ def _sharded_ivf_pq_search_routed(centroids, codebooks, list_codes, list_ids,
                 r = qv
             lut = pqops.adc_lut(r, codebooks, metric=metric)  # (g, m, ksub)
             codes = codes_local[slot]                    # (g, cap, m)
-            s = ivfmod._adc_pair_scores(lut, codes, use_pallas)  # (g, cap)
             ids = ids_local[slot]
             sizes = sizes_local[slot]
+            s, _ = ivfmod._adc_pair_scores(              # (g, cap)
+                lut, codes, jnp.where(valid, sizes, 0), use_pallas)
             ok = (jnp.arange(cap)[None, :] < sizes[:, None]) & (ids >= 0)
             ok = ok & valid[:, None]
             s = jnp.where(ok, s, distance.NEG_INF)

@@ -314,7 +314,8 @@ def spec_flat_list_scan_pallas():
 def spec_adc_scan_pallas():
     lut = _sds((_K, _M, _KSUB), "float32")
     codes = _sds((_K, _L, _M), "uint8")
-    return [((lut, codes), dict(interpret=True))]
+    sizes = _sds((_K,), "int32")
+    return [((lut, codes, sizes), dict(interpret=True))]
 
 
 def _codebooks():

@@ -39,8 +39,8 @@ def _flat(codec, metric, d, nq, g, cap, nlist=1024, scan_bf16=False):
 
 def _adc(m, pairs, L):
     def sig(sds):
-        return ((sds((pairs, m, 256), "float32"), sds((pairs, L, m), "uint8")),
-                dict(interpret=False))
+        return ((sds((pairs, m, 256), "float32"), sds((pairs, L, m), "uint8"),
+                 sds((pairs,), "int32")), dict(interpret=False))
 
     return (f"adc planes m={m} float32 nq={pairs} L={L}",
             adc_pallas.adc_scan_pallas_planes, sig)
@@ -59,9 +59,10 @@ def cases():
     # the widest scalar prefetch the block picker can ask for: 1024 queries
     # x 8 probes (ivf_simple width) — two of these must fit SMEM
     out.append(_flat("f32", "l2", 128, 1024, 8, 1024))
-    # the three-plane ADC kernel at the benchmark cells' shapes: one table a
-    # (query, probe) pair of an online window (4 x 32), a 64-row and a
-    # 256-row window, lists of capacity 1024; and its smallest geometry
+    # the three-plane ADC kernel (with each pair's list size since PR 35) at
+    # the benchmark cells' shapes: one table a (query, probe) pair of an
+    # online window (4 x 32), a 64-row and a 256-row window, lists of
+    # capacity 1024; and its smallest geometry
     for pairs in (128, 2048, 8192):
         out.append(_adc(64, pairs, 1024))
     out.append(_adc(8, 64, 128))
@@ -79,6 +80,11 @@ def cases():
         out.append(_adc(8, 64, L))
     out.append(_adc(64, 8, 1024))
     out.append(_adc(128, 16, 4096))
+    # the widest scalar prefetch: a pair's list size rides in SMEM (PR 35),
+    # and the callers' group budget (models/ivf._GROUP_BYTE_BUDGET over the
+    # least a pair weighs, capacity 128 at m 1) keeps a call under 61,680
+    # pairs; 1 MB of SMEM takes 131,072
+    out.append(_adc(8, 65536, 128))
     return out
 
 

@@ -1,6 +1,8 @@
 """Pallas ADC kernel golden tests (interpreter mode on CPU — same kernel
 code path the TPU runs compiled)."""
 
+import collections
+
 import numpy as np
 import pytest
 
@@ -209,6 +211,11 @@ def wide_tables(rng, shape):
             * 10.0 ** rng.uniform(-3, 3, shape)).astype(np.float32)
 
 
+def _whole_lists(codes):
+    """``sizes`` of lists that fill their capacity: the whole scan."""
+    return np.full(codes.shape[0], codes.shape[1], np.int32)
+
+
 def steep_tables(rng, shape):
     """As an l2 table is: every entry negative, magnitudes up to 1e6."""
     return (-(10.0 ** rng.uniform(0, 6, shape))).astype(np.float32)
@@ -228,7 +235,8 @@ def steep_tables(rng, shape):
 def test_planes_kernel_golden(rng, m, L, tables):
     lut = tables(rng, (3, m, 256))
     codes = rng.integers(0, 256, (3, L, m)).astype(np.uint8)
-    got = np.asarray(adc_pallas.adc_scan_pallas_planes(lut, codes, interpret=True))
+    got = np.asarray(adc_pallas.adc_scan_pallas_planes(
+        lut, codes, _whole_lists(codes), interpret=True))
     assert got.shape == (3, L)
     np.testing.assert_allclose(got, np_adc_f64(lut, codes), rtol=1e-4, atol=1e-4)
 
@@ -253,8 +261,201 @@ def test_planes_kernel_is_exact_where_f32_sums_are(rng):
     golden bit for bit, whatever order the MXU adds in."""
     lut = (rng.integers(-(1 << 20), 1 << 20, (2, 8, 256)) * 2.0 ** -13).astype(np.float32)
     codes = rng.integers(0, 256, (2, 256, 8)).astype(np.uint8)
-    got = np.asarray(adc_pallas.adc_scan_pallas_planes(lut, codes, interpret=True))
+    got = np.asarray(adc_pallas.adc_scan_pallas_planes(
+        lut, codes, _whole_lists(codes), interpret=True))
     np.testing.assert_array_equal(got, np_adc_f64(lut, codes).astype(np.float32))
+
+
+# ------------------------------------- the scan stops at a list's end (PR 35)
+
+_SIZED = {(8, 256): 3, (64, 1024): 5, (16, 640): 7}  # (m, cap): a seed each
+
+
+def _sized_case(m, cap):
+    """Tables, codes and the kernel's scan of whole lists at (m, cap), made
+    once a geometry."""
+    if (m, cap) not in _sized_case.made:
+        rng = np.random.default_rng(_SIZED[m, cap])
+        lut = rng.standard_normal((7, m, 256)).astype(np.float32)
+        codes = rng.integers(0, 256, (7, cap, m)).astype(np.uint8)
+        whole = np.asarray(adc_pallas.adc_scan_pallas_planes(
+            lut, codes, _whole_lists(codes), interpret=True))
+        _sized_case.made[m, cap] = lut, codes, whole
+    return _sized_case.made[m, cap]
+
+
+_sized_case.made = {}
+
+
+def _list_sizes(which, cap):
+    if which == "mixed":
+        return np.array([0, 1, 127, 128, 129, cap - 1, cap], np.int32)
+    return np.full(7, {"cap-1": cap - 1, "cap": cap}.get(which, which), np.int32)
+
+
+@pytest.mark.parametrize("which", [0, 1, 127, 128, 129, "cap-1", "cap", "mixed"])
+@pytest.mark.parametrize("m,cap", sorted(_SIZED))
+def test_the_scan_stops_at_the_end_of_each_list(m, cap, which):
+    """A column under the pair's size holds what the scan of whole lists
+    holds, bit for bit, and pq.adc_scan's sum; so does the rest of a
+    sub-tile that holds a row; a sub-tile past the list is -inf, written by
+    the kernel; and ``scanned_columns`` counts what was computed."""
+    from distributed_faiss_tpu.ops import pq
+
+    lut, codes, whole = _sized_case(m, cap)
+    sizes = _list_sizes(which, cap)
+    got = np.asarray(adc_pallas.adc_scan_pallas_planes(lut, codes, sizes, interpret=True))
+    sub = adc_pallas._SUB_TILE
+    computed = np.arange(cap)[None, :] < (-(-sizes // sub) * sub)[:, None]
+    live = np.arange(cap)[None, :] < sizes[:, None]
+    np.testing.assert_array_equal(got[computed], whole[computed])
+    assert np.all(got[~computed] == -np.inf)
+    xla = np.asarray(pq.adc_scan(lut, codes))
+    np.testing.assert_allclose(got[live], xla[live], rtol=1e-4, atol=1e-4)
+    assert int(adc_pallas.scanned_columns(sizes, cap)) == computed.sum()
+
+
+def test_an_empty_list_splits_no_planes(monkeypatch):
+    """``sizes[p]`` 0 skips the pair's whole scan, the plane split included:
+    the split is traced inside the steps of the loop alone, and an empty
+    list's loop has no step."""
+    lut, codes, _ = _sized_case(8, 256)
+    split = []
+    orig = adc_pallas._bf16_planes
+    monkeypatch.setattr(adc_pallas, "_bf16_planes",
+                        lambda x: split.append(1) or orig(x))
+    adc_pallas.adc_scan_pallas_planes.clear_cache()
+    try:
+        import jax
+
+        S = jax.ShapeDtypeStruct
+        jaxpr = jax.make_jaxpr(lambda a, b, c: adc_pallas.adc_scan_pallas_planes(
+            a, b, c, interpret=True))(S(lut.shape, np.float32), S(codes.shape, np.uint8),
+                                      S((7,), np.int32))
+    finally:
+        adc_pallas.adc_scan_pallas_planes.clear_cache()
+    # traced once a variant of a step that makes the planes, and nowhere else
+    variants = adc_pallas._step_tiles(256)
+    assert split == [1] * variants
+    (call,) = [e for e in _walk_eqns(jaxpr.jaxpr) if e.primitive.name == "pallas_call"]
+    top = [e.primitive.name for e in call.params["jaxpr"].eqns]
+    assert top.count("while") == 1, "one loop of traced length over the live steps"
+    assert "cond" not in top and "dot_general" not in top, "work outside the steps"
+    assert "convert_element_type" not in top, "planes made outside the steps"
+    (loop,) = [e for e in call.params["jaxpr"].eqns if e.primitive.name == "while"]
+    steps = [e.primitive.name for e in _walk_eqns(loop.params["body_jaxpr"].jaxpr)]
+    assert steps.count("dot_general") == 8 * (variants + 1)  # m a variant
+    # the trip count is the live steps: 0 for an empty list
+    got = np.asarray(adc_pallas.adc_scan_pallas_planes(
+        lut, codes, np.zeros(7, np.int32), interpret=True))
+    assert np.all(got == -np.inf)
+
+
+def _lists_of_every_kind(rng, kind="single", **kw):
+    """An IVF-PQ index (capacity 512) whose eight lists are empty, hold one
+    row, end inside, at and just past a sub-tile, or are full, with rows
+    removed from three of them; and queries that probe all of them."""
+    from distributed_faiss_tpu.models.ivf import IVFPQIndex
+    from distributed_faiss_tpu.parallel.mesh import ShardedIVFPQIndex
+
+    d, nlist = 32, 8
+    cls = {"single": IVFPQIndex, "sharded": ShardedIVFPQIndex}[kind]
+    idx = cls(d, nlist, m=8, metric="l2", kmeans_iters=3, pq_iters=3, **kw)
+    idx.train((4.0 * rng.standard_normal((nlist, 1, d))
+               + rng.standard_normal((nlist, 250, d))).reshape(-1, d).astype(np.float32))
+    cents = np.asarray(idx.centroids)
+    want = [0, 1, 100, 128, 129, 300, 512, 40]
+    x = np.concatenate([cents[i] + 0.05 * rng.standard_normal((n, d))
+                        for i, n in enumerate(want)]).astype(np.float32)
+    idx.add(x)
+    idx.set_nprobe(nlist)
+    assert idx.lists.cap == 512
+    assert sorted(np.asarray(idx.lists.sizes).tolist()) == sorted(want)
+    idx.remove_rows(np.array([1, 5, 101, 102, 300, 600, 601, 1100]))
+    q = (cents[rng.integers(0, nlist, 12)]
+         + 0.5 * rng.standard_normal((12, d))).astype(np.float32)
+    return idx, q
+
+
+def _with_every_size_at_capacity(monkeypatch):
+    """The kernel told every list is full: the parent's scan (PR 31), which
+    computed all of the capacity. The callers' masks are untouched."""
+    import jax.numpy as jnp
+
+    orig = adc_pallas.adc_scan_pallas_planes
+    monkeypatch.setattr(
+        adc_pallas, "adc_scan_pallas_planes",
+        lambda lut, codes, sizes, **kw: orig(
+            lut, codes, jnp.full_like(sizes, codes.shape[1]), **kw))
+
+
+def test_the_search_is_the_whole_scans_bit_for_bit(rng, monkeypatch):
+    """``_ivf_pq_search`` with the kernel stopping at each list's end gives
+    the (vals, ids) it gives when the kernel scans every capacity, and its
+    third output is the columns the kernel computed, counted here from the
+    probes and the sizes."""
+    import jax.numpy as jnp
+
+    from distributed_faiss_tpu.models import ivf as ivfmod
+    from distributed_faiss_tpu.ops import distance
+
+    idx, q = _lists_of_every_kind(rng, use_pallas=True)
+    nprobe, cap = 4, idx.lists.cap
+    args = (idx.centroids, idx.codebooks, idx.lists.data, idx.lists.ids,
+            idx.lists.sizes, jnp.asarray(distance.pad_rows(q, 16)))
+    kw = dict(k=10, nprobe=nprobe, g=2, metric="l2")
+    vals, ids, cols = ivfmod._ivf_pq_search(*args, use_pallas=True, **kw)
+    _, probes = distance.segmented_argtopk(
+        distance.pairwise_scores(args[5], idx.centroids, "l2"), nprobe)
+    sizes = np.asarray(idx.lists.sizes)[np.asarray(probes)]
+    sub = adc_pallas._SUB_TILE
+    assert int(cols) == (-(-sizes // sub) * sub).sum() < sizes.size * cap
+    xv, xi, xcols = ivfmod._ivf_pq_search(*args, use_pallas=False, **kw)
+    assert int(xcols) == sizes.size * cap
+    np.testing.assert_array_equal(np.asarray(ids), np.asarray(xi))
+
+    ivfmod._ivf_pq_search.clear_cache()
+    _with_every_size_at_capacity(monkeypatch)
+    try:
+        wv, wi, _ = ivfmod._ivf_pq_search(*args, use_pallas=True, **kw)
+    finally:
+        ivfmod._ivf_pq_search.clear_cache()
+    np.testing.assert_array_equal(np.asarray(vals), np.asarray(wv))
+    np.testing.assert_array_equal(np.asarray(ids), np.asarray(wi))
+    assert (np.asarray(ids) >= 0).all()
+
+
+@pytest.mark.parametrize("forced,nq,skipped", [
+    (True, 12, True),    # one block on the kernel
+    (True, 37, True),    # five blocks in one lax.map launch: one count a block
+    (False, 12, False),  # the XLA arm computes every column: 0 skipped
+], ids=["fused", "fused-multiblock", "forced-off"])
+def test_scan_adc_cols_books_the_capacity_and_what_was_skipped(
+        rng, monkeypatch, forced, nq, skipped):
+    from distributed_faiss_tpu.models import base
+
+    monkeypatch.setattr(base, "MAX_QUERY_BLOCK", 8)
+    idx, q = _lists_of_every_kind(rng, use_pallas=forced)
+    q = np.concatenate([q] * 4)[:nq]
+    sink = tracing.LatencyStats()
+    with tracing.stage("engine.launch", sink=sink):
+        idx.search(q, 5)
+    rows = sink.summary()
+    # rows of the launch: the pow2 bucket of a batch's blocks of 8
+    padded = 8 * base._next_pow2(-(-nq // 8), 1)
+    total = padded * idx.nprobe * idx.lists.cap
+    assert rows["engine.scan"]["count"] == 1 == rows["engine.scan_adc_cols"]["count"]
+    assert rows["engine.scan_adc_cols"]["total_s"] == total
+    assert rows["engine.scan_adc_cols_skipped"]["count"] == 1
+    got = rows["engine.scan_adc_cols_skipped"]["total_s"]
+    if not skipped:
+        assert got == 0
+        return
+    # every query probes all eight lists (padding rows too: a zero query
+    # has probes of its own), so a row skips what the lists leave of 8 caps
+    sizes = np.asarray(idx.lists.sizes)
+    a_row = (idx.lists.cap - -(-sizes // 128) * 128).sum()
+    assert got == padded * a_row > 0.5 * total
 
 
 def _first_m_over_budget():
@@ -265,16 +466,30 @@ def _first_m_over_budget():
 
 
 def _tile_chosen(m, L):
-    """The candidate tile adc_scan_pallas_planes picks, read off its grid."""
+    """The candidate block adc_scan_pallas_planes picks, read off its grid
+    (pairs, blocks of a list), and the sub-tile and the widest step its loop
+    walks a block in, read off the variants' compares."""
     import jax
 
     S = jax.ShapeDtypeStruct
-    jaxpr = jax.make_jaxpr(lambda a, b: adc_pallas.adc_scan_pallas_planes(
-        a, b, interpret=True))(S((2, m, 256), np.float32), S((2, L, m), np.uint8))
+    jaxpr = jax.make_jaxpr(lambda a, b, c: adc_pallas.adc_scan_pallas_planes(
+        a, b, c, interpret=True))(S((2, m, 256), np.float32), S((2, L, m), np.uint8),
+                                  S((2,), np.int32))
     (call,) = [e for e in _walk_eqns(jaxpr.jaxpr) if e.primitive.name == "pallas_call"]
     pairs, tiles = call.params["grid_mapping"].grid
     assert pairs == 2 and L % tiles == 0
-    return L // tiles
+    assert call.params["grid_mapping"].num_index_operands == 1  # sizes, in SMEM
+    widths = collections.Counter(
+        e.outvars[0].aval.shape[1] for e in _walk_eqns(call.params["jaxpr"])
+        if e.primitive.name == "eq" and e.outvars[0].aval.ndim == 2
+        and e.outvars[0].aval.shape[0] == 256)
+    # a variant a count of live sub-tiles of a step, m compares each; the
+    # whole step twice, once on planes an earlier step made
+    assert widths.pop(max(widths)) == 2 * m and set(widths.values()) <= {m}
+    widths[adc_pallas._SUB_TILE * adc_pallas._step_tiles(L // tiles)] = m
+    sub = min(widths)
+    assert sorted(widths) == [n * sub for n in range(1, len(widths) + 1)]
+    return L // tiles, sub, max(widths)
 
 
 # planes_supported's truth table: what it admits the kernel has a tile for
@@ -298,9 +513,13 @@ def test_what_planes_supported_admits_the_kernel_has_a_tile_for(m, ksub, L, admi
     for m, L in [(1, 128), (1, 4096), (8, 640), (64, 128), (64, 1024),
                  (64, 4096), (64, 8192 + 128), (top, 128), (top, 4096)]:
         assert adc_pallas.planes_supported(m, 256, L), (m, L)
-        tile = _tile_chosen(m, L)
+        tile, sub, step = _tile_chosen(m, L)
         assert tile % 128 == 0 and tile <= adc_pallas._PLANES_TILE
         assert adc_pallas._planes_vmem_bytes(m, 256, tile) <= budget, (m, L, tile)
+        # the grain it stops at, and the widest it scans at once: whole
+        # sub-tiles, a whole number of steps a block
+        assert sub == adc_pallas._SUB_TILE == 128
+        assert step == sub * adc_pallas._step_tiles(tile) and tile % step == 0, (m, L)
 
 
 def _onehots_outside_the_kernel(eqns):
@@ -379,9 +598,9 @@ def test_a_chosen_index_runs_the_planes_kernel_and_matches_xla(rng, monkeypatch)
     calls = []
     orig = adc_pallas.adc_scan_pallas_planes
 
-    def spy(lut, codes, **kw):
+    def spy(lut, codes, sizes, **kw):
         calls.append(tuple(lut.shape))
-        return orig(lut, codes, interpret=True)
+        return orig(lut, codes, sizes, interpret=True)
 
     monkeypatch.setattr(adc_pallas, "on_tpu", lambda: True)
     monkeypatch.setattr(adc_pallas, "adc_scan_pallas_planes", spy)
@@ -452,10 +671,10 @@ def test_first_fused_scan_with_wrong_scores_demotes_and_serves_xla(rng, monkeypa
     want_d, want_i = xla_twin(idx).search(x[:20], 5)
     orig = adc_pallas.adc_scan_pallas_planes
 
-    def one_plane(lut, codes, **kw):
+    def one_plane(lut, codes, sizes, **kw):
         import jax.numpy as jnp
 
-        return orig(lut.astype(jnp.bfloat16).astype(jnp.float32), codes, **kw)
+        return orig(lut.astype(jnp.bfloat16).astype(jnp.float32), codes, sizes, **kw)
 
     monkeypatch.setattr(adc_pallas, "adc_scan_pallas_planes", one_plane)
     ivfmod._ivf_pq_search.clear_cache()

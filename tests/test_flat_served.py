@@ -174,6 +174,9 @@ def test_scan_rows_stands_at_zero_beside_engine_scan_where_nothing_books_it(tmp_
     assert stats["engine.scan"]["count"] >= 1
     assert stats["engine.scan_rows"]["count"] == 0
     assert stats["engine.scan_prefilter"]["count"] == 0
+    # nor the PQ scan's pair of rows (PR 35)
+    assert stats["engine.scan_adc_cols"]["count"] == 0
+    assert stats["engine.scan_adc_cols_skipped"]["count"] == 0
 
 
 def test_scan_prefilter_counts_the_scans_whose_top_k_chose_segments_first(tmp_path):

@@ -386,25 +386,30 @@ def _jaxpr_digest(fn, *args, **kwargs):
     return hashlib.sha256(text.encode()).hexdigest()[:16], len(text.splitlines())
 
 
-# (sha256 of the jaxpr's text, its lines), taken at the parent commit (6912cf4)
-# by this same code under jax 0.9.0; a jax upgrade re-takes them from a checkout
-# of the commit before the change under test
-@pytest.mark.parametrize("rows,want", [(64, ("85fb7cf137e1380e", 383)),
-                                       (256, ("2a51d36aaefd3fc1", 332))])
-def test_the_knnlm_cells_program_is_the_parents_text(rows, want):
-    """``_ivf_pq_search`` at the ``knnlm`` cells' geometry (d 768, m 64,
-    capacity 1024, k 10 x 8, nprobe 32 of 4096 lists, XLA arm so no kernel
-    source lines enter the text)."""
-    d, m, cap, k, nprobe, nlist = 768, 64, 1024, 80, 32, 4096
-    g = ivfmod.probe_group_size(
-        nprobe, ivfmod.pq_probe_payload_bytes(cap, m, nq_block=rows))
+# (sha256 of the jaxpr's text, its lines), taken at the parent commit (a741d52;
+# the ``_knn_scan`` pair at 6912cf4, unchanged since) by this same code under
+# jax 0.9.0; a jax upgrade re-takes them from a checkout of the commit before
+# the change under test
+@pytest.mark.parametrize("rows,want", [(64, ("a59c5fe916bcb6d1", 933)),
+                                       (256, ("7d38668f4fd15659", 933))])
+def test_the_ivfsq_cells_program_is_the_parents_text(rows, want):
+    """``_ivf_flat_search`` at ``ivfsq-batch``'s geometry (d 512, 1024
+    float16 lists of capacity 4096, stored norms, k 10, nprobe 64, the
+    list-major XLA arm under the index's own tiling). PR 35 changed the
+    ``knnlm`` cells' program by design (``_ivf_pq_search`` hands the kernel
+    the lists' sizes and returns a count), so the digests that stood here
+    for it at PR 31 went, case for case, to the cell whose program shares
+    ``models/ivf.py`` with it and must not have moved."""
+    d, cap, nlist, nprobe = 512, 4096, 1024, 64
+    tile, group = ivfmod.listmajor_tiling(rows, nprobe, nlist, cap, d, 2)
     S = jax.ShapeDtypeStruct
     got = _jaxpr_digest(
-        ivfmod._ivf_pq_search,
-        S((nlist, d), np.float32), S((m, 256, d // m), np.float32),
-        S((nlist, cap, m), np.uint8), S((nlist, cap), np.int32),
-        S((nlist,), np.int32), S((rows, d), np.float32),
-        k=k, nprobe=nprobe, g=g, metric="l2", use_pallas=False)
+        lambda cents, data, ids, sizes, q, norms, nvalid: ivfmod._ivf_flat_search(
+            cents, data, ids, sizes, q, k=10, nprobe=nprobe, g=1, metric="l2",
+            codec="f16", list_norms=norms, tile=tile, group=group, nvalid=nvalid),
+        S((nlist, d), np.float32), S((nlist, cap, d), np.float16),
+        S((nlist, cap), np.int32), S((nlist,), np.int32), S((rows, d), np.float32),
+        S((nlist, cap), np.float32), S((), np.int32))
     assert got == want
 
 

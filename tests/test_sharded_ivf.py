@@ -364,6 +364,49 @@ def test_sharded_pq_pallas_matches_xla(rng, routing, refine):
     np.testing.assert_allclose(Da, Db, rtol=1e-4, atol=1e-4)
 
 
+@pytest.mark.parametrize("routing", [False, True], ids=["masked", "routed"])
+def test_sharded_pq_kernel_answers_do_not_move_with_the_skip(rng, monkeypatch, routing):
+    """The kernel is handed each pair's list size, 0 for a pair the chip
+    does not own (masked) or a slot of the bucket's padding (routed), and
+    computes no further (PR 35): the answers are those of the kernel that
+    scans every capacity, bit for bit."""
+    import jax.numpy as jnp
+
+    from distributed_faiss_tpu.ops import adc_pallas
+    from distributed_faiss_tpu.parallel import mesh as meshmod
+
+    d, m = 32, 8
+    x = rng.standard_normal((1200, d)).astype(np.float32)
+    q = rng.standard_normal((5, d)).astype(np.float32)
+    idx = meshmod.ShardedIVFPQIndex(d, 8, m=m, metric="l2", probe_routing=routing,
+                                    refine_k_factor=8, use_pallas=True)
+    idx.train(x)
+    idx.add(x)
+    idx.set_nprobe(4)
+    idx.remove_rows(np.arange(0, 1200, 7))
+    assert idx._kernel_applies()
+    got_d, got_i = idx.search(q, 8)
+    assert idx._pallas_runtime_ok, "pallas path silently fell back"
+
+    program = (meshmod._sharded_ivf_pq_search_routed if routing
+               else meshmod._sharded_ivf_pq_search)
+    orig, seen = adc_pallas.adc_scan_pallas_planes, []
+
+    def whole(lut, codes, sizes, **kw):
+        seen.append(1)
+        return orig(lut, codes, jnp.full_like(sizes, codes.shape[1]), **kw)
+
+    program.clear_cache()
+    monkeypatch.setattr(adc_pallas, "adc_scan_pallas_planes", whole)
+    try:
+        want_d, want_i = idx.search(q, 8)
+    finally:
+        program.clear_cache()  # the stand-in is baked into the traces
+    assert seen and idx._pallas_runtime_ok
+    np.testing.assert_array_equal(got_i, want_i)
+    np.testing.assert_array_equal(got_d, want_d)
+
+
 def test_sharded_pq_refine_state_round_trip(rng, tmp_path):
     from distributed_faiss_tpu.models.factory import build_index, index_from_state_dict
     from distributed_faiss_tpu.parallel.mesh import ShardedIVFPQIndex

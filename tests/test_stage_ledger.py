@@ -144,7 +144,7 @@ def launch_loop_closure(rank):
         return {n: rows.get(n, {"total_s": 0.0})["total_s"]
                 for n in tracing.LAUNCH_LOOP}
 
-    serve = sched._serve
+    serve, enough, callers = sched._serve, threading.Event(), []
 
     def serve_and_mark(batch):  # runs on the batcher thread, at a window end
         serve(batch)
@@ -152,12 +152,20 @@ def launch_loop_closure(rank):
             marks.append(tracing.now())
             if len(marks) in (1, windows):
                 totals.append(booked())
+            if len(marks) == windows:
+                enough.set()
 
     sched._serve = serve_and_mark
     try:
-        for t in drive(rank, requests=30):
-            t.join()
+        # until the windows are made, not a number of requests: four callers
+        # that fall into step share every window, and 30 requests each then
+        # make 30 windows
+        callers = drive(rank, until=enough)
+        enough.wait(60)
     finally:
+        enough.set()
+        for t in callers:
+            t.join()
         sched._serve = serve
     assert len(marks) == windows, "the drive made too few windows"
     wall = marks[-1] - marks[0]
@@ -227,6 +235,27 @@ def test_scan_fused_stands_at_zero_beside_engine_scan_on_the_cpu(rank):
         assert rank["srv"].ping()["kernels"]["pallas_degraded"] == [INDEX_ID]
     finally:
         rank["idx"].tpu_index._pallas_runtime_ok = True
+
+
+def test_scan_adc_cols_count_the_capacity_and_nothing_skipped_on_the_cpu(rank):
+    """An IVF-PQ rank books the candidate columns of its pairs' capacity
+    once an ``engine.scan`` (``engine.scan_adc_cols``) and, beside it, those
+    the ADC scan left uncomputed: the XLA one-hot, which the CPU backend
+    keeps, computes them all, so the rank reads 0 skipped of what it
+    scanned, not a missing row."""
+    client, x = rank["client"], rank["x"]
+    index = rank["idx"].tpu_index
+    before = client.get_perf_stats()[0]["engine"][INDEX_ID]
+    client.search(x[:8], 5, INDEX_ID)
+    after = client.get_perf_stats()[0]["engine"][INDEX_ID]
+    scans = after["engine.scan"]["count"] - before["engine.scan"]["count"]
+    cols, skipped = after["engine.scan_adc_cols"], after["engine.scan_adc_cols_skipped"]
+    assert scans >= 1
+    assert cols["count"] - before["engine.scan_adc_cols"]["count"] == scans
+    assert skipped["count"] - before["engine.scan_adc_cols_skipped"]["count"] == scans
+    a_scan = 8 * min(index.nprobe, index.nlist) * index.lists.cap
+    assert cols["total_s"] - before["engine.scan_adc_cols"]["total_s"] == scans * a_scan
+    assert skipped["total_s"] == 0
 
 
 def test_every_span_of_a_sampled_request_hangs_under_client_search(rank):
