@@ -10,7 +10,9 @@ The configurations keep their rows in float16 and take distances in float32,
 so the step below is 8-bit rows. ``--rows int8`` puts the plain reference in
 the program's place over rows kept as per-dimension 8-bit codes (what SQ8
 keeps), at the cell's own size, and needs no chip; ``--rows float16`` is the
-same at the stated precision, for comparison. ``--index`` runs the program
+same at the stated precision, for comparison, and the control of a
+configuration that states float32 rows, where ``--rows float32`` is the
+comparison. ``--index`` runs the program
 itself on the chip with keys of the configuration's index replaced — for
 ``ivfsq`` the program's own ``IVF1024,SQ8`` path — through the whole harness.
 The benchmark's own runs never come here; PERF.md records the readings.
@@ -40,7 +42,11 @@ def as_float16(chunks):
     return [c.astype(np.float16).astype(np.float32) for c in chunks]
 
 
-KEPT_AS = {"int8": as_int8, "float16": as_float16}
+def as_float32(chunks):
+    return chunks
+
+
+KEPT_AS = {"int8": as_int8, "float16": as_float16, "float32": as_float32}
 
 
 def reference_in_the_programs_place(cell, seed, kept_as):

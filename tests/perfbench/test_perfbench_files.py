@@ -3,15 +3,13 @@ files are found by name, and a configuration, a mix, a per-layer metric and
 a cell are added by new files and entries alone."""
 
 import hashlib
-import json
 import os
 import re
-import shutil
 
 import pytest
 
-from perfbench import loader
-from pb_helpers import REPO
+from perfbench import load_gen, loader
+from pb_helpers import REPO, add_pretend_cell, copy_benchmark
 
 NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.\-]{0,63}$")
 UNIT = re.compile(r"^[A-Za-z0-9_/%.\-]{1,16}$")
@@ -94,9 +92,11 @@ def test_every_file_under_paths_is_named_from_the_allowed_characters():
 def test_a_cells_files_are_found_by_name(cell):
     c = loader.Cell(cell)
     assert c.config["name"] == c.entry["config"]
-    assert c.config["index"]["dim"] in (512, 768)  # published widths, never cut
+    dim = c.config["index"]["dim"]
+    assert isinstance(dim, int) and dim > 0
+    assert "dim" not in c.config["reduced"]  # a published width, never cut
     assert callable(c.reference.exact_topk) and callable(c.reference.exact_distances)
-    assert c.traffic["kind"] == "closed_loop"
+    assert c.traffic["kind"] in load_gen.KINDS
     assert "setup_s" in c.end_to_end() and len(c.end_to_end()) >= 2
     readers = c.layer_readers()
     assert readers and all(callable(r.read) for _, r in readers)
@@ -115,54 +115,41 @@ def digest(root):
 
 
 def test_a_later_pr_adds_a_cell_by_new_files_and_entries_alone(tmp_path):
-    root = str(tmp_path)
-    shutil.copy(os.path.join(REPO, "BENCHMARK.json"), root)
-    shutil.copytree(os.path.join(REPO, "perfbench"), os.path.join(root, "perfbench"),
-                    ignore=shutil.ignore_patterns("__pycache__"))
+    """With names no real cell would take, and at the shapes of the
+    deployments that wait: d=128, k=100, the dot metric."""
+    root = copy_benchmark(str(tmp_path))
     before = digest(root)
+    name = add_pretend_cell(root)
 
-    # what a later PR would add: a directory of its own with one of each
-    extra = os.path.join(root, "perfbench_more")
-    os.makedirs(os.path.join(extra, "configs", "flat"))
-    os.makedirs(os.path.join(extra, "traffic"))
-    os.makedirs(os.path.join(extra, "layer_metrics"))
-    with open(os.path.join(extra, "configs", "flat", "config.json"), "w") as f:
-        json.dump({"name": "flat", "ranks": 1, "rows": 10, "k": 10,
-                   "index": {"index_builder_type": "flat", "dim": 128}}, f)
-    with open(os.path.join(extra, "configs", "flat", "reference.py"), "w") as f:
-        f.write("def exact_topk(chunks, q, k):\n    return 'flat reference'\n")
-    with open(os.path.join(extra, "traffic", "batch1x256.json"), "w") as f:
-        json.dump({"kind": "closed_loop", "callers": 1, "rows_per_request": 256, "stagger_s": 0,
-                   "query_pool_rows": 1024}, f)
-    with open(os.path.join(extra, "layer_metrics", "wire.bytes.py"), "w") as f:
-        f.write("def read(obs):\n    return obs['wire_bytes']\n")
-    bench = loader.read_json(os.path.join(root, "BENCHMARK.json"))
-    bench["paths"].append("perfbench_more")
-    bench["configs"].append({"name": "flat", "source": "x", "reduced": [], "why": "y",
-                             "file": "perfbench_more/configs/flat/config.json"})
-    bench["workloads"].append({"name": "flat-batch", "config": "flat",
-                               "traffic": "batch1x256", "chips": 1, "why": "z"})
-    loader.by_name(bench["end_to_end"], "qps", "metric")["workloads"].append("flat-batch")
-    bench["per_layer"].append({"name": "wire.bytes", "unit": "bytes", "better": "lower",
-                               "source": "program_counter", "layer": "wire",
-                               "moves": "qps", "workloads": ["flat-batch"]})
-    with open(os.path.join(root, "BENCHMARK.json"), "w") as f:
-        json.dump(bench, f)
-
-    cell = loader.Cell("flat-batch", root)
-    assert cell.config["index"]["index_builder_type"] == "flat"
-    assert cell.reference.exact_topk(None, None, 10) == "flat reference"
+    cell = loader.Cell(name, root)
+    assert cell.config["index"] == {"index_builder_type": "flat", "dim": 128,
+                                    "metric": "dot", "buffer_bsz": 1000}
+    assert cell.config["k"] == 100
+    assert cell.config["guarantees"]["self_lookup_top1"] is False
+    assert cell.reference.__doc__.startswith("Plain reference for the tests' ``pretend``")
     assert cell.traffic["rows_per_request"] == 256
     assert cell.end_to_end() == ["qps", "setup_s"]
+    bench = loader.read_json(os.path.join(root, "BENCHMARK.json"))
+    assert bench["per_layer"][-1]["name"] == "pretend.bytes"  # appended at the end
     readers = {m["name"]: r for m, r in cell.layer_readers()}
-    assert "wire.bytes" in readers and readers["wire.bytes"].read({"wire_bytes": 7}) == 7
+    assert readers["pretend.bytes"].read({"wire_bytes": 7}) == 7
+    assert readers["pretend.bytes"].read({}) is None
     # metrics without a ``workloads`` key would be read here too; those with
     # one are read only where they say
     assert "client.fanout_skew_ms" not in readers
     # and an old cell does not see the new metric
     old = {m["name"] for m, _ in loader.Cell("knnlm-batch", root).layer_readers()}
-    assert "wire.bytes" not in old
-    assert digest(root) == before, "adding a cell edited a file that was there"
+    assert "pretend.bytes" not in old
+    after = digest(root)
+    assert {f: h for f, h in after.items() if f in before} == before, \
+        "adding a cell edited a file that was there"
+
+
+@pytest.mark.parametrize("taken", ["pretend", "pretend-cell", "pretend1x256",
+                                   "pretend.bytes"])
+def test_the_pretend_cells_names_are_no_real_ones(taken):
+    real = {name for _, name in all_names()}
+    assert taken not in real
 
 
 def test_an_unknown_name_says_what_exists():

@@ -57,4 +57,3 @@ def test_the_metric_is_listed_once_for_the_ivfsq_cell():
     assert entry[0] == {"name": NAME, "unit": "%", "better": "higher",
                         "source": "program_counter", "layer": "models and kernels",
                         "moves": "qps", "workloads": ["ivfsq-batch"]}
-    assert bench["per_layer"][-1]["name"] == NAME  # appended, nothing moved

@@ -20,7 +20,7 @@ from perfbench import loader, run, search_bytes
 from pb_helpers import REPO, tiny_root
 
 CELLS = [w["name"] for w in loader.read_json(os.path.join(REPO, "BENCHMARK.json"))["workloads"]]
-RESULT_KEYS = {"correct", "attempted", "failed", "metrics", "device"}
+RESULT_KEYS = {"correct", "attempted", "failed", "metrics", "device", "checks"}
 SEED = 2**31 + 4242  # the driver's seeds pass 32 signed bits
 
 
@@ -39,16 +39,38 @@ def rehearse(tmp_path, monkeypatch, capfd):
         rc = run.main(["--workload", cell, "--seed", str(SEED), "--seconds",
                        str(seconds), "--trace", str(trace)],
                       platform="cpu", device_prefix="/host:CPU", root=root)
-        out = capfd.readouterr().out
+        out, go.err = capfd.readouterr()
         lines = out.strip().splitlines()
         return rc, lines, (json.loads(lines[-1]) if rc == 0 else None)
 
+    go.root = root  # a test may add a cell of its own to the copy
     return go
 
 
-def metrics_of(cell, key):
-    bench = loader.read_json(os.path.join(REPO, "BENCHMARK.json"))
+def metrics_of(cell, key, root=REPO):
+    bench = loader.read_json(os.path.join(root, "BENCHMARK.json"))
     return {m["name"] for m in bench[key] if cell in m.get("workloads", [cell])}
+
+
+def expected_checks(config):
+    """The numbers a run compares, in the order it prints them: by the
+    configuration's own k, and the self lookup only where it is promised."""
+    promised = config["guarantees"].get("self_lookup_top1")
+    return (["ntotal_gap"] + (["self_lookup_misses"] if promised else [])
+            + ["failed_requests", f"recall_at_{config['k']}", "distance_gap_rel"])
+
+
+def assert_the_checks_are_printed_three_times(config, lines, result, err):
+    """As they come on standard output, as the last lines of standard error,
+    and last in the result's line, each number beside its limit."""
+    names = expected_checks(config)
+    printed = [ln for ln in lines if ln.startswith("check ") and "limit" in ln]
+    assert [ln.split(":")[0] for ln in printed] == ["check " + n for n in names]
+    assert err.strip().splitlines()[-len(names):] == printed
+    assert list(result)[-1] == "checks" and list(result["checks"]) == names
+    for row in result["checks"].values():
+        assert set(row) == {"value", "limit", "ok"}
+    assert result["correct"] == all(row["ok"] for row in result["checks"].values())
 
 
 @pytest.mark.parametrize("cell", CELLS)
@@ -65,10 +87,8 @@ def test_rehearse_a_cell_end_to_end_on_the_cpu(cell, rehearse):
     assert result["device"]["platform"] == "cpu"  # and says so: never a speed
     assert set(result["device"]) == {"platform", "kind", "count", "memory_peak_bytes"}
     assert sum(1 for ln in lines if ln.startswith("rank ") and "platform=cpu" in ln) == ranks
-    checks = [ln.split(":")[0] for ln in lines if ln.startswith("check ") and "limit" in ln]
-    assert checks == ["check ntotal_gap", "check self_lookup_misses",
-                      "check failed_requests", "check recall_at_10",
-                      "check distance_gap_rel"]
+    assert_the_checks_are_printed_three_times(loader.Cell(cell).config, lines, result,
+                                              rehearse.err)
     assert any(ln.startswith("bytes_in_use per rank after set-up") for ln in lines)
 
 

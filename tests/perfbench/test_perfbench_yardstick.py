@@ -11,16 +11,25 @@ import pytest
 from perfbench import control as control_rows
 from perfbench import (corpus, correctness, load_gen, loader, search_bytes, stats,
                        trace_reduce)
-from pb_helpers import REPO
+from pb_helpers import PRETEND, REPO
 
-CONFIGS = sorted(os.listdir(os.path.join(REPO, "perfbench", "configs")))
+# every configuration's directory: the benchmark's, and the tests' pretend
+# one, which is the only one on the dot metric so far
+CONFIG_DIRS = {name: os.path.join(REPO, "perfbench", "configs", name)
+               for name in sorted(os.listdir(os.path.join(REPO, "perfbench", "configs")))}
+CONFIG_DIRS["pretend"] = os.path.join(PRETEND, "configs", "pretend")
+CONFIGS = sorted(CONFIG_DIRS)
 
 
-def smoke_exact_topk(x, q, k):
+def smoke_exact_topk(x, q, k, metric):
     """``chip_smoke.exact_topk``'s scan, the one PR 21 proved on the chip,
-    written out here so the test imports no script."""
-    d2 = (q * q).sum(1)[:, None] - 2.0 * (q @ x.T) + (x * x).sum(1)[None, :]
-    return np.argsort(d2, axis=1, kind="stable")[:, :k]
+    written out here so the test imports no script: the nearest by squared
+    L2, or the largest inner products."""
+    if metric == "l2":
+        score = (q * q).sum(1)[:, None] - 2.0 * (q @ x.T) + (x * x).sum(1)[None, :]
+    else:
+        score = -(q @ x.T)
+    return np.argsort(score, axis=1, kind="stable")[:, :k]
 
 
 def tiny_corpus(seed=5, n=3000, d=32, nq=40):
@@ -30,8 +39,7 @@ def tiny_corpus(seed=5, n=3000, d=32, nq=40):
 
 
 def reference_of(name):
-    return loader.load_module(
-        os.path.join(REPO, "perfbench", "configs", name, "reference.py"))
+    return loader.load_module(os.path.join(CONFIG_DIRS[name], "reference.py"))
 
 
 # ------------------------------------------------------------------ corpus
@@ -54,21 +62,26 @@ def test_the_same_seed_gives_the_same_rows_and_another_seed_others():
 
 @pytest.mark.parametrize("name", CONFIGS)
 def test_each_reference_agrees_with_the_smokes_exact_scan(name):
+    """By the configuration's own metric. A score is what the client serves:
+    a squared distance, or an inner product negated, so the best comes first
+    and the scores ascend under either."""
+    metric = loader.read_json(os.path.join(CONFIG_DIRS[name], "config.json"))["index"]["metric"]
     ref = reference_of(name)
     _, chunks, q = tiny_corpus()
     x = np.concatenate(chunks)
     dist, ids = ref.exact_topk(chunks, q, 10)
     assert ids.dtype == np.int64 and ids.shape == (40, 10)
-    assert np.array_equal(ids, smoke_exact_topk(x, q, 10))
+    assert np.array_equal(ids, smoke_exact_topk(x, q, 10, metric))
     exact = ref.exact_distances(x[ids], q)
     assert exact.dtype == np.float64
     assert np.allclose(dist, exact, rtol=1e-4)
-    assert (np.diff(exact, axis=1) >= -1e-6).all()  # nearest first
+    assert (np.diff(exact, axis=1) >= -1e-6).all()  # best first
+    assert (exact >= 0).all() if metric == "l2" else (exact < 0).any()
 
 
 @pytest.mark.parametrize("name", CONFIGS)
 def test_a_reference_imports_nothing_of_the_program(name):
-    with open(os.path.join(REPO, "perfbench", "configs", name, "reference.py")) as f:
+    with open(os.path.join(CONFIG_DIRS[name], "reference.py")) as f:
         imports = [ln for ln in f if ln.startswith(("import ", "from "))]
     assert imports == ["import numpy as np\n"]
 
@@ -91,7 +104,7 @@ def served_by(chunks_as_stored, pool, rows_per_request, requests, k=10):
     return out
 
 
-CONFIG = {"k": 10, "guarantees": {"recall_at_k_min": 0.95},
+CONFIG = {"k": 10, "index": {"metric": "l2"}, "guarantees": {"recall_at_k_min": 0.95},
           "limits": {"sample_rows": 64, "distance_gap_rel_max": 1e-2}}
 
 
@@ -167,6 +180,10 @@ def test_warm_up_covers_every_window_the_mix_can_merge():
     online = {"kind": "closed_loop", "callers": 16, "rows_per_request": 1}
     assert load_gen.request_sizes(batch, 256) == [64, 128, 192, 256]
     assert load_gen.request_sizes(online, 256) == list(range(1, 17))
+    tier = loader.read_json(os.path.join(REPO, "perfbench", "traffic", "batch16x64.json"))
+    assert (tier["callers"], tier["rows_per_request"]) == (16, 64)
+    # sixteen callers fill a window four at a time: batch4x64's shapes, no more
+    assert load_gen.request_sizes(tier, 256) == [64, 128, 192, 256]
     wide = {"kind": "closed_loop", "callers": 3, "rows_per_request": 300}
     assert load_gen.request_sizes(wide, 256) == [300]
     with pytest.raises(ValueError, match="unknown traffic kind"):
