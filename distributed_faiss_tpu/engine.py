@@ -30,6 +30,7 @@ from typing import List, Optional, Tuple, Union
 
 import numpy as np
 
+from distributed_faiss_tpu.models.base import SearchHandle
 from distributed_faiss_tpu.models.factory import (
     build_index,
     index_from_state_dict,
@@ -46,6 +47,7 @@ from distributed_faiss_tpu.utils import (
     tracing,
     xfercheck,
 )
+from distributed_faiss_tpu.utils.atomics import AtomicCounters
 from distributed_faiss_tpu.utils.batching import SearchBatcher
 from distributed_faiss_tpu.utils.config import (
     IndexCfg,
@@ -298,15 +300,21 @@ class Index:
         # lock wait, launch, join, train, buffer drain; the model's feed /
         # scan / refine_fetch stages inherit it through the context)
         self.perf = LatencyStats()
+        # merged windows launched and collected (monotonic; a leaf lock of
+        # their own, so a collect never waits behind an add for index_lock):
+        # a launch that finds fewer collected than launched before it books
+        # engine.launch_overlapped
+        self._windows = AtomicCounters(("launched", "collected"))
         # newest committed snapshot generation in this shard's storage dir
         # (0 = nothing committed yet; from_storage_dir seeds it on restore)
         self._generation = 0
 
         # ---- mutation subsystem (mutation/) ----
         # positional dead-row set + id record; guarded by index_lock (the
-        # same lock the device mask scatter and every device search hold,
-        # which is what makes a scheduler-merged window see one consistent
-        # tombstone snapshot — never a torn mask mid-window)
+        # same lock the device mask scatter holds and every device search
+        # is launched under, which is what makes a scheduler-merged window
+        # see one consistent tombstone snapshot — never a torn mask
+        # mid-window)
         self.tombstones = TombstoneSet()
         self._mutation_counters = {
             "compactions": 0, "compactions_aborted": 0, "load_fallbacks": 0,
@@ -635,8 +643,9 @@ class Index:
         Unversioned calls keep the exact legacy delete-wins semantics.
 
         Indexed rows are masked on device immediately (one scatter under
-        ``index_lock`` — the same lock every device search holds, so a
-        merged window is entirely pre- or post-delete, never torn).
+        ``index_lock`` — the same lock every device search is launched
+        under, all its programs in one hold and on the operands it found,
+        so a merged window is entirely pre- or post-delete, never torn).
         Buffer-aware: rows still in the add buffer keep their positional
         slot and are masked the moment their drain chunk lands
         (_add_buffer_to_idx), so an id deleted mid-ingest never serves.
@@ -1505,23 +1514,33 @@ class Index:
 
     # ------------------------------------------------------------------ query
 
-    # graftlint: ok(blocking-under-lock): the designed locked launch — one in-flight device search per index IS the serialization contract
-    def _device_search(self, query_batch: np.ndarray, top_k: int):
-        """The locked device launch behind the batcher: one in-flight
-        search per index (reference rationale at index.py:246-252; the
-        lock also serializes against add/growth).
+    # graftlint: ok(blocking-under-lock): the designed locked launch — a search is dispatched under index_lock so that it reads one state of the index; an index without a two-phase form runs its whole search here
+    def _launch_device_search(self, query_batch: np.ndarray, top_k: int):
+        """The locked device launch behind the batchers, first half: state
+        check and launch under ``index_lock``, the fetch left to the
+        returned ``collect()``. The lock's job — no search reads the
+        index's Python-side arrays while add, growth or compaction replaces
+        them (reference rationale at index.py:246-252) — is done once the
+        programs are dispatched: they hold their operands, and a later
+        program that donates one of them (``models/base._write_rows``) runs
+        behind them on the device. So the scheduler may launch the next
+        window while this one is uncollected; ``engine.launch_overlapped``
+        counts the launches that found one so.
 
-        Routes through the model's already-batched entry
-        (``TpuIndex.search_batched``): for mesh-backed indexes that is the
-        one-pjit-launch path — the whole merged window reaches the chips as
-        a single device program with an on-mesh top-k reduce, and results
-        leave the device exactly once (parallel/mesh.py). Models exposing a
-        ``launches`` dispatch counter get it diffed around the call into
+        Routes through the model's two-phase entry
+        (``TpuIndex.launch_search``): the flat and IVF indexes dispatch and
+        return; a mesh-backed index, or HNSW, runs its already-batched
+        entry to the end here (the one-pjit-launch path — the whole merged
+        window reaches the chips as a single device program with an on-mesh
+        top-k reduce, and results leave the device exactly once:
+        parallel/mesh.py) and hands back a finished handle. Models exposing a
+        ``launches`` dispatch counter get it diffed around the launch into
         ``device_launches`` (dispatches this window took — 1.0 on the mesh
         path) and ``rows_per_launch`` (merged-window occupancy per
         dispatch), both served through ``perf_stats``. Stages
-        (utils/tracing.stage): ``engine.lock_wait``, then ``engine.launch``
-        (counter ``device_search_s``) from launch to fetch."""
+        (utils/tracing): ``engine.lock_wait``, then ``engine.launch``
+        (counter ``device_search_s``), a ``handover`` from the launch's
+        start to the fetch's end."""
         with tracing.stage("engine.lock_wait", sink=self.perf) as wait, \
                 self.index_lock:
             wait.done()
@@ -1532,13 +1551,23 @@ class Index:
             rows = int(query_batch.shape[0])
             # launch to fetch: the model's engine.feed / engine.scan /
             # engine.refine_fetch stages nest inside and add up to it
-            with tracing.stage("engine.launch", sink=self.perf,
-                               counter="device_search_s", rows=rows) as launch:
-                out = self.tpu_index.search_batched(query_batch, top_k)
+            launch = tracing.handover("engine.launch", sink=self.perf,
+                                      counter="device_search_s", rows=rows)
+            with launch:
+                pending = self.tpu_index.launch_search(query_batch, top_k)
                 launches = None
                 if launches0 is not None:
                     launches = launch.extra["launches"] = int(
                         self.tpu_index.launches - launches0)
+            if self._windows.inc("launched") - 1 > self._windows["collected"]:
+                self.perf.record("engine.launch_overlapped", 1.0)
+
+        def collect():
+            try:
+                with launch.last():
+                    out = pending.collect()
+            finally:
+                self._windows.inc("collected")
             self.perf.record("device_search_rows", float(rows))
             if launches is not None:
                 self.perf.record("device_launches", float(launches))
@@ -1546,16 +1575,25 @@ class Index:
                     self.perf.record("rows_per_launch", rows / launches)
             return out
 
-    def _run_and_join(self, run, return_embeddings: bool):
-        """Launch + metadata join under the layout-epoch seqlock.
+        return collect
 
-        ``run()`` returns (scores, indexes, embs_arr|None). A compaction
-        swap (or drop/recreate) between the device launch and the join
-        would pair OLD positional ids with the NEW metadata layout —
-        silent wrong-metadata results. The epoch (bumped under both locks
-        by every layout replacement) detects the overlap and relaunches
-        on the new layout instead."""
-        for _ in range(8):
+    def _device_search(self, query_batch: np.ndarray, top_k: int):
+        """Launch and collect in one call (the in-process batcher's entry)."""
+        return self._launch_device_search(query_batch, top_k)()
+
+    def _launch_and_join(self, launch, return_embeddings: bool) -> SearchHandle:
+        """Launch now; the handle's ``collect()`` fetches and joins the
+        metadata, under the layout-epoch seqlock.
+
+        ``launch()`` starts a search and returns its fetch, ``fetch() ->
+        (scores, indexes, embs_arr|None)``. A compaction swap (or
+        drop/recreate) between the device launch and the join would pair
+        OLD positional ids with the NEW metadata layout — silent
+        wrong-metadata results. The epoch (bumped under both locks by
+        every layout replacement, read here before the launch) detects the
+        overlap and the collect relaunches on the new layout instead."""
+
+        def launch_once():
             with self.buffer_lock:
                 epoch0 = self._meta_epoch
             # DFT_XFERCHECK=1: the launch-to-fetch span is a guarded
@@ -1563,16 +1601,31 @@ class Index:
             # through explicit feeds (device_put) and the explicit()
             # fetch scopes down in the blocked-search drivers
             with xfercheck.guarded("engine launch-to-fetch span"):
-                scores, indexes, embs_arr = run()
-            with tracing.stage("engine.join", sink=self.perf):
-                with self.buffer_lock:
-                    if self._meta_epoch != epoch0:
-                        continue  # layout swapped mid-flight: retry on the new one
-                    meta_arr, meta_n = self.id_to_metadata.snapshot()
-                return self._join_results(scores, indexes, embs_arr,
-                                          return_embeddings, meta_arr, meta_n)
-        raise RuntimeError(
-            "metadata layout kept changing during search (compaction storm)")
+                return epoch0, launch()
+
+        first = launch_once()
+
+        def collect():
+            for attempt in range(8):
+                epoch0, fetch = launch_once() if attempt else first
+                with xfercheck.guarded("engine launch-to-fetch span"):
+                    scores, indexes, embs_arr = fetch()
+                with tracing.stage("engine.join", sink=self.perf):
+                    with self.buffer_lock:
+                        if self._meta_epoch != epoch0:
+                            continue  # layout swapped mid-flight: retry on the new one
+                        meta_arr, meta_n = self.id_to_metadata.snapshot()
+                    return self._join_results(scores, indexes, embs_arr,
+                                              return_embeddings, meta_arr, meta_n)
+            raise RuntimeError(
+                "metadata layout kept changing during search (compaction storm)")
+
+        return SearchHandle(collect)
+
+    def _run_and_join(self, run, return_embeddings: bool):
+        """``_launch_and_join`` for a search with no launch half: ``run()``
+        is the whole search, made at the collect."""
+        return self._launch_and_join(lambda: run, return_embeddings).collect()
 
     def search(
         self, query_batch: np.ndarray, top_k: int = 100, return_embeddings: bool = False
@@ -1586,27 +1639,37 @@ class Index:
             run = lambda: self._search_reconstruct(query_batch, top_k)
         return self._run_and_join(run, return_embeddings)
 
+    def launch_batched(
+        self, query_batch: np.ndarray, top_k: int = 100, return_embeddings: bool = False
+    ) -> SearchHandle:
+        """The already-batched search entry for the serving scheduler
+        (serving/scheduler.py), in two halves: the locked device launch
+        now, the fetch and the metadata join at the handle's ``collect()``,
+        which returns what ``search`` returns — same launch, same join —
+        but WITHOUT the in-process SearchBatcher in front. The scheduler has
+        already coalesced concurrent callers into ``query_batch``, and it
+        keeps two windows in flight: the next one is launched, behind this
+        one on the device, before this one is collected. What the index
+        offers decides how much of a window the launch is
+        (``_launch_device_search``); ``return_embeddings`` stays atomic
+        under ``index_lock`` (``_search_reconstruct``), so its launch is the
+        whole search and its handle comes back finished."""
+        query_batch = np.asarray(query_batch, np.float32)
+        if not return_embeddings:
+            def launch():
+                fetch = self._launch_device_search(query_batch, top_k)
+                return lambda: fetch() + (None,)
+        else:
+            def launch():
+                found = self._search_reconstruct(query_batch, top_k)
+                return lambda: found
+        return self._launch_and_join(launch, return_embeddings)
+
     def search_batched(
         self, query_batch: np.ndarray, top_k: int = 100, return_embeddings: bool = False
     ) -> Tuple[np.ndarray, List[List[object]], Optional[List[List[np.ndarray]]]]:
-        """The already-batched search entry for the serving scheduler
-        (serving/scheduler.py): identical results to ``search`` — same
-        locked device launch, same metadata join — but WITHOUT the
-        in-process SearchBatcher in front. The scheduler has already
-        coalesced concurrent callers into ``query_batch``, and it calls
-        from a single batcher thread, so routing through the natural
-        batcher again would only add leader/follower bookkeeping to every
-        launch. For a mesh-backed index the locked launch is the
-        one-pjit-launch path (``TpuIndex.search_batched``): the merged
-        window crosses to the chips as a single device program and the
-        engine's ``device_launches``/``rows_per_launch`` perf rows record
-        the contract (see ``_device_search``)."""
-        query_batch = np.asarray(query_batch, np.float32)
-        if not return_embeddings:
-            run = lambda: self._device_search(query_batch, top_k) + (None,)
-        else:
-            run = lambda: self._search_reconstruct(query_batch, top_k)
-        return self._run_and_join(run, return_embeddings)
+        """``launch_batched`` and its collect in one call."""
+        return self.launch_batched(query_batch, top_k, return_embeddings).collect()
 
     # ------------------------------------------------- generation-pinned reads
 
@@ -1751,8 +1814,10 @@ class Index:
         return scores, results_meta, embs
 
     def perf_stats(self, raw: bool = False) -> dict:
-        """Per-index stage and launch summary: ``device_search_s`` (wall
-        time of each locked launch), ``device_search_rows`` (rows per
+        """Per-index stage and launch summary: ``device_search_s`` (each
+        merged window's launch, its start to its fetch's end: with two
+        windows in flight, the wait on the device behind the one ahead
+        too), ``device_search_rows`` (rows per
         merged window) and the stages that make the launch up
         (``engine.feed``, ``engine.scan``, ``engine.refine_fetch``) or
         surround it (``engine.lock_wait``, ``engine.join``);
@@ -1789,8 +1854,14 @@ class Index:
         none; ``IVFPQIndex._book_adc_cols`` books both);
         ``engine.store_grow`` is one record a reallocation of a
         ``DeviceVectorStore`` (models/base.py), allocation to the end of
-        the copy."""
+        the copy. ``engine.launch_overlapped`` (a count row, at zero beside
+        ``device_search_s`` until booked) is one record a merged window
+        that was launched while an earlier window of this index was not yet
+        collected: over ``device_search_s``'s count, the share of windows
+        the scheduler put on the device behind another."""
         out = self.perf.summary(raw=raw)
+        if "device_search_s" in out:
+            out.setdefault("engine.launch_overlapped", tracing.zero_row())
         if "engine.scan" in out:
             for name in ("engine.scan_fused", "engine.scan_rows",
                          "engine.scan_prefilter", "engine.scan_listmajor",

@@ -366,6 +366,16 @@ class TpuIndex:
         launches-per-window (``Index.perf``)."""
         return self.search(q, k)
 
+    def launch_search(self, q: np.ndarray, k: int) -> "SearchHandle":
+        """``search_batched`` in two halves: launch now, ``collect()`` the
+        result later, so that the engine's lock covers the launch alone and
+        the scheduler can launch the next window behind this one. The
+        default runs the whole search here and hands back a finished
+        handle; an index whose scan can put off its wait overrides it (the
+        flat and the IVF indexes; a pre-transform wrapper asks its inner
+        index) and serves ``search`` as ``launch_search(...).collect()``."""
+        return finished(self.search_batched(q, k))
+
     def reconstruct_batch(self, ids: np.ndarray) -> np.ndarray:
         """Return (approximate) stored vectors for ids (FAISS
         search_and_reconstruct parity, reference index.py:255-257)."""
@@ -461,13 +471,102 @@ def query_blocks(q: np.ndarray, block: int = 256):
         yield s, n, chunk
 
 
-def blocked_search(q: np.ndarray, k: int, metric: str, fn, block: int = 256,
-                   fused_fn=None, refine_fn=None, with_counts: bool = False):
-    """THE blocked search driver (shared by the IVF family and the mesh
-    indexes — one implementation so the bucketing/padding policy cannot
-    drift between them).
+class SearchHandle:
+    """A search that was launched: ``collect()``, called once, waits for it
+    and returns what ``search`` returns. The two halves of every search
+    (``TpuIndex.launch_search``): the launch is what must see one state of
+    the index (the engine holds ``index_lock`` around it), the collect only
+    waits for programs that hold their operands."""
 
-    Default: one device launch per query block (``fn`` over a padded
+    __slots__ = ("collect",)
+
+    def __init__(self, collect):
+        self.collect = collect
+
+
+def finished(result) -> SearchHandle:
+    """The handle of a search that ran to its end in the launch."""
+    return SearchHandle(lambda: result)
+
+
+class Dispatched:
+    """What a scan callable hands ``blocked_search`` to put its wait off to
+    the collect: ``out``, the program's outputs as dispatched, ``(vals, ids,
+    ...)`` (the rerank is dispatched on them), and ``wait()``, the ``(vals,
+    ids)`` to serve once the device has them — through ``settled(out)``
+    where the caller has rows to count or further outputs to take off.
+    ``wait`` may serve other arrays than ``out`` (models/ivf.py's
+    ``GuardedScan`` serves the XLA oracle's where the kernel aborted); the
+    collect then dispatches the rerank again, on those."""
+
+    def __init__(self, out, settled=None):
+        self.out = out
+        self._settled = settled
+
+    def _ready(self):
+        return jax.block_until_ready(self.out)
+
+    def wait(self):
+        out = self._ready()
+        return out if self._settled is None else self._settled(out)
+
+
+def _start_fetch(arrays) -> None:
+    """Start the copies to the host of a window's last outputs, behind the
+    programs that make them: the collect's fetch then finds them landed
+    (a device-to-host read started after the fact is 0.4 ms of latency on
+    a v5e even for ready bytes: PERF.md, PR 35)."""
+    with xfercheck.explicit("blocked_search result fetch, started with the launch"):
+        for a in arrays:
+            if hasattr(a, "copy_to_host_async"):
+                a.copy_to_host_async()
+
+
+class _Unit:
+    """One dispatched scan of a window (a block, or the fused stack): what
+    the collect needs to wait for it, rerank again if the wait served other
+    arrays, and place its rows."""
+
+    __slots__ = ("rows", "chunk", "dispatched", "stage", "refine_fn", "ids", "out")
+
+    def __init__(self, fn, chunk, counts, refine_fn, rows: int):
+        self.rows, self.chunk, self.refine_fn = rows, chunk, refine_fn
+        self.stage = tracing.handover("engine.scan")
+        with self.stage:
+            out = fn(chunk, *counts)
+            self.dispatched = out if isinstance(out, Dispatched) else Dispatched(out)
+            vals, self.ids = self.dispatched.out[:2]
+            self.out = self._reranked(vals, self.ids)
+            _start_fetch(self.out)
+
+    def _reranked(self, vals, ids):
+        """The unit's last outputs: the scan's, or the exact rerank's
+        (dispatched here) on the scan's candidates."""
+        return (vals, ids) if self.refine_fn is None else self.refine_fn(self.chunk, ids)
+
+    def collect(self):
+        """(scores, ids) of the unit's real rows, on the host, ops-convention."""
+        with self.stage.last():
+            vals, ids = self.dispatched.wait()
+        with tracing.stage("engine.refine_fetch", sink=self.stage.sink):
+            if ids is not self.ids:  # the wait served other arrays
+                self.out = self._reranked(vals, ids)
+            with xfercheck.explicit("blocked_search result fetch"):
+                vals, ids = (np.asarray(a) for a in self.out)
+            return (vals.reshape(-1, vals.shape[-1])[: self.rows],
+                    ids.reshape(-1, ids.shape[-1])[: self.rows])
+
+
+def launch_blocked_search(q: np.ndarray, k: int, metric: str, fn, block: int = 256,
+                          fused_fn=None, refine_fn=None,
+                          with_counts: bool = False) -> SearchHandle:
+    """THE blocked search driver (shared by the IVF family, the flat index
+    and the mesh indexes — one implementation so the bucketing/padding
+    policy cannot drift between them), in two halves: this call is the
+    launch, the returned handle's ``collect()`` the rest. ``blocked_search``
+    is the two in one call.
+
+    The launch: one device dispatch per query block (``fn`` over a padded
     (bucket, d) block). When the batch spans multiple blocks and the
     caller supplies ``fused_fn`` (a callable over (nblocks, block, d)
     stacked queries), the whole batch runs in ONE launch, saving
@@ -478,7 +577,18 @@ def blocked_search(q: np.ndarray, k: int, metric: str, fn, block: int = 256,
     variable-batch serving workload compiles O(log max_batch) fused
     variants (each sharded variant is a multi-second compile) instead of
     one per distinct batch size — offline/bench callers with a stable
-    batch size still compile once.
+    batch size still compile once. Where the index refines outside the scan
+    program (``refine_fn(block, ids)``, the exact rerank) that program is
+    dispatched right behind the scan, on the scan's outputs as dispatched,
+    and the last outputs' copies to the host are started: nothing here
+    waits for the device, so the rerank follows the scan on the chip
+    without the host between them, and a caller may launch the next window
+    behind this one before collecting it.
+
+    The collect: per dispatched scan, the wait for it (``Dispatched.wait``:
+    where a scan callable handed back plain outputs, or had waited itself
+    as the mesh indexes' do, a plain ``block_until_ready``), the fetch and
+    ``finalize_results``.
 
     Memory cliff (ADVICE r4): the pow2 bucket can pad the fused batch up
     to ~2x (33 blocks -> 64), doubling the stacked (nblocks, block, d)
@@ -491,15 +601,14 @@ def blocked_search(q: np.ndarray, k: int, metric: str, fn, block: int = 256,
     block to avoid even that.
 
     Stage ledger (utils/tracing.stage; counters land in the caller's
-    sink, the engine's): a block is ``engine.feed`` (slice, pad,
-    ``device_put``), ``engine.scan`` (``fn`` / ``fused_fn``: dispatch of
-    the scan program up to wherever the callable itself waits — the IVF
-    family's ``pallas_guarded`` and a flat index's callables block until
-    the scan is done) and
-    ``engine.refine_fetch`` (``refine_fn(block, ids)``, the exact rerank's
-    dispatch where the index refines outside the scan program, then the
-    result fetch and ``finalize_results``). The boundaries sit where the
-    host already waits: no sync is added for them.
+    sink, the engine's): a dispatched scan is ``engine.feed`` (slice, pad,
+    ``device_put``), ``engine.scan`` (a ``tracing.handover``: from the
+    dispatch of the scan program to the moment the collect finds its
+    outputs ready, so with a window ahead of this one on the chip it holds
+    the wait behind that window too) and ``engine.refine_fetch`` (from
+    there to the end of the fetch and ``finalize_results``: the rerank's
+    device time where there is one, and the copy). No sync is added for
+    the boundaries.
 
     ``with_counts``: the scan callables also get the real rows of what
     they are handed, as a device int32 — ``fn(chunk, n)`` a scalar,
@@ -514,6 +623,7 @@ def blocked_search(q: np.ndarray, k: int, metric: str, fn, block: int = 256,
     # host<->device copies jnp.asarray/np.asarray would otherwise hide
     # at the jit boundary. (Mesh callers re-place the block onto their
     # sharding inside fn/fused_fn — also explicitly.)
+    units = []
     if fused_fn is not None and nq > block:
         with tracing.stage("engine.feed"):
             nblocks = _next_pow2(-(-nq // block), 1)
@@ -521,26 +631,27 @@ def blocked_search(q: np.ndarray, k: int, metric: str, fn, block: int = 256,
             q3 = jax.device_put(qp.reshape(nblocks, block, -1))
             rows = np.clip(nq - block * np.arange(nblocks), 0, block)
             counts = (jax.device_put(rows.astype(np.int32)),) if with_counts else ()
-        with tracing.stage("engine.scan"):
-            vals, ids = fused_fn(q3, *counts)
-        with tracing.stage("engine.refine_fetch"):
-            with xfercheck.explicit("blocked_search fused result fetch"):
-                out_s = np.asarray(vals).reshape(nblocks * block, -1)[:nq]
-                out_i = np.asarray(ids).reshape(nblocks * block, -1)[:nq]
-            return finalize_results(out_s, out_i, metric)
-    out_s = np.empty((nq, k), np.float32)
-    out_i = np.empty((nq, k), np.int64)
-    for s in range(0, nq, block):
-        with tracing.stage("engine.feed"):
-            n, chunk = _padded_block(q, s, block)
-            chunk = jax.device_put(chunk)
-            counts = (jax.device_put(np.int32(n)),) if with_counts else ()
-        with tracing.stage("engine.scan"):
-            vals, ids = fn(chunk, *counts)
-        with tracing.stage("engine.refine_fetch"):
-            if refine_fn is not None:
-                vals, ids = refine_fn(chunk, ids)
-            with xfercheck.explicit("blocked_search block result fetch"):
-                out_s[s : s + n], out_i[s : s + n] = finalize_results(
-                    np.asarray(vals)[:n], np.asarray(ids)[:n], metric)
-    return out_s, out_i
+        units.append(_Unit(fused_fn, q3, counts, None, nq))
+    else:
+        for s in range(0, nq, block):
+            with tracing.stage("engine.feed"):
+                n, chunk = _padded_block(q, s, block)
+                chunk = jax.device_put(chunk)
+                counts = (jax.device_put(np.int32(n)),) if with_counts else ()
+            units.append(_Unit(fn, chunk, counts, refine_fn, n))
+
+    def collect():
+        parts = [u.collect() for u in units] or [
+            (np.empty((0, k), np.float32), np.empty((0, k), np.int32))]
+        return finalize_results(np.concatenate([s for s, _ in parts]),
+                                np.concatenate([i for _, i in parts]), metric)
+
+    return SearchHandle(collect)
+
+
+def blocked_search(q: np.ndarray, k: int, metric: str, fn, block: int = 256,
+                   fused_fn=None, refine_fn=None, with_counts: bool = False):
+    """``launch_blocked_search`` and its collect in one call: what every
+    caller that has nothing to do meanwhile uses."""
+    return launch_blocked_search(q, k, metric, fn, block, fused_fn, refine_fn,
+                                 with_counts).collect()

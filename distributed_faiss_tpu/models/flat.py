@@ -86,53 +86,61 @@ class FlatIndex(base.TpuIndex):
         self.store.mask_rows(rows)
 
     def search(self, q: np.ndarray, k: int):
+        return self.launch_search(q, k).collect()
+
+    def launch_search(self, q: np.ndarray, k: int) -> base.SearchHandle:
         nq = q.shape[0]
         if self.ntotal == 0:
             empty_d = np.full((nq, k), np.inf if self.metric == "l2" else -np.inf, np.float32)
-            return empty_d, np.full((nq, k), -1, np.int64)
+            return base.finished((empty_d, np.full((nq, k), -1, np.int64)))
         kwargs = {}
         if self.codec == "sq8":
             kwargs = {"codec": "sq8", "vmin": self.sq_params["vmin"], "span": self.sq_params["span"]}
+        # the store as this launch finds it: the programs hold these
+        # operands, whatever an add does to the store before the collect
         store = self.store
+        data, live, cap = store.data, store.live, store.cap
+        # explicit device_put: the serving path runs under DFT_XFERCHECK's
+        # transfer guard, which forbids the implicit upload at jit dispatch
+        ntotal = jax.device_put(np.int32(store.ntotal))
 
         def scanned(out, blocks):
-            """The scan's outputs once the device has them: ``engine.scan``
-            (base.blocked_search opens it around these callables) then runs
-            from the dispatch to the end of the wait, and the fetch after it
-            times the fetch alone. ``engine.scan_rows`` counts the rows of
+            """The scan as dispatched, its wait put off to the collect
+            (``base.Dispatched``): ``engine.scan`` then runs from the
+            dispatch to the end of that wait, and the fetch after it times
+            the fetch alone. ``engine.scan_rows`` counts the rows of
             the store the scan read, capacity padding included;
             ``engine.scan_prefilter`` the scans whose per-chunk top-k chose
             its segments by their maxima (the rule the traced code
             branches on, asked of the same k and chunk)."""
-            out = jax.block_until_ready(out)
-            tracing.count("engine.scan_rows", float(blocks * store.cap))
-            if distance.topk_prefilters(k, min(distance.SCAN_CHUNK, store.cap)):
-                tracing.count("engine.scan_prefilter")
-            return out
 
-        def ntotal_on_device():
-            # explicit device_put: the serving path runs under DFT_XFERCHECK's
-            # transfer guard, which forbids the implicit upload at jit dispatch
-            return jax.device_put(np.int32(store.ntotal))
+            def settled(out):
+                tracing.count("engine.scan_rows", float(blocks * cap))
+                if distance.topk_prefilters(k, min(distance.SCAN_CHUNK, cap)):
+                    tracing.count("engine.scan_prefilter")
+                return out
+
+            return base.Dispatched(out, settled)
 
         def scan_block(block):
             return scanned(distance.knn(
-                block, store.data, k, metric=self.metric, ntotal=ntotal_on_device(),
-                live=store.live, **kwargs), 1)
+                block, data, k, metric=self.metric, ntotal=ntotal,
+                live=live, **kwargs), 1)
 
         def scan_fused(q3):
             # multi-block batch: one launch for all blocks (lax.map)
             return scanned(sanitize.maybe_checked(
-                _flat_search_fused, q3, store.data, ntotal_on_device(), k=k,
+                _flat_search_fused, q3, data, ntotal, k=k,
                 metric=self.metric, codec=self.codec, vmin=kwargs.get("vmin"),
-                span=kwargs.get("span"), live=store.live), q3.shape[0])
+                span=kwargs.get("span"), live=live), q3.shape[0])
 
         # per-query transient is the (nq, chunk) score block of the running
         # scan — launch-bound serving wants the largest block that keeps it
         # within budget (see base.pick_query_block)
-        return base.blocked_search(q, k, self.metric, scan_block,
-                                   block=base.pick_query_block(distance.SCAN_CHUNK * 4),
-                                   fused_fn=scan_fused)
+        return base.launch_blocked_search(
+            q, k, self.metric, scan_block,
+            block=base.pick_query_block(distance.SCAN_CHUNK * 4),
+            fused_fn=scan_fused)
 
     def reconstruct_batch(self, ids: np.ndarray) -> np.ndarray:
         rows = self.store.rows(np.asarray(ids))

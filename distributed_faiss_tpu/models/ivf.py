@@ -622,16 +622,24 @@ class _IVFBase(base.TpuIndex):
             out[s:e] = base.gather_list_rows(lists, assign[ids], pos[ids])
         return out
 
-    def _search_blocks(self, q: np.ndarray, k: int, fn, block: int = 256,
-                       fused_fn=None, refine_fn=None, with_counts: bool = False):
-        """Blocked search driver — see ``models.base.blocked_search`` (the
-        single shared implementation: one launch per block by default;
+    def _launch_blocks(self, q: np.ndarray, k: int, fn, block: int = 256,
+                       fused_fn=None, refine_fn=None,
+                       with_counts: bool = False) -> base.SearchHandle:
+        """Blocked search driver — see ``models.base.launch_blocked_search``
+        (the single shared implementation: one launch per block by default;
         with ``fused_fn`` a multi-block batch runs in ONE lax.map launch,
         with the pow2-bucketing and memory-cliff rationale documented
         there; ``refine_fn`` is the per-block exact rerank, dispatched
-        after ``fn``'s scan has finished)."""
-        return base.blocked_search(q, k, self.metric, fn, block, fused_fn,
-                                   refine_fn, with_counts)
+        behind ``fn``'s scan)."""
+        return base.launch_blocked_search(q, k, self.metric, fn, block,
+                                          fused_fn, refine_fn, with_counts)
+
+    def _search_blocks(self, q: np.ndarray, k: int, fn, block: int = 256,
+                       fused_fn=None, refine_fn=None, with_counts: bool = False):
+        """``_launch_blocks`` and its collect in one call (the mesh
+        indexes, whose scan callables wait themselves)."""
+        return self._launch_blocks(q, k, fn, block, fused_fn, refine_fn,
+                                   with_counts).collect()
 
     def _empty_results(self, nq: int, k: int):
         d = np.full((nq, k), np.inf if self.metric == "l2" else -np.inf, np.float32)
@@ -819,8 +827,11 @@ class IVFFlatIndex(_IVFBase):
                                 self.dim, np.dtype(self.lists.dtype).itemsize)
 
     def search(self, q: np.ndarray, k: int):
+        return self.launch_search(q, k).collect()
+
+    def launch_search(self, q: np.ndarray, k: int) -> base.SearchHandle:
         if self._n == 0:
-            return self._empty_results(q.shape[0], k)
+            return base.finished(self._empty_results(q.shape[0], k))
         nprobe = min(self.nprobe, self.nlist)
         # nb: rows a block, launch-bound-aware (see base.pick_query_block);
         # g: probes a step of the Pallas arm, sized as for a gathered fp32
@@ -837,14 +848,18 @@ class IVFFlatIndex(_IVFBase):
             extra.update(vmin=self.sq_params["vmin"], span=self.sq_params["span"])
         norms = self._scan_norms()
         scan_k = k * self.refine_k_factor if self.refine_k_factor else k
+        # the index as this launch finds it: every program of the search,
+        # the collect's oracle included, reads these operands, whatever an
+        # add replaces on the index before the collect
+        lists = (self.centroids, self.lists.data, self.lists.ids, self.lists.sizes)
+        refine_rows = self.refine_store.data if self.refine_k_factor else None
 
         def scan(b, with_pallas, nvalid=None):
             # maybe_checked = GRAFT_SANITIZE=1 checkify wrapper (identity
             # when off); scalar knobs ride as kwargs so the sanitizer can
             # partial-bind them before checkify abstracts the operands
             return sanitize.maybe_checked(
-                _ivf_flat_search,
-                self.centroids, self.lists.data, self.lists.ids, self.lists.sizes,
+                _ivf_flat_search, *lists,
                 b, k=scan_k, nprobe=nprobe, g=g, metric=self.metric,
                 codec=self.codec, list_norms=norms, use_pallas=with_pallas,
                 scan_bf16=self.scan_bf16, nvalid=nvalid, **extra,
@@ -855,43 +870,34 @@ class IVFFlatIndex(_IVFBase):
                 distance.pad_rows(np.asarray(q[:8], np.float32), 8))
             self._validate_flat_pallas(scan)
 
-        def guarded(call):
-            """pallas_guarded, plus the count row that says the scan's
-            program took the list-major order (``engine.scan_listmajor``,
-            beside the ``engine.scan`` stage this runs in): the XLA arm
-            does, the Pallas kernel scans query-major; the last path tried
-            is the one served."""
-            tried = []
-
-            def attempt(with_pallas):
-                tried.append(with_pallas)
-                return call(with_pallas)
-
-            out = pallas_guarded(self, attempt)
-            if not tried[-1]:
+        def listmajor(out, with_pallas):
+            """The count row that says the scan's program took the
+            list-major order (``engine.scan_listmajor``, beside the
+            ``engine.scan`` stage whose wait books it): the XLA arm does,
+            the Pallas kernel scans query-major; the last path tried is the
+            one served."""
+            if not with_pallas:
                 tracing.count("engine.scan_listmajor")
             return out
 
         def run(b, n):
-            return guarded(lambda p: scan(b, p, n))
+            return GuardedScan(self, lambda p: scan(b, p, n), listmajor)
 
         def refine(b, ids):
-            return _rerank_exact(self.refine_store.data, b, ids, k, self.metric)
+            return _rerank_exact(refine_rows, b, ids, k, self.metric)
 
         def run_fused(q3, counts):
-            return guarded(
-                lambda p: sanitize.maybe_checked(
-                    _ivf_flat_search_fused,
-                    self.centroids, self.lists.data, self.lists.ids, self.lists.sizes,
-                    self.refine_store.data if self.refine_k_factor else None,
+            return GuardedScan(
+                self, lambda p: sanitize.maybe_checked(
+                    _ivf_flat_search_fused, *lists, refine_rows,
                     q3, k=k, scan_k=scan_k, nprobe=nprobe, g=g,
                     metric=self.metric, codec=self.codec,
                     refine=bool(self.refine_k_factor), list_norms=norms,
                     use_pallas=p, scan_bf16=self.scan_bf16, counts=counts,
                     **extra,
-                ))
+                ), listmajor)
 
-        return self._search_blocks(
+        return self._launch_blocks(
             q, k, run, block=nb, fused_fn=run_fused,
             refine_fn=refine if self.refine_k_factor else None,
             with_counts=True)
@@ -971,34 +977,59 @@ class IVFFlatIndex(_IVFBase):
         return idx
 
 
-def pallas_guarded(index, call):
-    """Run ``call(use_pallas)`` on the ladder kernel -> XLA oracle -> demote.
+class GuardedScan(base.Dispatched):
+    """``call(use_pallas)`` on the ladder kernel -> XLA oracle -> demote, in
+    two halves: the dispatch (here) and ``wait()`` (``blocked_search``'s
+    collect), with ``pallas_guarded`` the two in one call.
 
     The kernel runs where the index wants it (``index._kernel_applies()``)
-    and this process has not demoted it. If that attempt raises, the XLA
-    path runs as a side-effect-free oracle. It raises too: the request
-    itself is bad (a dim mismatch fails in the shared coarse scoring) —
-    re-raise, no flag flipped, no cache cleared, so one misbehaving client
-    cannot cost the others their kernel. It returns: the kernel is at fault
-    — log, demote it for the rest of this process (``_pallas_runtime_ok``;
-    never persisted, listed in ``ping()["kernels"]["pallas_degraded"]``) and
-    serve the oracle's result. Every attempt runs under
-    ``jax.block_until_ready`` so an asynchronous kernel abort surfaces here,
-    not at a later np.asarray.
-    """
-    with_pallas = index._kernel_applies() and index._pallas_runtime_ok
-    try:
-        return jax.block_until_ready(call(with_pallas))
-    except Exception:
-        if not with_pallas:
+    and this process has not demoted it. If that attempt raises — at the
+    dispatch (a trace or compile fault) or at the wait, where an
+    asynchronous kernel abort surfaces — the XLA path runs as a
+    side-effect-free oracle, on the block the handle still holds. It raises
+    too: the request itself is bad (a dim mismatch fails in the shared
+    coarse scoring) — re-raise, no flag flipped, no cache cleared, so one
+    misbehaving client cannot cost the others their kernel. It returns: the
+    kernel is at fault — log, demote it for the rest of this process
+    (``_pallas_runtime_ok``; never persisted, listed in
+    ``ping()["kernels"]["pallas_degraded"]``) and serve the oracle's
+    result. ``settled(out, with_pallas)`` is handed the path that was
+    served, for the count row it books."""
+
+    def __init__(self, index, call, settled=None):
+        self.index, self.call, self._settled = index, call, settled
+        self.with_pallas = index._kernel_applies() and index._pallas_runtime_ok
+        try:
+            self.out = call(self.with_pallas)
+        except Exception:
+            self.out = self._oracle()
+
+    def _oracle(self):
+        """Called where the attempt raised (inside the handler): the XLA
+        path's outputs, waited for, or the attempt's error again."""
+        if not self.with_pallas:
             raise
-        out = jax.block_until_ready(call(False))  # raises: a bad request
+        out = jax.block_until_ready(self.call(False))  # raises: a bad request
         logger.exception(
             "pallas kernel (%s) failed on this backend; using the XLA path "
             "for the rest of this process (persisted use_pallas intent is "
-            "unchanged)", index._PALLAS_KERNEL)
-        index._pallas_runtime_ok = False
+            "unchanged)", self.index._PALLAS_KERNEL)
+        self.index._pallas_runtime_ok = False
+        self.with_pallas = False
         return out
+
+    def wait(self):
+        try:
+            out = self._ready()
+        except Exception:
+            out = self.out = self._oracle()
+        return out if self._settled is None else self._settled(out, self.with_pallas)
+
+
+def pallas_guarded(index, call):
+    """``GuardedScan``'s ladder with no time between dispatch and wait: what
+    a caller with nothing to do meanwhile uses (the mesh indexes)."""
+    return GuardedScan(index, call).wait()
 
 
 class IVFPQIndex(_IVFBase):
@@ -1059,21 +1090,19 @@ class IVFPQIndex(_IVFBase):
         return (adc_pallas.planes_supported(self.m, 1 << self.nbits, self.lists.cap)
                 and (self.use_pallas is True or adc_pallas.on_tpu()))
 
-    def _guarded_scan(self, call):
-        """pallas_guarded, plus the count row that says the scan ran the
-        fused kernel (``engine.scan_fused``, beside the ``engine.scan``
-        stage this runs in): the last path tried is the one whose result is
-        served."""
-        tried = []
-
-        def attempt(with_pallas):
-            tried.append(with_pallas)
-            return call(with_pallas)
-
-        out = pallas_guarded(self, attempt)
-        if tried[-1]:
+    @staticmethod
+    def _fused_counted(out, with_pallas):
+        """The count row that says the scan ran the fused kernel
+        (``engine.scan_fused``, beside the ``engine.scan`` stage whose wait
+        books it): the last path tried is the one whose result is served."""
+        if with_pallas:
             tracing.count("engine.scan_fused")
         return out
+
+    def _guarded_scan(self, call):
+        """pallas_guarded plus ``engine.scan_fused``, dispatch and wait in
+        one call (the sharded index's scans)."""
+        return GuardedScan(self, call, self._fused_counted).wait()
 
     @staticmethod
     def _book_adc_cols(counts) -> None:
@@ -1114,8 +1143,11 @@ class IVFPQIndex(_IVFBase):
             self.refine_store.add(clip_f16(x))
 
     def search(self, q: np.ndarray, k: int):
+        return self.launch_search(q, k).collect()
+
+    def launch_search(self, q: np.ndarray, k: int) -> base.SearchHandle:
         if self._n == 0:
-            return self._empty_results(q.shape[0], k)
+            return base.finished(self._empty_results(q.shape[0], k))
         nprobe = min(self.nprobe, self.nlist)
         # group payload: codes + ids + lut + score blocks (the one-hot feeds
         # the MXU contraction without full materialization)
@@ -1126,9 +1158,16 @@ class IVFPQIndex(_IVFBase):
         # in a few loop steps (one, online) and not in nprobe of them, each
         # with its own gather, top-k merge and tens of device ops
         rows = nb if q.shape[0] > nb else distance.bucket_size(q.shape[0])
+        cap = self.lists.cap
         g = probe_group_size(
-            nprobe, pq_probe_payload_bytes(self.lists.cap, self.m, nq_block=rows))
+            nprobe, pq_probe_payload_bytes(cap, self.m, nq_block=rows))
         adc_k = k * self.refine_k_factor if self.refine_k_factor else k
+        # the index as this launch finds it: every program of the search,
+        # the collect's oracle included, reads these operands, whatever an
+        # add replaces on the index before the collect
+        lists = (self.centroids, self.codebooks, self.lists.data, self.lists.ids,
+                 self.lists.sizes)
+        refine_rows = self.refine_store.data if self.refine_k_factor else None
 
         counts = []  # (capacity columns, columns scored) of every scan
 
@@ -1138,16 +1177,19 @@ class IVFPQIndex(_IVFBase):
                 out[2].copy_to_host_async()
             return out
 
-        def counted(out, rows):
-            vals, ids, cols = out
-            counts.append((rows * nprobe * self.lists.cap, cols))
-            return vals, ids
+        def counted(rows):
+            """At a scan's wait: its count taken off its outputs."""
+
+            def settled(out, with_pallas):
+                vals, ids, cols = self._fused_counted(out, with_pallas)
+                counts.append((rows * nprobe * cap, cols))
+                return vals, ids
+
+            return settled
 
         def adc(b, with_pallas):
             return launched(sanitize.maybe_checked(
-                _ivf_pq_search,
-                self.centroids, self.codebooks, self.lists.data, self.lists.ids,
-                self.lists.sizes, b, k=adc_k, nprobe=nprobe, g=g,
+                _ivf_pq_search, *lists, b, k=adc_k, nprobe=nprobe, g=g,
                 metric=self.metric, use_pallas=with_pallas,
             ))
 
@@ -1162,31 +1204,33 @@ class IVFPQIndex(_IVFBase):
                 self._PALLAS_KERNEL, 1e-4)
 
         def run(b):
-            return counted(self._guarded_scan(lambda p: adc(b, p)), b.shape[0])
+            return GuardedScan(self, lambda p: adc(b, p), counted(b.shape[0]))
 
         def refine(b, ids):
-            return _rerank_exact(self.refine_store.data, b, ids, k, self.metric)
+            return _rerank_exact(refine_rows, b, ids, k, self.metric)
 
         def adc_fused(q3, with_pallas):
             return launched(sanitize.maybe_checked(
-                _ivf_pq_search_fused,
-                self.centroids, self.codebooks, self.lists.data, self.lists.ids,
-                self.lists.sizes,
-                self.refine_store.data if self.refine_k_factor else None,
+                _ivf_pq_search_fused, *lists, refine_rows,
                 q3, k=k, adc_k=adc_k, nprobe=nprobe, g=g, metric=self.metric,
                 use_pallas=with_pallas,
                 refine=bool(self.refine_k_factor),
             ))
 
         def run_fused(q3):
-            return counted(self._guarded_scan(lambda p: adc_fused(q3, p)),
-                           q3.shape[0] * q3.shape[1])
+            return GuardedScan(self, lambda p: adc_fused(q3, p),
+                               counted(q3.shape[0] * q3.shape[1]))
 
-        out = self._search_blocks(
+        pending = self._launch_blocks(
             q, k, run, block=nb, fused_fn=run_fused,
             refine_fn=refine if self.refine_k_factor else None)
-        self._book_adc_cols(counts)
-        return out
+
+        def collect():
+            out = pending.collect()
+            self._book_adc_cols(counts)
+            return out
+
+        return base.SearchHandle(collect)
 
     def reconstruct_batch(self, ids: np.ndarray) -> np.ndarray:
         ids = np.asarray(ids, np.int64)

@@ -107,6 +107,11 @@ class PreTransformIndex(base.TpuIndex):
     def search(self, q: np.ndarray, k: int):
         return self.inner.search(self.apply(q), k)
 
+    def launch_search(self, q: np.ndarray, k: int) -> base.SearchHandle:
+        # the transform is the host's; the launch is whatever the inner
+        # index offers
+        return self.inner.launch_search(self.apply(q), k)
+
     def supports_remove_rows(self) -> bool:
         return self.inner.supports_remove_rows()
 

@@ -949,6 +949,11 @@ class ShardedIVFFlatIndex(IVFFlatIndex):
             # identical slot layout and capacity
             self.raw_lists.append(assign, clip_f16(x), gids)
 
+    # one launch a window, results off the device once: the whole search
+    # runs in the launch and the handle comes back finished (the parent's
+    # two-phase form is the local index's, not this one's)
+    launch_search = base.TpuIndex.launch_search
+
     def search(self, q: np.ndarray, k: int):
         if self._n == 0:
             return self._empty_results(q.shape[0], k)
@@ -1239,6 +1244,11 @@ class ShardedIVFPQIndex(IVFPQIndex):
             # identical (assign, gids) stream as the code lists -> identical
             # slot layout and capacity, so one local position addresses both
             self.raw_lists.append(assign, clip_f16(x), gids)
+
+    # one launch a window, results off the device once: the whole search
+    # runs in the launch and the handle comes back finished (the parent's
+    # two-phase form is the local index's, not this one's)
+    launch_search = base.TpuIndex.launch_search
 
     def search(self, q: np.ndarray, k: int):
         if self._n == 0:

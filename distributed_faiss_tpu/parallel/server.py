@@ -159,6 +159,27 @@ class _Call:
             self.ticket = (trace_id, self.span_id, spans)
 
 
+class _EngineSearch:
+    """The scheduler's target: the engine's already-batched entry (the
+    scheduler has coalesced the callers; engine.py ``search_batched`` skips
+    the in-process natural batcher), in one call and in two halves —
+    ``launch`` returns the handle the scheduler collects once the window
+    ahead of this one is done (engine.py ``launch_batched``)."""
+
+    def __init__(self, get_index):
+        self._get_index = get_index
+
+    def __call__(self, index_id: str, query_batch: np.ndarray, top_k: int,
+                 return_embeddings: bool) -> Tuple:
+        return self._get_index(index_id).search_batched(
+            query_batch, top_k=top_k, return_embeddings=return_embeddings)
+
+    def launch(self, index_id: str, query_batch: np.ndarray, top_k: int,
+               return_embeddings: bool):
+        return self._get_index(index_id).launch_batched(
+            query_batch, top_k=top_k, return_embeddings=return_embeddings)
+
+
 class IndexServer:
     def __init__(self, rank: int, index_storage_dir: str,
                  scheduler_cfg: Optional[SchedulerCfg] = None,
@@ -213,7 +234,7 @@ class IndexServer:
         self.scheduler: Optional[SearchScheduler] = None
         if cfg.enabled:
             self.scheduler = SearchScheduler(
-                self._engine_search_batched, cfg,
+                _EngineSearch(self._get_index), cfg,
                 name=f"search-batcher:r{rank}",
                 tag={"rank": rank, "shard_group": self.shard_group})
         # request multiplexing: calls whose frame meta carries a req_id are
@@ -294,15 +315,6 @@ class IndexServer:
             # structured rejection is group-failover-eligible client-side)
             index.assert_min_version(min_version)
         return index.search(
-            query_batch, top_k=top_k, return_embeddings=return_embeddings
-        )
-
-    def _engine_search_batched(self, index_id: str, query_batch: np.ndarray,
-                               top_k: int, return_embeddings: bool) -> Tuple:
-        """The scheduler's launch target: the engine's already-batched
-        entry (the scheduler has coalesced the callers; engine.py
-        search_batched skips the in-process natural batcher)."""
-        return self._get_index(index_id).search_batched(
             query_batch, top_k=top_k, return_embeddings=return_embeddings
         )
 

@@ -3,12 +3,32 @@
 One ``SearchScheduler`` per server rank. Connection threads call
 ``submit`` (blocking) or — for multiplexed RPC, where the connection
 reader must keep pulling frames — ``submit_async`` with a completion
-callback; a single named batcher thread drains the queue, coalesces
+callback; a named batcher thread drains the queue, coalesces
 compatible requests — same ``(index_id, top_k, return_embeddings,
-dim)`` — into one concatenated device batch, runs the engine's batched
-search entry once, and hands every caller its row slice. Two flush triggers: the pending compatible rows reach
-``max_batch_rows``, or the oldest queued request has waited
-``max_wait_ms``.
+dim)`` — into one concatenated device batch and launches the engine's
+batched search entry once; a completer thread collects the launched
+windows in their order and hands every caller its row slice. Two flush
+triggers: the pending compatible rows reach ``max_batch_rows``, or the
+oldest queued request has waited ``max_wait_ms`` (while a window is in
+flight it waits for followers longer: below).
+
+Two windows in flight (``IN_FLIGHT``, fixed). While window n runs on the
+chip the batcher assembles, feeds and dispatches window n+1 behind it, so
+the chip starts n+1 the moment n ends, and the completer fetches, joins,
+splits and finishes n meanwhile. ``search_fn.launch(index_id, query_batch,
+top_k, return_embeddings)``, where the target offers it
+(``engine.Index.launch_batched`` behind the server's), returns a handle
+whose ``collect()`` gives the result; a plain ``search_fn`` is served
+through a launch that runs the whole search and a handle that is already
+finished. A third window waits for the first to be collected. Waiting for
+followers costs the chip nothing while a window is on its way to be
+collected, so then a head request waits, past ``max_wait_ms``, until the
+queue holds as many rows as the newest window in flight took (or
+``max_batch_rows``), or until the last of them is collected: a window does
+not shrink because the loop no longer gives followers a whole launch to
+queue up. Completions are published in
+launch order; a window's error fails its own callers only; ``stop()``
+lets the windows in flight complete and fails what is queued.
 
 Admission control (the backpressure contract, docs/OPERATIONS.md):
 
@@ -31,7 +51,7 @@ caller's rows.
 
 One flush = one engine call = (on a mesh-backed index) ONE pjit launch:
 ``search_fn`` is ``engine.Index.search_batched``, whose locked device
-step routes through ``TpuIndex.search_batched`` — for a rank that owns a
+step routes through ``TpuIndex.launch_search`` — for a rank that owns a
 device mesh the whole merged window crosses to the chips as a single
 device program with the top-k reduce on-mesh, and results leave the
 device once per window (parallel/mesh.py; the engine's
@@ -45,15 +65,17 @@ percentiles, batch occupancy (requests and rows per launch), queue depth
 at flush, and monotonic shed/busy counters — all exported through the
 rank's ``get_perf_stats`` RPC under the ``"scheduler"`` key.
 
-The batcher thread keeps the launch loop's stage ledger
-(``utils/tracing.stage``, docs/OPERATIONS.md#stage-ledger): every second
-between two window ends is booked to exactly one of ``sched.idle`` (queue
-empty), ``sched.window_wait`` (a head request waits for followers),
-``sched.assemble`` (deadline shed, concat), the engine's stages (inside
-``server.device``, the engine call: a span and a profiler event, no
-counter) and ``sched.split`` (row split and completion callbacks), so the
-stages' totals add up to the thread's wall clock over windows that
-succeed (a stage that raises books nothing). A sampled request (its submitter's context held a trace,
+The two threads keep the launch loop's stage ledger
+(``utils/tracing.stage``, docs/OPERATIONS.md#stage-ledger), every stage
+once a window: on the batcher thread ``sched.idle`` (queue empty),
+``sched.window_wait`` (a head request waits for followers, or for one of
+the two places in flight), ``sched.assemble`` (deadline shed, concat) and
+the launch half of the engine's stages; on the completer thread their
+collect half and ``sched.split`` (row split and completion callbacks).
+``server.device`` (a span and a profiler event, no counter) runs from the
+launch call's start to the collect's end. The stages' totals no longer add
+up to one thread's wall clock: ``engine.scan`` of window n+1 runs beside
+window n's collect (a stage that raises books nothing). A sampled request (its submitter's context held a trace,
 ``tracing.ticket``) additionally gets ``server.queue`` (wait + which
 merge window it landed in and its occupancy) and ``server.device`` spans
 in its submitter's SpanBuffer, and stamps the latency histograms'
@@ -63,6 +85,7 @@ exemplars (observability/spans.py).
 import logging
 import threading
 import time
+from collections import deque
 from typing import Callable, List, Optional, Tuple
 
 import numpy as np
@@ -170,18 +193,52 @@ def _split_rows(value, offsets: List[Tuple[int, int]]):
     return [value] * len(offsets)
 
 
+class _Finished:
+    """The handle of a window whose launch was the whole search, or
+    failed: ``collect()`` returns the result or raises the error."""
+
+    __slots__ = ("result", "error")
+
+    def __init__(self, result=None, error: Optional[BaseException] = None):
+        self.result, self.error = result, error
+
+    def collect(self):
+        if self.error is not None:
+            raise self.error
+        return self.result
+
+
+class _Window:
+    """A launched window on its way to the completer."""
+
+    __slots__ = ("live", "handle", "device", "traced")
+
+    def __init__(self, live, handle, device, traced):
+        self.live, self.handle, self.device, self.traced = (
+            live, handle, device, traced)
+
+
 class SearchScheduler:
-    """Bounded queue + batcher thread coalescing concurrent searches.
+    """Bounded queue + batcher and completer threads coalescing concurrent
+    searches, two windows in flight.
 
     ``search_fn(index_id, query_batch, top_k, return_embeddings)`` is the
     engine's already-batched entry (engine.Index.search_batched on a
     server); it must return a tuple whose ndarray/list elements have one
-    leading row per query row.
+    leading row per query row. Where it also offers ``search_fn.launch``
+    (same arguments; returns a handle whose ``collect()`` gives that
+    tuple), windows are launched through it and collected later.
     """
+
+    IN_FLIGHT = 2  # windows launched and not yet collected, at most
 
     def __init__(self, search_fn: Callable, cfg: Optional[SchedulerCfg] = None,
                  name: str = "search-batcher", tag: Optional[dict] = None):
-        self._search_fn = search_fn
+        launch = getattr(search_fn, "launch", None)
+        if launch is None:
+            # no two-phase form: the launch is the whole search
+            launch = lambda *call: _Finished(search_fn(*call))
+        self._launch_fn = launch
         self.cfg = cfg if cfg is not None else SchedulerCfg()
         # replica identity riding the stats surface (replication layer):
         # admission behavior is unchanged per replica, but operators need
@@ -190,6 +247,13 @@ class SearchScheduler:
         self.tag = dict(tag or {})
         self._cond = lockdep.condition("SearchScheduler._cond")
         self._queue: List[_Request] = []
+        # launched windows in launch order, and how many of them are not
+        # collected yet (a window leaves the deque when its collect starts
+        # and the count when it ends); both guarded by _cond
+        self._launched: deque = deque()
+        self._in_flight = 0
+        self._newest_rows = 0  # rows of the window launched last
+        self._batcher_done = False
         self._stopping = False
         self.stats = LatencyStats()
         # admission/flush counters ride the shared atomic-counter helper
@@ -199,7 +263,10 @@ class SearchScheduler:
             ("submitted", "batches", "shed_deadline", "rejected_busy"))
         self._thread = threading.Thread(
             target=self._batcher_loop, name=name, daemon=True)
+        self._completer = threading.Thread(
+            target=self._completer_loop, name=f"{name}-collect", daemon=True)
         self._thread.start()
+        self._completer.start()
 
     # ------------------------------------------------------------ client side
 
@@ -294,6 +361,14 @@ class SearchScheduler:
     # ----------------------------------------------------------- batcher side
 
     def _batcher_loop(self) -> None:
+        try:
+            self._batch_and_launch()
+        finally:
+            with self._cond:  # the completer ends once what is launched is done
+                self._batcher_done = True
+                self._cond.notify_all()
+
+    def _batch_and_launch(self) -> None:
         while True:
             try:
                 batch = self._next_batch()
@@ -314,15 +389,24 @@ class SearchScheduler:
             if batch is None:
                 return  # stopped; stop() already drained the queue
             try:
-                self._serve(batch)
+                window = self._launch(batch)
             except BaseException:  # the loop must survive any launch failure
                 logger.exception("scheduler batch failed")
-                for r in batch:
-                    self._finish(r)
+                window = _Window(
+                    [r for r in batch if not r.event.is_set()],
+                    _Finished(error=RuntimeError("scheduled search aborted")),
+                    None, ())
+            # to the completer, which publishes in launch order — a window
+            # with nothing to collect (all shed, or dead above) too: it
+            # holds its place in flight until its turn
+            with self._cond:
+                self._launched.append(window)
+                self._cond.notify_all()
 
     def _next_batch(self) -> Optional[List[_Request]]:
-        """Block until a flush trigger fires; pop and return one batch of
-        compatible requests (FIFO from the head's group)."""
+        """Block until a flush trigger fires and a place in flight is free;
+        pop and return one batch of compatible requests (FIFO from the
+        head's group), counted in flight from here."""
         max_wait_s = self.cfg.max_wait_ms / 1000.0
         with self._cond:
             while True:
@@ -339,12 +423,28 @@ class SearchScheduler:
                     continue
                 head = self._queue[0]
                 rows = sum(r.rows for r in self._queue if r.key == head.key)
+                # a window on its way to be collected keeps the chip busy:
+                # waiting for followers then costs the chip nothing, and
+                # the callers of the window before it are on their way
+                # back. So while one is in flight the head waits, past
+                # max_wait_ms, for as many rows as the newest of them
+                # took: a window does not shrink because the loop no longer
+                # gives followers a whole launch to queue up (two windows
+                # of 64 rows cost a flat index twice one of 128). The
+                # completer's notify ends the wait with the last collect;
+                # max_wait_ms then applies as it always has. With no place
+                # free the head waits whatever the queue holds.
+                want = self.cfg.max_batch_rows
+                if self._in_flight:
+                    want = min(want, self._newest_rows)
+                full = head.eager or rows >= want
                 flush_at = head.enqueue_t + max_wait_s
                 now = time.monotonic()
-                if (not head.eager and rows < self.cfg.max_batch_rows
-                        and now < flush_at):
+                if (self._in_flight >= self.IN_FLIGHT
+                        or (not full and (self._in_flight or now < flush_at))):
+                    timeout = 1.0 if self._in_flight else flush_at - now
                     with tracing.stage("sched.window_wait", sink=self.stats):
-                        self._cond.wait(flush_at - now)
+                        self._cond.wait(timeout)
                     continue
                 # pop whole compatible requests until the row budget is
                 # reached; a single over-budget request still goes alone
@@ -358,15 +458,17 @@ class SearchScheduler:
                     else:
                         rest.append(r)
                 self._queue = rest
+                self._in_flight += 1
+                self._newest_rows = taken_rows
                 self.stats.record("queue_depth", float(len(rest)))
                 return taken
 
-    def _serve(self, batch: List[_Request]) -> None:
-        """One window: assemble, launch, split. An ``Exception`` fails the
-        window's callers here; anything else (a BaseException out of the
-        engine or the split) is the batcher loop's to catch, which
-        finishes every request of the batch (``_finish`` publishes once)."""
-        error = None
+    def _launch(self, batch: List[_Request]) -> _Window:
+        """A window's first half, on the batcher thread: assemble and
+        launch. An ``Exception`` becomes the window's error, published at
+        its turn; anything else (a BaseException out of the engine) is the
+        batcher loop's to catch, which sends the whole batch to be finished
+        (``_finish`` publishes once)."""
         with tracing.stage("sched.assemble", sink=self.stats):
             now = time.monotonic()
             live: List[_Request] = []
@@ -382,7 +484,7 @@ class SearchScheduler:
                     continue
                 live.append(r)
             if not live:
-                return
+                return _Window((), _Finished(), None, ())
             window = self._counters.inc("batches")
             n_rows = sum(r.rows for r in live)
             self.stats.record("batch_requests", float(len(live)))
@@ -403,30 +505,66 @@ class SearchScheduler:
             try:
                 qcat = head.q if len(live) == 1 else _concat_rows(live, n_rows)
             except Exception as exc:
-                error = exc
-        result = None
-        if error is None:
-            # the whole window IS one device program: the engine's stages
-            # nest under one representative sampled request, and every
-            # other sampled request of the window gets the launch's span
-            # echoed into its own trace
-            traced = [r.ticket for r in live if r.ticket is not None]
+                return _Window(live, _Finished(error=exc), None, ())
+        # the whole window IS one device program: the engine's stages
+        # nest under one representative sampled request, and every
+        # other sampled request of the window gets the launch's span
+        # echoed into its own trace
+        traced = [r.ticket for r in live if r.ticket is not None]
+        device = tracing.handover("server.device", sink=tracing.SPAN_ONLY,
+                                  window=window, rows=n_rows)
+        try:
+            # DFT_XFERCHECK=1 arms jax's transfer guard for the whole
+            # merged-window launch (and again for its collect): any
+            # implicit host<->device copy in the flush fails the
+            # provoking request with provenance
+            with tracing.bind(traced[0] if traced else None), device, \
+                    xfercheck.guarded("scheduler merge-window flush"):
+                handle = self._launch_fn(
+                    head.index_id, qcat, head.k, head.return_embeddings)
+        except Exception as exc:
+            handle, device = _Finished(error=exc), None
+        return _Window(live, handle, device, traced)
+
+    def _completer_loop(self) -> None:
+        """Collect, split and publish the launched windows, oldest first;
+        ends when the batcher has and nothing is left in flight."""
+        while True:
+            with self._cond:
+                while not self._launched:
+                    if self._batcher_done:
+                        return
+                    self._cond.wait(timeout=1.0)
+                window = self._launched.popleft()
             try:
-                with tracing.bind(traced[0] if traced else None), \
-                        tracing.stage("server.device", sink=tracing.SPAN_ONLY,
-                                      window=window, rows=n_rows) as launch:
-                    # DFT_XFERCHECK=1 arms jax's transfer guard for the
-                    # whole merged-window launch: any implicit
-                    # host<->device copy in the flush fails the provoking
-                    # request with provenance
-                    with xfercheck.guarded("scheduler merge-window flush"):
-                        result = self._search_fn(
-                            head.index_id, qcat, head.k,
-                            head.return_embeddings)
-                for ticket in traced[1:]:
-                    launch.echo(ticket)
-            except Exception as exc:
-                error = exc
+                self._complete(window)
+            except BaseException:  # the loop must survive any window
+                logger.exception("scheduler batch failed")
+            for r in window.live:
+                self._finish(r)  # whoever _complete left unpublished
+
+    def _complete(self, window: _Window) -> None:
+        """A window's second half, on the completer thread: collect (the
+        place in flight is free again as soon as that ends), split,
+        publish."""
+        live, device, result, error = window.live, window.device, None, None
+        try:
+            if device is None:
+                result = window.handle.collect()
+            else:
+                with device.last(), \
+                        xfercheck.guarded("scheduler merge-window flush"):
+                    result = window.handle.collect()
+                for ticket in window.traced[1:]:
+                    device.echo(ticket)
+        except Exception as exc:
+            error = exc
+        finally:
+            with self._cond:
+                self._in_flight -= 1
+                self._cond.notify_all()
+        if not live:
+            return
         with tracing.stage("sched.split", sink=self.stats):
             if error is None:
                 try:
@@ -463,7 +601,7 @@ class SearchScheduler:
 
     def stop(self) -> None:
         """Stop the batcher and fail everything still queued (callers see
-        ``SchedulerStopped``; in-flight launches complete normally)."""
+        ``SchedulerStopped``; the windows in flight complete normally)."""
         with self._cond:
             self._stopping = True
             stranded, self._queue = self._queue, []
@@ -471,9 +609,11 @@ class SearchScheduler:
         for r in stranded:
             r.error = SchedulerStopped("scheduler stopped with request queued")
             self._finish(r)
-        self._thread.join(timeout=10.0)
-        if self._thread.is_alive():  # pragma: no cover - launch wedged in device
-            logger.warning("scheduler batcher thread did not exit in 10s")
+        for thread in (self._thread, self._completer):
+            thread.join(timeout=10.0)
+            if thread.is_alive():  # pragma: no cover - launch wedged in device
+                logger.warning("scheduler %s thread did not exit in 10s",
+                               thread.name)
 
     # ---------------------------------------------------------- observability
 

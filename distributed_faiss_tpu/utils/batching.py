@@ -19,9 +19,10 @@ Leader/follower protocol ("natural batching"):
   sustained load, leadership is HANDED OFF after ``max_rounds`` drains:
   the leader wakes one pending caller as the next leader and returns.
 
-The per-index serialization the engine already guarantees (one in-flight
-device search per index, reference rationale at index.py:246-252) is
-preserved: there is exactly one leader at a time.
+The per-index serialization the engine guarantees (every device search is
+launched under ``index_lock``, reference rationale at index.py:246-252) is
+preserved, and here one search is in flight at a time: there is exactly
+one leader, and it collects each launch before the next.
 
 The reference has no analog — its FAISS searches serialize under
 ``index_lock`` with one launch per RPC.

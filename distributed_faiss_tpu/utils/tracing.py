@@ -21,7 +21,9 @@ The reference has none of this beyond log lines (SURVEY §5.1); here:
   processes, so the stage sits in a profiler session's ``.xplane.pb`` on
   the clock of the device's ``XLA Ops`` line) and, for a sampled request,
   a span with its own id and its parent's. ``bind`` / ``ticket`` hand the
-  sampled request from thread to thread.
+  sampled request from thread to thread; ``handover`` is a stage whose
+  interval opens in one block and closes in a later one, on any thread (a
+  window's launch and its collect).
 """
 
 import bisect
@@ -210,11 +212,14 @@ class LatencyStats:
 
 now = time.perf_counter  # the one monotonic clock stage timing reads
 
-# The launch loop's ledger: every second of a rank's batcher thread between
-# two window ends is inside exactly one of these stages (serving/scheduler.py,
-# engine.py, models/base.py), so their totals add up to the thread's wall
-# clock. ``server.device`` (the engine call) and ``engine.launch`` (launch to
-# fetch, counter ``device_search_s``) are subtotals that contain some of them.
+# The launch loop's ledger: the stages a merged window passes through
+# (serving/scheduler.py, engine.py, models/base.py), each booked once a
+# window. The batcher thread launches and the completer thread collects, two
+# windows in flight, so the totals no longer add up to one thread's wall
+# clock: ``engine.scan`` holds the time a window waits on the chip behind
+# the one ahead of it. ``server.device`` (launch call to collect's end) and
+# ``engine.launch`` (launch to fetch, counter ``device_search_s``) are
+# subtotals that contain some of them.
 LAUNCH_LOOP = (
     "sched.idle", "sched.window_wait", "sched.assemble",
     "engine.lock_wait", "engine.feed", "engine.scan", "engine.refine_fetch",
@@ -437,3 +442,77 @@ class stage:
 
     def __exit__(self, exc_type, exc, tb):
         self.done(failed=exc_type is not None)
+
+
+class handover:
+    """A stage in legs: ``with st:`` opens it, a later ``with st.last():``,
+    on whichever thread, closes and books it — one counter record and one
+    span from the first leg's start to the last leg's end, whatever ran
+    between the legs (a window's launch on the batcher thread, its collect
+    on the completer's). Every leg is a profiler event of the stage's name
+    and sets the thread's context as ``stage`` does, so the stages nested in
+    a later leg book into the sink, and hang under the span, that the first
+    leg found: the sampled request crosses the threads with the stage, no
+    ticket beside it. A leg that raises leaves the stage unbooked."""
+
+    __slots__ = ("name", "sink", "counter", "extra", "dt", "_t0", "_w0",
+                 "_ticket", "_span_id", "_ann", "_old", "_last")
+
+    def __init__(self, name: str, sink: Optional[LatencyStats] = None,
+                 counter: Optional[str] = None, **extra):
+        self.name = name
+        self.sink = sink
+        self.counter = counter
+        self.extra = extra
+        self.dt = 0.0
+        self._t0 = None
+        self._last = False
+
+    def last(self) -> "handover":
+        self._last = True
+        return self
+
+    def __enter__(self):
+        c = _ctx
+        self._old = (c.sink, c.trace_id, c.parent, c.spans)
+        if self._t0 is None:
+            if self.sink is None:
+                self.sink = c.sink
+            self._ticket = ticket()
+            self._span_id = None if self._ticket is None else new_span_id()
+            self._w0 = time.time()
+            self._t0 = now()
+        c.sink = self.sink
+        if self._ticket is not None:
+            c.trace_id, _, c.spans = self._ticket
+            c.parent = self._span_id
+        self._ann = None
+        if _annotation is not None:
+            self._ann = _annotation(self.name)
+            self._ann.__enter__()
+        return self
+
+    def __exit__(self, exc_type, exc, tb):
+        if self._ann is not None:
+            self._ann.__exit__(None, None, None)
+        c = _ctx
+        c.sink, c.trace_id, c.parent, c.spans = self._old
+        if not self._last or exc_type is not None:
+            return
+        self.dt = dt = now() - self._t0
+        if self.sink is not None:
+            self.sink.record(self.counter or self.name, dt,
+                             exemplar=self._ticket and self._ticket[0])
+        if self._ticket is not None:
+            trace_id, parent, spans = self._ticket
+            _span_buffer(spans).record(trace_id, self.name, self._w0, dt,
+                                       span_id=self._span_id, parent=parent,
+                                       **self.extra)
+
+    def echo(self, ticket: tuple) -> None:
+        """As ``stage.echo``: the booked span again, in another sampled
+        request's trace."""
+        trace_id, parent, spans = ticket
+        _span_buffer(spans).record(
+            trace_id, self.name, self._w0, self.dt, span_id=new_span_id(),
+            parent=parent, **self.extra)

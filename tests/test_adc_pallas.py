@@ -717,9 +717,9 @@ def test_per_block_scans_each_count(rng, monkeypatch):
 
     monkeypatch.setattr(base, "MAX_QUERY_BLOCK", 8)
     idx, x = small_pq(rng, use_pallas=True)
-    blocked = base.blocked_search
+    blocked = base.launch_blocked_search  # the driver's launch half
     monkeypatch.setattr(
-        base, "blocked_search",
+        base, "launch_blocked_search",
         lambda q, k, metric, fn, block=256, fused_fn=None, refine_fn=None,
         with_counts=False:
         blocked(q, k, metric, fn, block, None, refine_fn, with_counts))

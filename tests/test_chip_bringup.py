@@ -17,10 +17,11 @@ REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 # ------------------------------------------------------------ compile cache
 
 
-def _place_cache(cwd, env_dir=None):
+def _place_cache(cwd, env_dir=None, then=""):
     """Run place_compile_cache in a fresh interpreter (jax config is
     process-wide: doing it here would point the whole suite's cache at the
-    checkout). Returns (function result, jax's configured directory)."""
+    checkout). Returns (function result, jax's configured directory);
+    ``then`` is code to run after both are printed."""
     env = {k: v for k, v in os.environ.items() if k != "JAX_COMPILATION_CACHE_DIR"}
     env["PYTHONPATH"] = REPO
     if env_dir is not None:
@@ -32,7 +33,7 @@ def _place_cache(cwd, env_dir=None):
          "print(envutil.place_compile_cache())\n"
          "print(jax.config.jax_compilation_cache_dir)\n"
          "from jax._src import xla_bridge\n"
-         "assert not xla_bridge._backends\n"],
+         "assert not xla_bridge._backends\n" + then],
         cwd=cwd, env=env, capture_output=True, text=True, timeout=120, check=True)
     return out.stdout.split()
 
@@ -44,11 +45,23 @@ def test_cache_unset_is_checkout_jax_cache_from_any_cwd(tmp_path):
 
 
 def test_cache_env_var_stands_and_checkout_is_untouched(tmp_path):
+    """Judged by what this test's own subprocess wrote: other workers' ranks
+    create ``<checkout>/.jax_cache`` whenever they like (ROADMAP D18). The
+    subprocess compiles a program nobody else has (a constant of its own),
+    whose cache entry lands where the variable says and not, under the same
+    name, in the checkout."""
     default = os.path.join(REPO, ".jax_cache")
-    existed = os.path.exists(default)
     elsewhere = str(tmp_path / "cache")
-    assert _place_cache(str(tmp_path), env_dir=elsewhere) == [elsewhere, elsewhere]
-    assert os.path.exists(default) == existed
+    compile_one = (
+        "import jax.numpy as jnp\n"
+        f"jax.jit(lambda a: a * {int.from_bytes(os.urandom(4), 'big')}.5)(jnp.ones(3))"
+        ".block_until_ready()\n")
+    assert _place_cache(str(tmp_path), env_dir=elsewhere,
+                        then=compile_one) == [elsewhere, elsewhere]
+    written = set(os.listdir(elsewhere))
+    assert written, "the compile left no entry where the variable points"
+    if os.path.isdir(default):
+        assert not written & set(os.listdir(default))
 
 
 # --------------------------------------------------------- one rank per chip

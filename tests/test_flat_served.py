@@ -260,19 +260,12 @@ def rank(tmp_path_factory):
     srv.stop()
 
 
-def test_a_flat_ranks_launch_loop_stages_add_up_to_its_wall_clock(rank):
-    """As ``test_stage_ledger`` closes it for IVF: every second between two
-    window ends is in exactly one stage, and the launch's own three stages
-    are what launch-to-fetch is made of — ``engine.feed`` among them."""
-    best_of(3, lambda: launch_loop_closure(rank), 0.02)
-    e = rank["idx"].perf.summary()
-    inner = sum(e[n]["total_s"] for n in
-                ("engine.feed", "engine.scan", "engine.refine_fetch"))
-    assert abs(inner - e["device_search_s"]["total_s"]) <= (
-        0.02 * e["device_search_s"]["total_s"])
-    for name in ("engine.feed", "engine.scan", "engine.refine_fetch",
-                 "engine.scan_rows"):
-        assert e[name]["count"] == e["device_search_s"]["count"], name
+def test_a_flat_ranks_launch_loop_stages_are_booked_once_a_window(rank):
+    """As ``test_stage_ledger`` holds it for IVF: with two windows in flight
+    every stage and count row is booked once a window, and the launch's own
+    three stages are what launch-to-fetch is made of — ``engine.feed``
+    among them, and ``engine.scan_rows`` once a scan."""
+    best_of(3, lambda: launch_loop_closure(rank, ("engine.scan_rows",)), 0.03)
 
 
 def test_a_flat_rank_serves_the_new_rows_in_get_perf_stats(rank):

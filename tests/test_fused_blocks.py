@@ -152,14 +152,14 @@ def test_fused_engages_only_past_one_block(rng, small_blocks):
     idx.set_nprobe(4)
     called = {"fused": 0}
 
-    orig = idx._search_blocks
+    orig = idx._launch_blocks  # the driver's launch half: search collects it
 
     def spy(q, k, fn, block=256, fused_fn=None, **kw):
         if fused_fn is not None and np.asarray(q).shape[0] > block:
             called["fused"] += 1
         return orig(q, k, fn, block=block, fused_fn=fused_fn, **kw)
 
-    idx._search_blocks = spy
+    idx._launch_blocks = spy
     idx.search(rng.standard_normal((8, d)).astype(np.float32), 3)
     assert called["fused"] == 0
     idx.search(rng.standard_normal((9, d)).astype(np.float32), 3)

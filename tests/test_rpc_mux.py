@@ -272,13 +272,13 @@ def test_out_of_order_completion_on_real_server(tmp_path):
     engine, queries = make_trained_engine(tmp_path / "shard")
     srv, port = start_server(tmp_path, "blocking",
                              SchedulerCfg(max_wait_ms=1.0), engine)
-    orig = engine.search_batched
+    orig = engine.launch_batched  # what the scheduler launches a window through
 
     def slow_search(*a, **k):
         time.sleep(0.6)
         return orig(*a, **k)
 
-    engine.search_batched = slow_search
+    engine.launch_batched = slow_search
     try:
         c = rpc.Client(0, "localhost", port)
         events = []
@@ -299,7 +299,7 @@ def test_out_of_order_completion_on_real_server(tmp_path):
         assert events == ["get_rank", "search"]
         c.close()
     finally:
-        engine.search_batched = orig
+        engine.launch_batched = orig
     srv.stop()
 
 
@@ -373,13 +373,13 @@ def test_close_with_calls_in_flight_unblocks_and_demux_exits(tmp_path):
     engine, queries = make_trained_engine(tmp_path / "shard")
     srv, port = start_server(tmp_path, "blocking",
                              SchedulerCfg(max_wait_ms=1.0), engine)
-    orig = engine.search_batched
+    orig = engine.launch_batched  # what the scheduler launches a window through
 
     def slow_search(*a, **k):
         time.sleep(1.0)
         return orig(*a, **k)
 
-    engine.search_batched = slow_search
+    engine.launch_batched = slow_search
     try:
         c = rpc.Client(0, "localhost", port)
         outcome = []
@@ -406,7 +406,7 @@ def test_close_with_calls_in_flight_unblocks_and_demux_exits(tmp_path):
         with pytest.raises(RuntimeError):
             c.generic_fun("get_rank", ())
     finally:
-        engine.search_batched = orig
+        engine.launch_batched = orig
     srv.stop()
 
 

@@ -27,6 +27,7 @@ from distributed_faiss_tpu import (
     SchedulerCfg,
 )
 from distributed_faiss_tpu.parallel import rpc
+from distributed_faiss_tpu.serving import SearchScheduler
 
 pytestmark = pytest.mark.scheduler
 
@@ -66,6 +67,21 @@ def start_server(storage, mode, sched_cfg):
     return srv, port
 
 
+def serve_serially(srv):
+    """Swap the rank's scheduler for one whose ``search_fn`` is a plain
+    callable (no ``launch``): every window is then served through a launch
+    that runs the whole search — the form every target had before the
+    two-phase one, and the one a third-party ``search_fn`` still has."""
+    cfg = srv.scheduler.cfg
+    srv.scheduler.stop()
+
+    def search_fn(index_id, q, k, return_embeddings):
+        return srv._get_index(index_id).search_batched(
+            q, top_k=k, return_embeddings=return_embeddings)
+
+    srv.scheduler = SearchScheduler(search_fn, cfg, name="serial-batcher")
+
+
 def flat_cfg():
     return IndexCfg(index_builder_type="flat", dim=16, metric="l2",
                     train_num=64)
@@ -97,16 +113,21 @@ def fill_and_train(disc, index_id, x, meta):
     return client
 
 
+@pytest.mark.parametrize("search_fn", ["two-phase", "serial"])
 @pytest.mark.parametrize("mode", ["blocking", "selector"])
-def test_concurrent_clients_identical_to_direct_serving(tmp_path, mode):
+def test_concurrent_clients_identical_to_direct_serving(tmp_path, mode, search_fn):
     """8 concurrent clients x 5 searches through the scheduler vs direct
-    serving: every (scores, meta) pair must match exactly."""
+    serving: every (scores, meta) pair must match exactly — with windows
+    launched and collected later (two in flight) and with a plain
+    ``search_fn`` that serves a window in one call."""
     x, meta, queries = build_corpus()
     index_id = f"ident_{mode}"
     setups = {}
     for arm, enabled in (("on", True), ("off", False)):
         cfg = SchedulerCfg(enabled=enabled, max_wait_ms=3.0)
         srv, port = start_server(tmp_path / arm, mode, cfg)
+        if enabled and search_fn == "serial":
+            serve_serially(srv)
         disc = write_discovery(tmp_path, [port], f"{arm}.txt")
         admin = fill_and_train(disc, index_id, x, meta)
         admin.close()
@@ -194,14 +215,16 @@ def test_busy_backpressure_and_client_retry(tmp_path):
     golden = admin.search(queries[0], 3, index_id)
 
     # slow every scheduled launch so the queue saturates deterministically
+    # (the launch half, on the batcher thread: the next window is not even
+    # assembled meanwhile, as when one call served a window)
     engine = srv.indexes[index_id]
-    orig = engine.search_batched
+    orig = engine.launch_batched
 
     def slow_search(*a, **k):
         time.sleep(0.4)
         return orig(*a, **k)
 
-    engine.search_batched = slow_search
+    engine.launch_batched = slow_search
     try:
         stubs = [rpc.Client(i, "localhost", port) for i in range(3)]
         outcomes = []
@@ -252,7 +275,7 @@ def test_busy_backpressure_and_client_retry(tmp_path):
             stub.close()
         patient.close()
     finally:
-        engine.search_batched = orig
+        engine.launch_batched = orig
     admin.close()
     srv.stop()
 
@@ -269,7 +292,7 @@ def test_deadline_shed_serverside_without_touching_device(tmp_path):
     admin = fill_and_train(disc, index_id, x, meta)
 
     engine = srv.indexes[index_id]
-    orig = engine.search_batched
+    orig = engine.launch_batched
     launches = []
 
     def slow_search(*a, **k):
@@ -277,7 +300,7 @@ def test_deadline_shed_serverside_without_touching_device(tmp_path):
         time.sleep(0.5)
         return orig(*a, **k)
 
-    engine.search_batched = slow_search
+    engine.launch_batched = slow_search
     try:
         c1 = rpc.Client(1, "localhost", port)
         c2 = rpc.Client(2, "localhost", port)
@@ -301,7 +324,7 @@ def test_deadline_shed_serverside_without_touching_device(tmp_path):
         c1.close()
         c2.close()
     finally:
-        engine.search_batched = orig
+        engine.launch_batched = orig
     admin.close()
     srv.stop()
 

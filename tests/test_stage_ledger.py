@@ -121,56 +121,74 @@ def best_of(attempts, measure, within):
     raise AssertionError((got, want, detail))
 
 
-def test_launch_loop_stages_add_up_to_the_batcher_threads_wall_clock(rank):
-    """Every second between two window ends is in exactly one stage: over 50
-    windows the stages' totals are within 2% of the thread's own clock."""
-    best_of(3, lambda: launch_loop_closure(rank), 0.02)
-    # the launch's own three stages are what launch-to-fetch is made of
-    e = rank["idx"].perf.summary()
-    inner = sum(e[n]["total_s"] for n in
-                ("engine.feed", "engine.scan", "engine.refine_fetch"))
-    assert abs(inner - e["device_search_s"]["total_s"]) <= (
-        0.02 * e["device_search_s"]["total_s"])
-    assert e["engine.scan"]["count"] == e["device_search_s"]["count"]
+# what a merged window books once, two in flight or not (ISSUE 41, Tentpole 7)
+ONCE_A_WINDOW = ("sched.assemble", "sched.split", "batch_rows",
+                 "engine.lock_wait", "engine.feed", "engine.scan",
+                 "engine.refine_fetch", "engine.join", "device_search_s",
+                 "device_search_rows")
 
 
-def launch_loop_closure(rank):
+def test_every_launch_loop_stage_is_booked_once_a_window_with_two_in_flight(rank):
+    """The batcher launches and the completer collects, so the stages no
+    longer add up to one thread's wall clock; what holds: over 50 windows
+    every stage and count row of the launch loop is booked once a window,
+    ``engine.launch_overlapped`` counts windows (some, never more than
+    there were), and the launch's own three stages are what
+    launch-to-fetch is made of — the wait behind the window ahead lies in
+    ``engine.scan``."""
+    best_of(3, lambda: launch_loop_closure(
+        rank, ("engine.scan_adc_cols", "engine.scan_adc_cols_skipped")), 0.03)
+
+
+def launch_loop_closure(rank, once_a_scan=()):
     sched, idx = rank["srv"].scheduler, rank["idx"]
-    windows = 51
-    marks, totals = [], []
+    windows = 50
 
     def booked():
-        rows = {**sched.stats.summary(), **idx.perf.summary()}
-        return {n: rows.get(n, {"total_s": 0.0})["total_s"]
-                for n in tracing.LAUNCH_LOOP}
+        rows = {**sched.stats.summary(), **idx.perf_stats()}
+        return {n: dict(rows.get(n, tracing.zero_row())) for n in (
+            *tracing.LAUNCH_LOOP, *ONCE_A_WINDOW, *once_a_scan,
+            "queue_wait_s", "engine.launch_overlapped")}
 
-    serve, enough, callers = sched._serve, threading.Event(), []
+    def settled():
+        """Nothing queued, in flight or still being split."""
+        deadline = time.time() + 10
+        while time.time() < deadline:
+            rows = booked()
+            if (sched.perf_stats()["counters"]["batches"]
+                    == rows["device_search_s"]["count"] == rows["sched.split"]["count"]):
+                return rows
+            time.sleep(0.01)
+        raise AssertionError("the windows in flight never drained")
 
-    def serve_and_mark(batch):  # runs on the batcher thread, at a window end
-        serve(batch)
-        if len(marks) < windows:
-            marks.append(tracing.now())
-            if len(marks) in (1, windows):
-                totals.append(booked())
-            if len(marks) == windows:
-                enough.set()
-
-    sched._serve = serve_and_mark
+    before = settled()
+    enough = threading.Event()
+    callers = drive(rank, until=enough)
     try:
-        # until the windows are made, not a number of requests: four callers
-        # that fall into step share every window, and 30 requests each then
-        # make 30 windows
-        callers = drive(rank, until=enough)
-        enough.wait(60)
+        deadline = time.time() + 60
+        while (idx.perf.summary()["device_search_s"]["count"]
+               < before["device_search_s"]["count"] + windows):
+            assert time.time() < deadline, "the drive made too few windows"
+            time.sleep(0.01)
     finally:
         enough.set()
         for t in callers:
             t.join()
-        sched._serve = serve
-    assert len(marks) == windows, "the drive made too few windows"
-    wall = marks[-1] - marks[0]
-    by_stage = {n: totals[1][n] - totals[0][n] for n in tracing.LAUNCH_LOOP}
-    return sum(by_stage.values()), wall, by_stage
+    after = settled()
+
+    def moved(name, field="count"):
+        return after[name][field] - before[name][field]
+
+    n = moved("device_search_s")
+    assert n >= windows
+    for name in (*ONCE_A_WINDOW, *once_a_scan):
+        assert moved(name) == n, (name, moved(name), n)
+    assert moved("queue_wait_s") >= n  # once a request
+    assert 0 < moved("engine.launch_overlapped") <= n
+    inner = sum(moved(name, "total_s") for name in
+                ("engine.feed", "engine.scan", "engine.refine_fetch"))
+    return inner, moved("device_search_s", "total_s"), {
+        name: moved(name, "total_s") for name in tracing.LAUNCH_LOOP}
 
 
 def test_a_requests_client_stages_add_up_to_client_search(rank):

@@ -151,7 +151,7 @@ def _may_launch(model):
             if not isinstance(sub, ast.Call):
                 continue
             cn = call_name(sub)
-            if cn in ("pallas_call", "pallas_guarded") or (
+            if cn in ("pallas_call", "pallas_guarded", "GuardedScan") or (
                     cn in registry_names) or (
                     cn in model.jitted_names and cn not in HOT_EDGE_STOPLIST):
                 launching.add(id(fi))

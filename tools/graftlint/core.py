@@ -448,7 +448,7 @@ class ModuleInfo:
                 cn = call_name(n)
                 if cn == "pallas_call":
                     unit.has_pallas_call = True
-                if cn == "pallas_guarded":
+                if cn in ("pallas_guarded", "GuardedScan"):
                     unit.calls_pallas_guarded = True
             for child in ast.iter_child_nodes(n):
                 if isinstance(child, (ast.FunctionDef, ast.AsyncFunctionDef,
