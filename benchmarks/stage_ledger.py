@@ -1,17 +1,26 @@
 #!/usr/bin/env python3
-"""Both stage ledgers of one benchmark cell, from an UNTRACED run's counters.
+"""Both stage ledgers of one benchmark cell, and the chip's timeline, from a
+run's counters.
 
     python3 benchmarks/stage_ledger.py --workload <cell> --seed <n> --seconds <s>
-        [--profile <seconds>]
+        [--trace 1] [--profile <seconds>]
 
 ``perfbench.run --trace 0`` with one thing added: two ``get_perf_stats``
 snapshots around its window, which the benchmark takes in traced runs only
 (ROADMAP S2, item (c): once it takes them always, this script goes). Prints
-every per-layer metric the snapshots can feed, and the two closures of
-docs/OPERATIONS.md's stage ledger. ``--profile`` has every rank run its
-``profile`` op that long, three seconds into the window. Beside a ``--trace
-1`` run of the same cell and seed the numbers size what the profiler does to
-a run. A builder's tool: no benchmark file reads it.
+every per-layer metric the snapshots can feed — the benchmark's own, and
+every reader file under ``perfbench/layer_metrics/`` that no entry of
+``BENCHMARK.json`` names yet (the chip timeline's ten, PR 42) — the two
+closures of docs/OPERATIONS.md's stage ledger, and the chip's timeline as
+the scheduler books it, a rank: its five rows over the window, whether busy
+plus idle is what ``chip_timeline_s`` moved by, and, under ``--trace 1``, the
+device trace's busy seconds and busy time a launch beside them.
+``--profile`` has every rank run its ``profile`` op that long, three seconds
+into the window, with a snapshot of the counters either side of the call, so
+that ``idle_by_cause`` can be laid beside the ``sched.chip_idle.*`` rows of
+the same seconds. Beside a ``--trace 1`` run of the same cell and seed the
+untraced numbers size what the profiler does to a run. A builder's tool: no
+benchmark file reads it.
 """
 
 import argparse
@@ -28,7 +37,7 @@ from distributed_faiss_tpu.utils import tracing  # noqa: E402
 from perfbench import ledger, loader, run, stats  # noqa: E402
 
 OBS = {}
-untraced_window = run.measure
+the_window = run.measure
 
 
 def measure(profile_s):
@@ -37,25 +46,83 @@ def measure(profile_s):
             time.sleep(3.0)
             # on threads of its own: a worker of the client's fan-out pool
             # held for the session would be a request less in flight
+            def one(stub):
+                before = stub.generic_fun("get_perf_stats", ())
+                reply = stub.generic_fun("profile", (profile_s,),
+                                         timeout=profile_s + 120)
+                after = stub.generic_fun("get_perf_stats", ())
+                reply["counters_meanwhile"] = chip_rows(before, after)
+                return reply
+
             with ThreadPoolExecutor(len(client.sub_indexes)) as own:
-                OBS["profiles"] = list(own.map(
-                    lambda s: s.generic_fun("profile", (profile_s,),
-                                            timeout=profile_s + 120),
-                    client.sub_indexes))
+                OBS["profiles"] = list(own.map(one, client.sub_indexes))
 
         asker = threading.Thread(target=ask, name="profile-asker")
         before = run.perf_stats(client)
         if profile_s:
             asker.start()
-        obs, t0 = untraced_window(client, *args, **kwargs)
+        obs, t0 = the_window(client, *args, **kwargs)
         after = run.perf_stats(client)
         if profile_s:
             asker.join()
-        OBS.update(obs, stats_before=before, stats_after=after,
+        # (a traced window has taken its own pair, closer to the window)
+        OBS.update({"stats_before": before, "stats_after": after, **obs},
                    index_id=run.INDEX_ID)
         return obs, t0
 
     return with_snapshots
+
+
+def chip_rows(before, after):
+    """One rank's timeline between two snapshots: every row's count and
+    seconds, what ``chip_timeline_s`` (the last ``ready`` less the first
+    ``dispatched``) moved by, and busy + idle less that: 0 by construction."""
+    def moved(name, field):
+        a, b = (stats.dig(s, ledger.sched(name)) or {} for s in (after, before))
+        return a.get(field, 0) - b.get(field, 0)
+
+    line = [stats.dig(s, ("scheduler", "counters", "chip_timeline_s"))
+            for s in (before, after)]
+    if line[1] is None:
+        return None  # a program without the timeline
+    out = {name: [moved(name, "count"), moved(name, "total_s")]
+           for name in tracing.CHIP_ROWS}
+    out["chip_timeline_s"] = line[1] - (line[0] or 0.0)
+    out["busy_plus_idle_less_timeline_s"] = (
+        sum(out[name][1] for name in tracing.CHIP_ROWS if name != "sched.chip_queue")
+        - out["chip_timeline_s"])
+    return out
+
+
+def chip_timeline(obs):
+    """Per rank: the rows over the window and, in a traced run, the device
+    trace's busy seconds and launches beside them."""
+    out = []
+    for i, (before, after) in enumerate(zip(obs["stats_before"], obs["stats_after"])):
+        rank = chip_rows(before, after)
+        if rank is None:
+            return None
+        launches = stats.window_count(before, after,
+                                      ledger.engine(obs, "device_search_s"))
+        rank["launches"] = launches
+        if obs.get("traces"):
+            busy = obs["traces"][i]["busy_s"]
+            rank["trace_busy_s"] = busy
+            rank["trace_busy_ms_a_launch"] = 1e3 * busy / launches if launches else None
+            rank["trace_idle_pct"] = 100.0 * (1.0 - busy / obs["window_s"])
+        out.append(rank)
+    return out
+
+
+def unlisted_readers(cell):
+    """The reader files no entry of ``BENCHMARK.json`` names (yet), for this
+    cell's kind: ``.online`` ones where it is judged on latency."""
+    listed = {m["name"] for m in cell.bench["per_layer"]}
+    online = "lat_p50_ms" in cell.end_to_end()
+    folder = os.path.join(cell.root, "perfbench", "layer_metrics")
+    for name in sorted(f[:-3] for f in os.listdir(folder) if f.endswith(".py")):
+        if name not in listed and name.endswith(".online") == online:
+            yield name, loader.load_module(os.path.join(folder, f"{name}.py"))
 
 
 def closures(obs):
@@ -85,29 +152,37 @@ def closures(obs):
     }
 
 
-def main():
+def main(argv=None, **rehearsal):
+    """``rehearsal``: ``perfbench.run.main``'s own keywords, for a run on the
+    CPU over a cut copy of the benchmark; the command line cannot reach them."""
     ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
     ap.add_argument("--workload", required=True)
     ap.add_argument("--seed", type=int, required=True)
     ap.add_argument("--seconds", type=float, required=True)
     ap.add_argument("--profile", type=float, default=0.0)
-    args = ap.parse_args()
+    ap.add_argument("--trace", type=int, choices=(0, 1), default=0)
+    args = ap.parse_args(argv)
     run.measure = measure(args.profile)
     rc = run.main(["--workload", args.workload, "--seed", str(args.seed),
-                   "--seconds", str(args.seconds), "--trace", "0"])
+                   "--seconds", str(args.seconds), "--trace", str(args.trace)],
+                  **rehearsal)
     if rc or not OBS:
         return rc or 1
-    cell = loader.Cell(args.workload)
+    cell = loader.Cell(args.workload, rehearsal.get("root", loader.ROOT))
     OBS.update(setup={}, config=cell.config, traffic=cell.traffic)
     metrics = {}
-    for metric, reader in cell.layer_readers():
+    readers = [(m["name"], r) for m, r in cell.layer_readers()]
+    for name, reader in readers + list(unlisted_readers(cell)):
         try:
-            metrics[metric["name"]] = reader.read(OBS)
+            metrics[name] = reader.read(OBS)
         except (KeyError, TypeError):  # needs the trace or the set-up's facts
             continue
     print("LEDGER " + json.dumps({
         "cell": args.workload, "seed": args.seed, "window_s": OBS["window_s"],
-        "per_layer_untraced": {k: v for k, v in metrics.items() if v is not None},
+        "traced": bool(args.trace),
+        "per_layer": {k: v for k, v in metrics.items() if v is not None},
+        "not_read": sorted(k for k, v in metrics.items() if v is None),
+        "chip_timeline": chip_timeline(OBS),
         "closures": closures(OBS), "profiles": OBS.get("profiles"),
     }, default=str), flush=True)
     return 0

@@ -485,7 +485,10 @@ class SearchHandle:
 
 
 def finished(result) -> SearchHandle:
-    """The handle of a search that ran to its end in the launch."""
+    """The handle of a search that ran to its end in the launch: its
+    outputs are in hand here, which the scheduler's timeline of the chip
+    takes for the window's ``ready`` where no collect reads a later one."""
+    tracing.instant("ready")
     return SearchHandle(lambda: result)
 
 
@@ -532,8 +535,10 @@ class _Unit:
     def __init__(self, fn, chunk, counts, refine_fn, rows: int):
         self.rows, self.chunk, self.refine_fn = rows, chunk, refine_fn
         self.stage = tracing.handover("engine.scan")
-        with self.stage:
+        with self.stage, tracing.stage("engine.dispatch"):
             out = fn(chunk, *counts)
+            # the window's first program is on its way to the chip
+            tracing.instant("dispatched", first=True)
             self.dispatched = out if isinstance(out, Dispatched) else Dispatched(out)
             vals, self.ids = self.dispatched.out[:2]
             self.out = self._reranked(vals, self.ids)
@@ -553,6 +558,9 @@ class _Unit:
                 self.out = self._reranked(vals, ids)
             with xfercheck.explicit("blocked_search result fetch"):
                 vals, ids = (np.asarray(a) for a in self.out)
+            # the unit's last outputs are in hand (the window's, once its
+            # last unit says so)
+            tracing.instant("ready")
             return (vals.reshape(-1, vals.shape[-1])[: self.rows],
                     ids.reshape(-1, ids.shape[-1])[: self.rows])
 

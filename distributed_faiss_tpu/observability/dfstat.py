@@ -12,7 +12,12 @@ diffs the cumulative counters against the previous poll with the shared
 ad-hoc CLI arithmetic), and renders one line per rank: search rate and
 latency percentiles, scheduler queue depth/shed/busy, mux in-flight,
 anti-entropy sweep health and suspects, and per-index mutation
-live-fraction. ``--watch`` redraws every ``--interval`` seconds;
+live-fraction. Under each rank that collected a window in the interval
+a ``chip`` line says where its chip's time went, from the scheduler's
+timeline rows (``sched.chip_busy`` and the three ``sched.chip_idle.*``,
+docs/OPERATIONS.md#stage-ledger): busy %, idle % by cause, a window's own
+milliseconds on the chip — utilisation with no profiler session.
+``--watch`` redraws every ``--interval`` seconds;
 ``--json`` emits one machine-readable JSON document per poll instead.
 
 ``--trace <id>`` switches to the distributed-trace view: every rank's
@@ -27,8 +32,9 @@ any sampled client's logs).
 ``--profile <seconds>`` asks every rank to run a profiler session on
 itself for that long (the ``profile`` op, observability/profile.py) and
 prints each rank's reduction: device busy and idle seconds, the idle
-seconds by the host stage that was open meanwhile, and device seconds by
-named scope. ``--keep`` leaves each rank's ``.xplane.pb`` in its storage
+seconds by the host stage that was open on the batcher thread meanwhile
+and by cause (the timeline rows' names), busy seconds a launch, and device
+seconds by named scope. ``--keep`` leaves each rank's ``.xplane.pb`` in its storage
 directory and prints where.
 """
 
@@ -40,7 +46,7 @@ from concurrent.futures import ThreadPoolExecutor
 
 from distributed_faiss_tpu.observability import spans as obs_spans
 from distributed_faiss_tpu.parallel import replication, rpc
-from distributed_faiss_tpu.utils.tracing import LatencyStats
+from distributed_faiss_tpu.utils.tracing import CHIP_IDLE, LatencyStats
 
 
 def _connect(discovery_path: str, connect_timeout: float = 3.0):
@@ -130,6 +136,7 @@ def _rate_row(prev: dict, cur: dict, dt: float) -> dict:
         "shed": counter_delta("shed_deadline"),
         "busy": counter_delta("rejected_busy"),
     })
+    row["chip"] = _chip_row((prev or {}).get("scheduler"), sched)
     row["in_flight"] = (cur.get("rpc") or {}).get("in_flight", 0)
     repl = cur.get("replication") or {}
     row["rank"] = repl.get("rank")
@@ -143,6 +150,40 @@ def _rate_row(prev: dict, cur: dict, dt: float) -> dict:
             if isinstance(m, dict) and m.get("live_fraction") is not None]
     row["live_frac"] = min(live) if live else 1.0
     return row
+
+
+def _chip_row(prev_sched, sched):
+    """Where the chip's time went in the interval, from the scheduler's
+    timeline rows: ``busy_pct`` and ``idle_pct`` by cause (of the timeline
+    the interval's windows cover: busy plus idle), a window's own
+    ``busy_ms`` and its ``queue_ms`` behind the window ahead. None where
+    the rank collected no window in it, or has no such rows."""
+    rows = LatencyStats.delta((prev_sched or {}).get("queues"),
+                              (sched or {}).get("queues") or {})
+    busy = rows.get("sched.chip_busy")
+    if not busy or not busy["count"]:
+        return None
+    idle = {row.rsplit(".", 1)[1]: rows.get(row, {}).get("total_s", 0.0)
+            for row in CHIP_IDLE}
+    timeline = busy["total_s"] + sum(idle.values())
+    return {
+        "windows": busy["count"],
+        "busy_pct": 100.0 * busy["total_s"] / timeline if timeline else 0.0,
+        "idle_pct": {c: 100.0 * s / timeline if timeline else 0.0
+                     for c, s in idle.items()},
+        "busy_ms": 1e3 * busy["interval_mean_s"],
+        "queue_ms": 1e3 * rows.get("sched.chip_queue", {}).get(
+            "interval_mean_s", 0.0),
+    }
+
+
+def _render_chip(chip: dict) -> str:
+    idle = chip["idle_pct"]
+    return (f"     └ chip: busy {chip['busy_pct']:.1f}%, idle "
+            f"{sum(idle.values()):.1f}% (empty {idle['empty']:.1f}, "
+            f"window_wait {idle['window_wait']:.1f}, host {idle['host']:.1f}); "
+            f"{chip['windows']} windows, {chip['busy_ms']:.2f} ms on the chip "
+            f"each, {chip['queue_ms']:.2f} ms behind the one ahead")
 
 
 _HEADER = (f"{'rank':>4} {'grp':>3} {'srch/s':>8} {'ms':>7} {'p99ms':>8} "
@@ -178,6 +219,8 @@ def render_stats(prev: list, cur: list, dt: float, as_json: bool) -> str:
         rows.append(row)
         if not as_json:
             lines.append(_render_row(row))
+            if row.get("chip"):
+                lines.append(_render_chip(row["chip"]))
             if row.get("p99_exemplar"):
                 lines.append(f"     └ p99 exemplar trace: "
                              f"{row['p99_exemplar']} "
@@ -258,8 +301,14 @@ def render_profiles(profiles: list, as_json: bool) -> str:
             f"({100 * p['idle_attributed_share']:.1f}% of it on a named "
             f"host stage); {p['ops_with_scope']} of {p['ops']} device "
             f"operations carry a scope")
-        lines.append("  idle seconds by host stage:")
+        lines.append("  idle seconds by host stage (the batcher thread's):")
         lines += [f"    {s:>10.4f}  {n}" for n, s in p["idle_by_stage"]]
+        if p.get("idle_by_cause"):
+            lines.append("  idle seconds by cause (beside the sched.chip_idle.* rows):")
+            lines += [f"    {s:>10.4f}  {n}" for n, s in p["idle_by_cause"].items()]
+        if p.get("busy_per_launch_s"):
+            lines.append(f"  device busy a launch: {1e3 * p['busy_per_launch_s']:.3f} ms "
+                         "(beside sched.chip_busy's mean)")
         lines.append("  device seconds by scope (else HLO name):")
         lines += [f"    {s:>10.4f}  {n}" for n, s in p["device_by_scope"]]
         if p.get("xplane"):

@@ -11,10 +11,23 @@ turns the rank's own ``.xplane.pb`` into
 - **idle seconds by host stage**: the rank's stages are profiler events
   (``utils/tracing.stage`` -> ``TraceAnnotation``) on the clock of the
   device's ``XLA Ops`` line, so every idle gap is split among the
-  launch-loop stages open on the batcher thread during it, by overlap;
-  what lies between two stages of a launch goes to ``engine.launch`` or
-  ``server.device``, the subtotals around them, and what no stage covers
-  is ``unattributed``;
+  launch-loop stages open on the **batcher's line** of the host plane
+  during it, by overlap; what lies between two stages of a launch goes to
+  ``engine.launch`` or ``server.device``, the subtotals around them, and
+  what no stage covers is ``unattributed``. The launch loop runs on two
+  threads (the batcher launches, the completer collects, two windows in
+  flight), and only the batcher's work precedes a dispatch: the completer's
+  stages (``engine.refine_fetch``, ``engine.join``, ``sched.split``, the
+  wait leg of ``engine.scan``) run beside it and take no gap. The batcher's
+  line is the one that holds ``sched.assemble`` events; its leg of
+  ``engine.scan`` is the dispatch and reads as ``engine.dispatch``;
+- **idle seconds by cause**, under the names of the scheduler's
+  ``sched.chip_idle.*`` counters (serving/scheduler.py), so the program's
+  clock and the device's lie side by side: ``sched.idle`` is ``empty``,
+  ``sched.window_wait`` is ``window_wait``, all else ``host``;
+- ``busy_per_launch_s``: busy seconds over the launches that end in the
+  window (the batcher's ``engine.launch`` events), beside the counters'
+  ``sched.chip_busy`` mean;
 - device seconds by named scope (``coarse``, ``list_scan``, ``merge_topk``,
   ``refine``: models/ivf.py) where an operation's event carries its scope
   path, else by HLO instruction name.
@@ -119,7 +132,10 @@ def read_xplane(path: str):
     scope, is_op)`` of every event, ``is_op`` marking a device operation —
     an event of a device plane's ``XLA Ops`` line, or (the CPU backend,
     which has no device plane) any event carrying an ``hlo_op`` stat — and
-    the session's own ``profile_start_time`` / ``profile_stop_time``."""
+    the session's own ``profile_start_time`` / ``profile_stop_time``. A
+    host line is a thread, and python's threads all bear the process's
+    name: the n-th line of a name in its plane is ``<name>/<n>`` here, so
+    that a thread's events can be told from another's."""
     from jax.profiler import ProfileData
 
     rows, facts = [], {}
@@ -128,12 +144,16 @@ def read_xplane(path: str):
             if key in ("profile_start_time", "profile_stop_time"):
                 facts[key] = int(value)
         device = plane.name.startswith(DEVICE_PLANE)
+        seen = defaultdict(int)
         for line in plane.lines:
+            seen[line.name] += 1
+            n = seen[line.name]
+            label = line.name if n == 1 else f"{line.name}/{n}"
             for ev in line.events:
                 stats = dict(ev.stats)
                 is_op = (line.name == OPS_LINE if device
                          else "hlo_op" in stats)
-                rows.append((plane.name, line.name, ev.name,
+                rows.append((plane.name, label, ev.name,
                              int(ev.start_ns), int(ev.duration_ns),
                              _scope_of(stats) if is_op else None, is_op))
     return rows, facts
@@ -158,9 +178,11 @@ def _ranked(totals, top):
 def _book(pieces, intervals, idle_by):
     """Book to each interval's name the nanoseconds it shares with the
     ``pieces`` (sorted ``(start, end)``); returns what is left of them.
-    ``intervals`` are sorted ``(start, end, name)`` of one thread, so
-    disjoint; where two ranks share a process and they overlap, the
-    earlier one takes the shared part."""
+    ``intervals`` are sorted ``(start, end, name)`` of the batcher's line
+    alone (``reduce_rows`` leaves the completer's out), so disjoint but for
+    ``engine.dispatch`` inside the first leg of ``engine.scan``, which bear
+    one name here; where two ranks share a process and their batchers'
+    overlap, the earlier one takes the shared part."""
     left, first = [], 0
     for a, b in pieces:
         while first < len(intervals) and intervals[first][1] <= a:
@@ -182,7 +204,11 @@ def _book(pieces, intervals, idle_by):
 
 # what a gap is booked to, in this order: the launch loop's own stages,
 # then the subtotals around them (the python between two stages of a launch)
-_LEVELS = (frozenset(tracing.LAUNCH_LOOP), {"engine.launch"}, {"server.device"})
+_LEVELS = (frozenset(tracing.LAUNCH_LOOP) | {"engine.dispatch"},
+           {"engine.launch"}, {"server.device"})
+BATCHER_STAGE = "sched.assemble"  # only the batcher thread books it
+# on the batcher's line ``engine.scan`` is its first leg: the dispatch
+_ON_BATCHER = {"engine.scan": "engine.dispatch"}
 
 
 def reduce_rows(rows, window_ns=None, top=12) -> dict:
@@ -194,8 +220,15 @@ def reduce_rows(rows, window_ns=None, top=12) -> dict:
     at all: the first event's start to the last event's end)."""
     ops = [(s, s + d, n, scope) for _, _, n, s, d, scope, is_op in rows
            if is_op and d > 0]
-    levels = [sorted((s, s + d, n) for _, _, n, s, d, _, is_op in rows
-                     if not is_op and n in names) for names in _LEVELS]
+    # the batcher's lines; a session with none (no scheduler in the
+    # process: the in-process batcher runs a launch on its caller's thread)
+    # is judged on every line, as one thread
+    batcher = {(p, l) for p, l, n, *_ in rows if n == BATCHER_STAGE}
+    levels = [sorted((s, s + d, _ON_BATCHER.get(n, n) if batcher else n)
+                     for p, l, n, s, d, _, is_op in rows
+                     if not is_op and n in names
+                     and (not batcher or (p, l) in batcher))
+              for names in _LEVELS]
     stages = levels[0]
     if not ops:
         return {"error": "the session holds no device operation",
@@ -224,6 +257,10 @@ def reduce_rows(rows, window_ns=None, top=12) -> dict:
         scoped += scope is not None
     busy = sum(e - s for s, e in merged)
     idle = sum(e - s for s, e in gaps)
+    by_cause = dict.fromkeys(tracing.CHIP_IDLE, 0)
+    for name, ns in idle_by.items():
+        by_cause[tracing.CHIP_IDLE_CAUSE.get(name, tracing.CHIP_IDLE_HOST)] += ns
+    launches = sum(lo < e <= hi for _, e, _ in levels[1]) if batcher else 0
     return {
         "window_s": (hi - lo) / 1e9,
         "busy_s": busy / 1e9,
@@ -232,6 +269,8 @@ def reduce_rows(rows, window_ns=None, top=12) -> dict:
         # operations of one program; busy + idle + sequencing = window
         "sequencing_s": (hi - lo - busy - idle) / 1e9,
         "idle_by_stage": _ranked(idle_by, top),
+        "idle_by_cause": {n: ns / 1e9 for n, ns in by_cause.items()},
+        "busy_per_launch_s": busy / 1e9 / launches if launches else None,
         "idle_attributed_share": (1.0 - idle_by.get("unattributed", 0) / idle
                                   if idle else 1.0),
         "device_by_scope": _ranked(by_scope, top),

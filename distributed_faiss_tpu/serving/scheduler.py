@@ -80,6 +80,21 @@ window n's collect (a stage that raises books nothing). A sampled request (its s
 merge window it landed in and its occupancy) and ``server.device`` spans
 in its submitter's SpanBuffer, and stamps the latency histograms'
 exemplars (observability/spans.py).
+
+The chip's timeline. One rank serves one chip and this loop is the one
+place that knows the order in which windows reach it, so the completer
+books, once a collected window, where the chip's time went
+(``_book_chip``): ``sched.chip_busy`` (the window's own span on the chip,
+as the host sees it), ``sched.chip_queue`` (its programs' wait behind the
+window ahead) and, where the chip stood idle before it,
+``sched.chip_idle.empty`` / ``.window_wait`` / ``.host`` by what the batcher
+thread was in meanwhile. Two ``tracing.instant`` readings a window feed it,
+handed up through the ``server.device`` handover by whatever ran inside it
+(``models/base._Unit``): ``dispatched`` (the first device program's dispatch
+call returned; where nothing says so, the launch's start) and ``ready`` (the
+window's last outputs are in hand; where nothing says so, the collect's
+end). Over any run of collected windows busy plus idle is the last
+``ready`` less the first ``dispatched`` (``counters["chip_timeline_s"]``).
 """
 
 import logging
@@ -211,11 +226,14 @@ class _Finished:
 class _Window:
     """A launched window on its way to the completer."""
 
-    __slots__ = ("live", "handle", "device", "traced")
+    __slots__ = ("live", "handle", "device", "traced", "waits")
 
     def __init__(self, live, handle, device, traced):
         self.live, self.handle, self.device, self.traced = (
             live, handle, device, traced)
+        # (start, end, stage) of the batcher's waits since its last launch:
+        # what a gap on the chip before this window is put down to
+        self.waits = ()
 
 
 class SearchScheduler:
@@ -236,8 +254,12 @@ class SearchScheduler:
                  name: str = "search-batcher", tag: Optional[dict] = None):
         launch = getattr(search_fn, "launch", None)
         if launch is None:
-            # no two-phase form: the launch is the whole search
-            launch = lambda *call: _Finished(search_fn(*call))
+            # no two-phase form: the launch is the whole search, and its end
+            # the moment the window's outputs are in hand
+            def launch(*call):
+                result = search_fn(*call)
+                tracing.instant("ready")
+                return _Finished(result)
         self._launch_fn = launch
         self.cfg = cfg if cfg is not None else SchedulerCfg()
         # replica identity riding the stats surface (replication layer):
@@ -255,6 +277,21 @@ class SearchScheduler:
         self._newest_rows = 0  # rows of the window launched last
         self._batcher_done = False
         self._stopping = False
+        # the batcher's own (filled and emptied in place): its sched.idle /
+        # sched.window_wait intervals since its last launch, handed over
+        # with the next window
+        self._waits: List[Tuple[float, float, str]] = []
+        # the completer's own (in place too): the waits of windows that died
+        # or were empty, for the next good window's gap
+        self._unbooked: List[Tuple[float, float, str]] = []
+        # the chip's timeline: _chip_free is the largest ``ready`` of the
+        # windows collected so far, _chip_first the first one's
+        # ``dispatched``. Both and a window's rows move together under
+        # _chip_lock, so that a snapshot's rows add up to its
+        # chip_timeline_s (a leaf beside _cond: never held with it)
+        self._chip_first: Optional[float] = None
+        self._chip_free: Optional[float] = None
+        self._chip_lock = lockdep.lock("SearchScheduler._chip_lock")
         self.stats = LatencyStats()
         # admission/flush counters ride the shared atomic-counter helper
         # (utils/atomics.py): the fast paths bump them without contending
@@ -396,6 +433,9 @@ class SearchScheduler:
                     [r for r in batch if not r.event.is_set()],
                     _Finished(error=RuntimeError("scheduled search aborted")),
                     None, ())
+            # the batcher's waits since its last launch go with the window
+            window.waits = tuple(self._waits)
+            self._waits.clear()
             # to the completer, which publishes in launch order — a window
             # with nothing to collect (all shed, or dead above) too: it
             # holds its place in flight until its turn
@@ -418,8 +458,9 @@ class SearchScheduler:
                     # bounds the window in which a lost/raced notify (or
                     # an interpreter bug) could strand the batcher — the
                     # loop re-checks the queue and stop flag each lap
-                    with tracing.stage("sched.idle", sink=self.stats):
+                    with tracing.stage("sched.idle", sink=self.stats) as st:
                         self._cond.wait(timeout=1.0)
+                    self._waited(st)
                     continue
                 head = self._queue[0]
                 rows = sum(r.rows for r in self._queue if r.key == head.key)
@@ -443,8 +484,9 @@ class SearchScheduler:
                 if (self._in_flight >= self.IN_FLIGHT
                         or (not full and (self._in_flight or now < flush_at))):
                     timeout = 1.0 if self._in_flight else flush_at - now
-                    with tracing.stage("sched.window_wait", sink=self.stats):
+                    with tracing.stage("sched.window_wait", sink=self.stats) as st:
                         self._cond.wait(timeout)
+                    self._waited(st)
                     continue
                 # pop whole compatible requests until the row budget is
                 # reached; a single over-budget request still goes alone
@@ -462,6 +504,17 @@ class SearchScheduler:
                 self._newest_rows = taken_rows
                 self.stats.record("queue_depth", float(len(rest)))
                 return taken
+
+    def _waited(self, st: tracing.stage) -> None:
+        """Keep a finished wait of the batcher (``sched.idle``,
+        ``sched.window_wait``) for the next window's gap on the chip; a lap
+        of the same wait right behind the last one extends it (an idle rank
+        laps every second)."""
+        waits, end = self._waits, st.t0 + st.dt
+        if waits and waits[-1][2] == st.name:
+            waits[-1] = (waits[-1][0], end, st.name)
+        else:
+            waits.append((st.t0, end, st.name))
 
     def _launch(self, batch: List[_Request]) -> _Window:
         """A window's first half, on the batcher thread: assemble and
@@ -512,7 +565,7 @@ class SearchScheduler:
         # echoed into its own trace
         traced = [r.ticket for r in live if r.ticket is not None]
         device = tracing.handover("server.device", sink=tracing.SPAN_ONLY,
-                                  window=window, rows=n_rows)
+                                  instants=True, window=window, rows=n_rows)
         try:
             # DFT_XFERCHECK=1 arms jax's transfer guard for the whole
             # merged-window launch (and again for its collect): any
@@ -552,14 +605,17 @@ class SearchScheduler:
             if device is None:
                 result = window.handle.collect()
             else:
-                with device.last(), \
-                        xfercheck.guarded("scheduler merge-window flush"):
-                    result = window.handle.collect()
+                with device.last():
+                    with xfercheck.guarded("scheduler merge-window flush"):
+                        result = window.handle.collect()
+                    self._book_chip(window, tracing.now())
                 for ticket in window.traced[1:]:
                     device.echo(ticket)
         except Exception as exc:
             error = exc
         finally:
+            # not booked: the next good window's gap has them
+            self._unbooked.extend(window.waits)
             with self._cond:
                 self._in_flight -= 1
                 self._cond.notify_all()
@@ -597,6 +653,50 @@ class SearchScheduler:
             for r in live:
                 self._finish(r)
 
+    def _book_chip(self, window: _Window, collected: float) -> None:
+        """The chip's timeline, once a collected window, on the completer
+        thread (inside the last leg of ``server.device``, whose span takes
+        the numbers as fields): from ``free``, the largest ``ready`` of the
+        windows before it, the chip was idle until this window's
+        ``dispatched`` or busy with the window ahead beyond it, then busy
+        with this one until its ``ready``. The idle gap is split by what
+        the batcher thread was in: ``sched.idle`` (no request queued) is
+        ``empty``, ``sched.window_wait`` is ``window_wait``, all else (the
+        assemble, the engine's lock and feed, the dispatch, the python
+        between them) ``host``. A window that died never comes here: it
+        books nothing and leaves ``free`` where it was."""
+        device, record = window.device, self.stats.record
+        dispatched = device.instants.get("dispatched", device.t0)
+        ready = device.instants.get("ready", collected)
+        waits = self._unbooked + list(window.waits)
+        self._unbooked.clear()
+        window.waits = ()
+        exemplar = window.traced[0][0] if window.traced else None
+        with self._chip_lock:
+            free = self._chip_free
+            if free is None:  # the rank's first window: the timeline starts here
+                self._chip_first = free = dispatched
+            start = max(dispatched, free)
+            ready = max(ready, start)  # (an index off the chip may end out of turn)
+            if dispatched > free:
+                idle, left = {}, dispatched - free
+                for t0, t1, name in waits:
+                    share = min(t1, dispatched) - max(t0, free)
+                    if share > 0:
+                        row = tracing.CHIP_IDLE_CAUSE[name]
+                        idle[row] = idle.get(row, 0.0) + share
+                        left -= share
+                if left > 0:
+                    idle[tracing.CHIP_IDLE_HOST] = left
+                for name, seconds in idle.items():
+                    record(name, seconds, exemplar=exemplar)
+            record("sched.chip_busy", ready - start, exemplar=exemplar)
+            record("sched.chip_queue", start - dispatched, exemplar=exemplar)
+            self._chip_free = ready
+        device.extra.update(chip_busy_s=ready - start,
+                            chip_queue_s=start - dispatched,
+                            idle_before_s=start - free)
+
     # ------------------------------------------------------------- lifecycle
 
     def stop(self) -> None:
@@ -629,7 +729,17 @@ class SearchScheduler:
             # reads are adjacent, not a cross-field consistency guarantee.
             counters = self._counters.snapshot()
             counters["queued"] = len(self._queue)
-        out = {"counters": counters, "queues": self.stats.summary(raw=raw)}
+        with self._chip_lock:
+            queues = self.stats.summary(raw=raw)
+            first, free = self._chip_first, self._chip_free
+        if free is not None:
+            # a cause that never took a gap reads 0, not a missing row; and
+            # what busy and idle add up to: the last ``ready`` less the
+            # first ``dispatched`` (queue lies inside another window's busy)
+            for name in tracing.CHIP_ROWS:
+                queues.setdefault(name, tracing.zero_row())
+            counters["chip_timeline_s"] = free - first
+        out = {"counters": counters, "queues": queues}
         if self.tag:
             out["replica"] = dict(self.tag)
         return out

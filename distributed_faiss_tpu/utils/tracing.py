@@ -23,7 +23,10 @@ The reference has none of this beyond log lines (SURVEY §5.1); here:
   a span with its own id and its parent's. ``bind`` / ``ticket`` hand the
   sampled request from thread to thread; ``handover`` is a stage whose
   interval opens in one block and closes in a later one, on any thread (a
-  window's launch and its collect).
+  window's launch and its collect). ``instant`` hands a ``now()`` reading
+  up to the handover that asked for them (the scheduler's ``server.device``:
+  when a window's first program was dispatched, when its last outputs were
+  in hand), so no parameter carries one down or up.
 """
 
 import bisect
@@ -219,7 +222,9 @@ now = time.perf_counter  # the one monotonic clock stage timing reads
 # clock: ``engine.scan`` holds the time a window waits on the chip behind
 # the one ahead of it. ``server.device`` (launch call to collect's end) and
 # ``engine.launch`` (launch to fetch, counter ``device_search_s``) are
-# subtotals that contain some of them.
+# subtotals that contain some of them; ``engine.dispatch`` is the host's
+# share of ``engine.scan`` (its first leg), and the scheduler's
+# ``sched.chip_*`` rows are the chip's own timeline (serving/scheduler.py).
 LAUNCH_LOOP = (
     "sched.idle", "sched.window_wait", "sched.assemble",
     "engine.lock_wait", "engine.feed", "engine.scan", "engine.refine_fetch",
@@ -227,11 +232,23 @@ LAUNCH_LOOP = (
 )
 
 
+# The chip's timeline (serving/scheduler.py books it, observability/profile.py
+# reduces a device trace to the same names): which wait of the batcher thread
+# explains an idle chip by something else than the host's own work, the row
+# each is booked to, and the row of everything else.
+CHIP_IDLE_CAUSE = {"sched.idle": "sched.chip_idle.empty",
+                   "sched.window_wait": "sched.chip_idle.window_wait"}
+CHIP_IDLE_HOST = "sched.chip_idle.host"
+CHIP_IDLE = (*CHIP_IDLE_CAUSE.values(), CHIP_IDLE_HOST)
+CHIP_ROWS = ("sched.chip_busy", "sched.chip_queue", *CHIP_IDLE)
+
+
 class _Context(threading.local):
     trace_id = None  # the sampled request's id; None = not sampled
     parent = None  # span id the next span booked on this thread hangs under
     spans = None  # SpanBuffer the spans land in; None = the process-local one
     sink = None  # LatencyStats the counters land in
+    instants = None  # dict the enclosing handover collects ``instant``s in
 
 
 class _NoCounter:
@@ -363,6 +380,19 @@ def count(name: str, value: float = 1.0) -> None:
         sink.record(name, value)
 
 
+def instant(name: str, first: bool = False) -> float:
+    """Read the clock, and hand the reading up to the enclosing handover
+    that collects instants (``handover(..., instants=True)``, in any of its
+    legs; nothing where there is none: the in-process batcher). A later
+    reading of the same name replaces an earlier one; with ``first`` the
+    earliest stands. Returns the reading."""
+    t = now()
+    held = _ctx.instants
+    if held is not None and not (first and name in held):
+        held[name] = t
+    return t
+
+
 class stage:
     """``with stage("sched.assemble"):`` books the block's wall time.
 
@@ -377,9 +407,10 @@ class stage:
     ``done()`` ends the stage before the block does (a lock wait ends
     where the lock is taken: ``with stage(..) as st, lock: st.done()``).
     A block that raises books nothing: a failure's wait ceiling must not
-    land in a latency row. ``dt`` holds the seconds afterwards."""
+    land in a latency row. ``t0`` is the ``now()`` reading the stage opened
+    at, ``dt`` holds the seconds afterwards."""
 
-    __slots__ = ("name", "sink", "counter", "extra", "dt", "_t0", "_w0",
+    __slots__ = ("name", "sink", "counter", "extra", "dt", "t0", "_w0",
                  "_ann", "_span_id", "_parent", "_old_sink", "_open")
 
     def __init__(self, name: str, sink: Optional[LatencyStats] = None,
@@ -407,14 +438,14 @@ class stage:
             self._ann = _annotation(self.name)
             self._ann.__enter__()
         self._open = True
-        self._t0 = now()
+        self.t0 = now()
         return self
 
     def done(self, failed: bool = False) -> None:
         if not self._open:
             return
         self._open = False
-        self.dt = dt = now() - self._t0
+        self.dt = dt = now() - self.t0
         if self._ann is not None:
             self._ann.__exit__(None, None, None)
         c = _ctx
@@ -453,20 +484,27 @@ class handover:
     and sets the thread's context as ``stage`` does, so the stages nested in
     a later leg book into the sink, and hang under the span, that the first
     leg found: the sampled request crosses the threads with the stage, no
-    ticket beside it. A leg that raises leaves the stage unbooked."""
+    ticket beside it. A leg that raises leaves the stage unbooked.
 
-    __slots__ = ("name", "sink", "counter", "extra", "dt", "_t0", "_w0",
-                 "_ticket", "_span_id", "_ann", "_old", "_last")
+    ``instants=True``: the ``instant`` readings taken inside any leg, by
+    whatever runs there, land in ``self.instants`` (a handover without it
+    leaves them to the one around it). ``t0`` is the ``now()`` reading the
+    first leg opened at."""
+
+    __slots__ = ("name", "sink", "counter", "extra", "dt", "t0", "_w0",
+                 "_ticket", "_span_id", "_ann", "_old", "_last", "instants")
 
     def __init__(self, name: str, sink: Optional[LatencyStats] = None,
-                 counter: Optional[str] = None, **extra):
+                 counter: Optional[str] = None, instants: bool = False,
+                 **extra):
         self.name = name
         self.sink = sink
         self.counter = counter
         self.extra = extra
         self.dt = 0.0
-        self._t0 = None
+        self.t0 = None
         self._last = False
+        self.instants = {} if instants else None
 
     def last(self) -> "handover":
         self._last = True
@@ -474,15 +512,17 @@ class handover:
 
     def __enter__(self):
         c = _ctx
-        self._old = (c.sink, c.trace_id, c.parent, c.spans)
-        if self._t0 is None:
+        self._old = (c.sink, c.trace_id, c.parent, c.spans, c.instants)
+        if self.t0 is None:
             if self.sink is None:
                 self.sink = c.sink
             self._ticket = ticket()
             self._span_id = None if self._ticket is None else new_span_id()
             self._w0 = time.time()
-            self._t0 = now()
+            self.t0 = now()
         c.sink = self.sink
+        if self.instants is not None:
+            c.instants = self.instants
         if self._ticket is not None:
             c.trace_id, _, c.spans = self._ticket
             c.parent = self._span_id
@@ -496,10 +536,10 @@ class handover:
         if self._ann is not None:
             self._ann.__exit__(None, None, None)
         c = _ctx
-        c.sink, c.trace_id, c.parent, c.spans = self._old
+        c.sink, c.trace_id, c.parent, c.spans, c.instants = self._old
         if not self._last or exc_type is not None:
             return
-        self.dt = dt = now() - self._t0
+        self.dt = dt = now() - self.t0
         if self.sink is not None:
             self.sink.record(self.counter or self.name, dt,
                              exemplar=self._ticket and self._ticket[0])

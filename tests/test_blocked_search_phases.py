@@ -136,21 +136,21 @@ def test_an_empty_index_and_an_empty_batch_come_back_finished():
 
 
 def test_the_stages_are_booked_once_a_launch_across_the_halves(indexes):
-    """``engine.feed`` in the launch, ``engine.scan`` from the dispatch to
-    the collect's wait, ``engine.refine_fetch`` in the collect: one record
-    each, into the sink the launch found, though the collect runs where
-    there is none."""
+    """``engine.feed`` and ``engine.dispatch`` (the host's share of the scan)
+    in the launch, ``engine.scan`` from the dispatch to the collect's wait,
+    ``engine.refine_fetch`` in the collect: one record each, into the sink
+    the launch found, though the collect runs where there is none."""
     x, built = indexes
     idx = built["ivf_pq_refine"]
     sink = tracing.LatencyStats()
     with tracing.stage("engine.launch", sink=sink):
         handle = idx.launch_search(x[:20], K)
-    assert set(sink.summary()) == {"engine.feed", "engine.launch"}
+    assert set(sink.summary()) == {"engine.feed", "engine.dispatch", "engine.launch"}
     handle.collect()
     rows = sink.summary()
-    for name in ("engine.feed", "engine.scan", "engine.refine_fetch"):
+    for name in ("engine.feed", "engine.dispatch", "engine.scan", "engine.refine_fetch"):
         assert rows[name]["count"] == 1, name
-    assert rows["engine.scan"]["total_s"] > 0
+    assert rows["engine.scan"]["total_s"] > rows["engine.dispatch"]["total_s"] > 0
     # the count rows an index books after its collect go where the collect
     # runs: inside ``engine.launch``'s last leg in an engine
     with tracing.stage("engine.launch", sink=sink):

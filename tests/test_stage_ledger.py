@@ -121,11 +121,16 @@ def best_of(attempts, measure, within):
     raise AssertionError((got, want, detail))
 
 
-# what a merged window books once, two in flight or not (ISSUE 41, Tentpole 7)
+# what a merged window books once, two in flight or not (ISSUE 41, Tentpole
+# 7; ISSUE 42: the dispatch inside engine.scan, and the chip's timeline)
 ONCE_A_WINDOW = ("sched.assemble", "sched.split", "batch_rows",
                  "engine.lock_wait", "engine.feed", "engine.scan",
-                 "engine.refine_fetch", "engine.join", "device_search_s",
-                 "device_search_rows")
+                 "engine.dispatch", "engine.refine_fetch", "engine.join",
+                 "device_search_s", "device_search_rows",
+                 "sched.chip_busy", "sched.chip_queue")
+CHIP_IDLE = tracing.CHIP_IDLE
+# the completer's stages: they run beside the batcher's and explain no gap
+COMPLETER = {"engine.scan", "engine.refine_fetch", "engine.join", "sched.split"}
 
 
 def test_every_launch_loop_stage_is_booked_once_a_window_with_two_in_flight(rank):
@@ -135,7 +140,10 @@ def test_every_launch_loop_stage_is_booked_once_a_window_with_two_in_flight(rank
     ``engine.launch_overlapped`` counts windows (some, never more than
     there were), and the launch's own three stages are what
     launch-to-fetch is made of — the wait behind the window ahead lies in
-    ``engine.scan``."""
+    ``engine.scan``, whose host share, ``engine.dispatch``, lies inside it;
+    and the chip's timeline closes on the rank's own clock: busy plus idle
+    over the windows is what ``chip_timeline_s`` moved by, a window's busy
+    plus queue lies inside its launch-to-collect."""
     best_of(3, lambda: launch_loop_closure(
         rank, ("engine.scan_adc_cols", "engine.scan_adc_cols_skipped")), 0.03)
 
@@ -147,8 +155,11 @@ def launch_loop_closure(rank, once_a_scan=()):
     def booked():
         rows = {**sched.stats.summary(), **idx.perf_stats()}
         return {n: dict(rows.get(n, tracing.zero_row())) for n in (
-            *tracing.LAUNCH_LOOP, *ONCE_A_WINDOW, *once_a_scan,
+            *tracing.LAUNCH_LOOP, *ONCE_A_WINDOW, *once_a_scan, *CHIP_IDLE,
             "queue_wait_s", "engine.launch_overlapped")}
+
+    def timeline():
+        return sched.perf_stats()["counters"]["chip_timeline_s"]
 
     def settled():
         """Nothing queued, in flight or still being split."""
@@ -161,7 +172,7 @@ def launch_loop_closure(rank, once_a_scan=()):
             time.sleep(0.01)
         raise AssertionError("the windows in flight never drained")
 
-    before = settled()
+    before, line0 = settled(), timeline()
     enough = threading.Event()
     callers = drive(rank, until=enough)
     try:
@@ -185,10 +196,46 @@ def launch_loop_closure(rank, once_a_scan=()):
         assert moved(name) == n, (name, moved(name), n)
     assert moved("queue_wait_s") >= n  # once a request
     assert 0 < moved("engine.launch_overlapped") <= n
+    assert 0 < moved("engine.dispatch", "total_s") < moved("engine.scan", "total_s")
+    # the chip's timeline: some windows queued behind the one ahead, a gap
+    # is booked once a cause at most, and busy + idle is the timeline
+    assert moved("sched.chip_queue", "total_s") > 0
+    assert all(moved(name) <= n for name in CHIP_IDLE)
+    assert (moved("sched.chip_busy", "total_s")
+            + sum(moved(name, "total_s") for name in CHIP_IDLE)
+            == pytest.approx(timeline() - line0, abs=1e-6))
     inner = sum(moved(name, "total_s") for name in
                 ("engine.feed", "engine.scan", "engine.refine_fetch"))
     return inner, moved("device_search_s", "total_s"), {
         name: moved(name, "total_s") for name in tracing.LAUNCH_LOOP}
+
+
+def test_a_windows_two_instants_are_read_at_the_dispatch_and_at_the_fetch(rank, monkeypatch):
+    """No fallback on the served path: ``dispatched`` is read in the unit's
+    dispatch on the batcher thread, ``ready`` after its fetch on the
+    completer's, once each for a window of one block, and the window's
+    span on the chip lies between them."""
+    seen = []
+    real = tracing.instant
+
+    def spy(name, first=False):
+        t = real(name, first)
+        seen.append((name, threading.current_thread().name, t))
+        return t
+
+    monkeypatch.setattr(tracing, "instant", spy)
+    sched = rank["srv"].scheduler
+    before = sched.stats.summary()["sched.chip_busy"]
+    rank["client"].search(rank["x"][:8], 5, INDEX_ID)
+    deadline = time.time() + 10
+    while sched.stats.summary()["sched.chip_busy"]["count"] == before["count"]:
+        assert time.time() < deadline
+        time.sleep(0.005)
+    (d_name, d_thread, dispatched), (r_name, r_thread, ready) = seen
+    assert (d_name, r_name) == ("dispatched", "ready")
+    assert r_thread.endswith("-collect") and not d_thread.endswith("-collect")
+    busy = sched.stats.summary()["sched.chip_busy"]["total_s"] - before["total_s"]
+    assert busy == pytest.approx(ready - dispatched, abs=1e-9)  # the chip was free
 
 
 def test_a_requests_client_stages_add_up_to_client_search(rank):
@@ -300,6 +347,13 @@ def test_every_span_of_a_sampled_request_hangs_under_client_search(rank):
     assert by_id[by_name["server.request"]["parent"]]["name"] == "client.rpc"
     assert by_id[by_name["server.queue"]["parent"]]["name"] == "server.request"
     assert by_id[by_name["engine.scan"]["parent"]]["name"] == "engine.launch"
+    assert by_id[by_name["engine.dispatch"]["parent"]]["name"] == "engine.scan"
+    # the window's place on the chip's timeline rides its server.device span
+    device = by_name["server.device"]["extra"]
+    assert device["chip_busy_s"] > 0 and device["chip_queue_s"] >= 0
+    assert device["idle_before_s"] >= 0
+    assert (device["chip_busy_s"] + device["chip_queue_s"]
+            <= by_name["server.device"]["dur_s"])
     assert by_id[by_name["engine.launch"]["parent"]]["name"] == "server.device"
     assert all(s.get("rank") == 0 for s in timeline
                if s["name"].startswith(("server.", "engine.")))
@@ -452,9 +506,21 @@ def test_the_profile_op_puts_the_idle_seconds_on_host_stages(rank):
             == pytest.approx(reply["window_s"], rel=1e-6))
     assert sum(s for _, s in reply["idle_by_stage"]) == pytest.approx(
         reply["idle_s"], rel=1e-6)
-    assert {n for n, _ in reply["idle_by_stage"]} <= (
-        set(tracing.LAUNCH_LOOP) | {"engine.launch", "server.device",
-                                    "unattributed"})
+    stages = {n for n, _ in reply["idle_by_stage"]}
+    assert stages <= (set(tracing.LAUNCH_LOOP) | {
+        "engine.dispatch", "engine.launch", "server.device", "unattributed"})
+    # only the batcher's work precedes a dispatch: no stage of the
+    # completer takes a gap, and the batcher's leg of engine.scan is the
+    # dispatch
+    assert not stages & COMPLETER
+    # the same seconds under the names of the scheduler's counters
+    assert set(reply["idle_by_cause"]) == set(CHIP_IDLE)
+    assert sum(reply["idle_by_cause"].values()) == pytest.approx(
+        reply["idle_s"], rel=1e-6)
+    by_stage = dict(map(tuple, reply["idle_by_stage"]))
+    assert reply["idle_by_cause"]["sched.chip_idle.empty"] == pytest.approx(
+        by_stage.get("sched.idle", 0.0))
+    assert 0 < reply["busy_per_launch_s"] <= reply["busy_s"]
     # (how much of it: a chip's question — here the rank shares one GIL
     # with its callers, and a batcher thread waiting for it is in no stage)
     assert 0.0 < reply["idle_attributed_share"] <= 1.0
@@ -494,6 +560,76 @@ def test_dfstat_profile_prints_every_ranks_reduction(rank):
     text = out.getvalue()
     assert rc == 0, text
     assert "rank 0: window" in text and "idle seconds by host stage" in text
+    assert "idle seconds by cause" in text and "sched.chip_idle.host" in text
+    assert "device busy a launch" in text
+
+
+def test_dfstat_prints_a_chip_line_from_the_timeline_rows(rank):
+    """Utilisation from two ``get_perf_stats`` snapshots, no profiler: busy
+    and idle by cause are shares of the timeline the interval's windows
+    cover, so they add up to the whole of it."""
+    import io
+
+    from distributed_faiss_tpu.observability import dfstat
+
+    stop = threading.Event()
+    threads = drive(rank, until=stop)
+    try:
+        out = io.StringIO()
+        assert dfstat.main(["--discovery", rank["disc"], "--count", "2",
+                            "--interval", "0.5", "--json"], out=out) == 0
+        text = io.StringIO()
+        assert dfstat.main(["--discovery", rank["disc"], "--count", "2",
+                            "--interval", "0.3"], out=text) == 0
+    finally:
+        stop.set()
+        for t in threads:
+            t.join()
+    chip = json.loads(out.getvalue().splitlines()[-1])["ranks"][0]["chip"]
+    assert chip["windows"] > 0 and chip["busy_ms"] > 0
+    assert set(chip["idle_pct"]) == {"empty", "window_wait", "host"}
+    assert chip["busy_pct"] + sum(chip["idle_pct"].values()) == pytest.approx(100.0)
+    assert "chip: busy" in text.getvalue() and "window_wait" in text.getvalue()
+    # an interval with no window says nothing of the chip
+    idle = io.StringIO()
+    assert dfstat.main(["--discovery", rank["disc"], "--count", "2",
+                        "--interval", "0.2", "--json"], out=idle) == 0
+    assert json.loads(idle.getvalue().splitlines()[-1])["ranks"][0]["chip"] is None
+
+
+def test_the_builders_tool_reads_the_timeline_and_the_readers_no_entry_names(rank):
+    """``benchmarks/stage_ledger.py``: a rank's timeline between two
+    snapshots closes on ``chip_timeline_s``; a program without it gives
+    None; and the reader files that wait for their ``BENCHMARK.json``
+    entries are found by the cell's kind."""
+    import sys
+
+    sys.path.insert(0, REPO)
+    from perfbench import loader
+
+    tool = loader.load_module(os.path.join(REPO, "benchmarks", "stage_ledger.py"))
+    client, x = rank["client"], rank["x"]
+    before = client.get_perf_stats()[0]
+    for j in range(5):
+        client.search(x[j:j + 8], 5, INDEX_ID)
+    time.sleep(0.2)  # the last window's split
+    after = client.get_perf_stats()[0]
+    rows = tool.chip_rows(before, after)
+    assert rows["sched.chip_busy"][0] == rows["sched.chip_queue"][0] >= 1
+    assert rows["chip_timeline_s"] > 0
+    assert abs(rows["busy_plus_idle_less_timeline_s"]) < 1e-6
+    bare = {"scheduler": {"counters": {}, "queues": {}}}
+    assert tool.chip_rows(bare, bare) is None
+    ten = {base + suffix for base in (
+        "sched.chip_busy_ms", "engine.dispatch_ms", "sched.chip_idle_pct",
+        "sched.chip_idle_empty_pct", "sched.chip_idle_host_pct")
+        for suffix in ("", ".online")}
+    for cell, online in (("knnlm-batch", False), ("knnlm-online", True)):
+        cell = loader.Cell(cell)
+        found = {name for name, _ in tool.unlisted_readers(cell)}
+        listed = {m["name"] for m, _ in cell.layer_readers()}
+        assert {n for n in ten if n.endswith(".online") == online} <= found | listed
+        assert all(n.endswith(".online") == online for n in found)
 
 
 def test_xla_compile_counts_a_fresh_shape_once_and_a_repeat_never(rank):
@@ -539,6 +675,37 @@ def test_the_trace_reduction_on_a_recorded_trace():
     trimmed = profile.reduce_rows([tuple(r) for r in trace["rows"]])
     assert trimmed["window_s"] == pytest.approx(38000e-9)  # [0, 38000)
     assert trimmed["idle_attributed_share"] == 1.0
+
+
+def test_the_trace_reduction_is_thread_aware_on_a_recorded_trace():
+    """Two windows in flight: while the chip idles between them the
+    completer fetches, joins and splits the one behind and the batcher
+    waits, assembles, feeds and dispatches the one ahead. Only the
+    batcher's line (the one that holds ``sched.assemble``) explains the
+    gap; read as one thread, as before PR 42, the completer is blamed."""
+    trace = recorded("trace_two_threads.json")
+    rows = [tuple(r) for r in trace["rows"]]
+    out = profile.reduce_rows(rows, tuple(trace["window_ns"]))
+    want = trace["expected"]
+    assert out["busy_s"] == pytest.approx(want["busy_s"])
+    assert out["idle_s"] == pytest.approx(want["idle_s"])
+    assert dict(map(tuple, out["idle_by_stage"])) == pytest.approx(
+        want["idle_by_stage"])
+    assert out["idle_by_cause"] == pytest.approx(want["idle_by_cause"])
+    assert sum(out["idle_by_cause"].values()) == pytest.approx(out["idle_s"])
+    assert out["busy_per_launch_s"] == pytest.approx(want["busy_per_launch_s"])
+    assert not {n for n, _ in out["idle_by_stage"]} & COMPLETER
+    one_thread = [(plane, "python" if plane == "/host:CPU" else line, *rest)
+                  for plane, line, *rest in rows]
+    blamed = dict(map(tuple, profile.reduce_rows(
+        one_thread, tuple(trace["window_ns"]))["idle_by_stage"]))
+    assert blamed["engine.refine_fetch"] > 0
+    # and a session with no batcher in it (no scheduler: the in-process
+    # batcher launches on its caller's thread) is judged on every line
+    no_batcher = [r for r in rows if r[2] != "sched.assemble"]
+    alone = profile.reduce_rows(no_batcher, tuple(trace["window_ns"]))
+    assert "engine.refine_fetch" in dict(map(tuple, alone["idle_by_stage"]))
+    assert alone["busy_per_launch_s"] is None
 
 
 def new_readers():

@@ -63,6 +63,41 @@ def test_stage_records_its_block():
     assert s["mean_s"] >= 0.015 and s["total_s"] == st.dt
 
 
+def test_instants_go_up_to_the_handover_that_collects_them():
+    """``tracing.instant`` readings land in the enclosing handover that asked
+    for them, in whichever leg and on whichever thread; a handover that did
+    not ask leaves them to the one around it; with none around, nothing
+    keeps them; ``first`` keeps the earliest."""
+    from distributed_faiss_tpu.utils import tracing
+
+    stats = LatencyStats()
+    assert tracing.instant("nowhere") > 0  # no handover: the reading, no more
+    outer = tracing.handover("outer", sink=stats, instants=True)
+    inner = tracing.handover("inner", sink=stats)
+    with outer:
+        with inner:
+            a = tracing.instant("dispatched", first=True)
+            assert tracing.instant("dispatched", first=True) > a
+        early = tracing.instant("ready")
+    assert inner.instants is None and outer.instants == {
+        "dispatched": a, "ready": early}
+    assert tracing.instant("between the legs") and "between the legs" not in outer.instants
+    got = {}
+
+    def last_leg():
+        with outer.last(), inner.last():
+            got["ready"] = tracing.instant("ready")  # a later one replaces
+
+    worker = threading.Thread(target=last_leg)
+    worker.start()
+    worker.join(10)
+    assert outer.instants == {"dispatched": a, "ready": got["ready"]}
+    assert outer.t0 <= a < early < got["ready"] <= outer.t0 + outer.dt
+    assert stats.summary()["outer"]["count"] == stats.summary()["inner"]["count"] == 1
+    with stage("after", stats):  # the context is what it was
+        assert tracing.instant("nobody's") and "nobody's" not in outer.instants
+
+
 def test_profile_capture_writes(tmp_path):
     import os
 
