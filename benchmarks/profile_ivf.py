@@ -104,13 +104,13 @@ def main():
         q = (centers[rng.integers(0, nlist, block)]
              + rng.standard_normal((block, d))).astype(np.float32)
         qj = jnp.asarray(q)
-        tile, group = idx._scan_tiling(block, nprobe)
+        tile, group, sub = idx._scan_tiling(block, nprobe)
 
         def call():
-            v, i = _ivf_flat_search(
+            v, i, _ = _ivf_flat_search(
                 idx.centroids, idx.lists.data, idx.lists.ids, idx.lists.sizes,
                 qj, k, nprobe, 1, "l2", "f16", list_norms=norms,
-                use_pallas=idx.use_pallas, tile=tile, group=group)
+                use_pallas=idx.use_pallas, tile=tile, group=group, sub=sub)
             np.asarray(v); np.asarray(i)
 
         t = timeit(call, reps=10)

@@ -10,7 +10,8 @@ snapshots around its window, which the benchmark takes in traced runs only
 (ROADMAP S2, item (c): once it takes them always, this script goes). Prints
 every per-layer metric the snapshots can feed — the benchmark's own, and
 every reader file under ``perfbench/layer_metrics/`` that no entry of
-``BENCHMARK.json`` names yet (the chip timeline's ten, PR 42) — the two
+``BENCHMARK.json`` names yet (the chip timeline's ten, PR 42), and
+``kernel.list_skip_pct`` (PR 43, read here until it has such a file) — the two
 closures of docs/OPERATIONS.md's stage ledger, and the chip's timeline as
 the scheduler books it, a rank: its five rows over the window, whether busy
 plus idle is what ``chip_timeline_s`` moved by, and, under ``--trace 1``, the
@@ -125,6 +126,24 @@ def unlisted_readers(cell):
             yield name, loader.load_module(os.path.join(folder, f"{name}.py"))
 
 
+def list_skip_pct(obs):
+    """``kernel.list_skip_pct``, until a ``benchmark`` PR makes these lines
+    ``perfbench/layer_metrics/kernel.list_skip_pct.py`` (PERF.md 7.1 p;
+    ``ivfsq-batch``, moves ``qps``): share of the list rows of the window's
+    list-major scans, at their lists' whole capacity, that lay in
+    sub-blocks past the end of their list and were never gathered, in %:
+    the window's total of ``engine.scan_list_rows_skipped`` over that of
+    ``engine.scan_list_rows``, all ranks together. A program without the
+    counters, or a window with no list-major scan, reads nothing."""
+    skipped = stats.per_rank(obs, ledger.engine(obs, "engine.scan_list_rows_skipped"),
+                             ledger.window_total)
+    rows = stats.per_rank(obs, ledger.engine(obs, "engine.scan_list_rows"),
+                          ledger.window_total)
+    if skipped is None or rows is None or not sum(rows):
+        return None
+    return 100.0 * sum(skipped) / sum(rows)
+
+
 def closures(obs):
     """Per rank, the launch loop's stages over the window and the launch's
     three over ``device_search_s``; the client's five over ``client.search``
@@ -177,6 +196,7 @@ def main(argv=None, **rehearsal):
             metrics[name] = reader.read(OBS)
         except (KeyError, TypeError):  # needs the trace or the set-up's facts
             continue
+    metrics.setdefault("kernel.list_skip_pct", list_skip_pct(OBS))
     print("LEDGER " + json.dumps({
         "cell": args.workload, "seed": args.seed, "window_s": OBS["window_s"],
         "traced": bool(args.trace),

@@ -1846,6 +1846,12 @@ class Index:
         the ``engine.scan`` blocks of an IVF-flat index whose probe scan
         took the list-major order (``models/ivf.listmajor_tiling``;
         ``IVFFlatIndex`` books it);
+        ``engine.scan_list_rows`` and ``engine.scan_list_rows_skipped``
+        (count rows, shown the same way) sum, one record a list-major
+        ``engine.scan``, the list rows of its tiles at whole capacity (what
+        a scan of whole padded lists gathers and multiplies) and those of
+        them in sub-blocks past the end of their list, which the scan
+        never gathers (``IVFFlatIndex._book_list_rows`` books both);
         ``engine.scan_adc_cols`` and ``engine.scan_adc_cols_skipped`` (count
         rows, shown the same way) sum, one record an ``engine.scan`` of an
         IVF-PQ index, the candidate columns of its (query, probe) pairs'
@@ -1865,6 +1871,7 @@ class Index:
         if "engine.scan" in out:
             for name in ("engine.scan_fused", "engine.scan_rows",
                          "engine.scan_prefilter", "engine.scan_listmajor",
+                         "engine.scan_list_rows", "engine.scan_list_rows_skipped",
                          "engine.scan_adc_cols", "engine.scan_adc_cols_skipped"):
                 out.setdefault(name, tracing.zero_row())
         return out

@@ -9,10 +9,14 @@ TPU-first search path (one jitted program per variant):
   over probes, each step gathering one (nq, g, cap, m) code block from HBM,
   scoring it by ADC LUT, masking the padded tail and merging into a running
   top-k carry. Flat/fp16/sq8: list-major (_listmajor_scan) — the (query,
-  probe) pairs are sorted by list on the device and cut into tiles, each
-  probed list is gathered once per tile of queries that probe it and
-  multiplied against all of them on the MXU (dequant fused into the
-  einsum), a top-k per pair, then one per query. The flat/sq8 l2 scan
+  probe) pairs are sorted by list on the device and every list's run of
+  pairs is cut into runs of T queries; a tile is one such run x ONE LIVE
+  SUB-BLOCK of its list (listmajor_sub_rows rows: 256 at d 512 in fp16),
+  made only for the sub-blocks that hold a row, so the scan gathers and
+  multiplies what a list holds and not its padded capacity. A tile's
+  sub-block is gathered once and multiplied against all its queries on
+  the MXU (dequant fused into the einsum); the top-k is taken once a
+  query over its probed lists' columns. The flat/sq8 l2 scan
   gathers STORED fp32 row norms (a (nlist, cap) sidecar filled at
   add/encode time, bit-identical to an in-scan recompute) instead of
   running a second elementwise pass over the block; with use_pallas the
@@ -101,9 +105,14 @@ _GROUP_BYTE_BUDGET = 128 * 1024 * 1024
 # buffer of a block stays under _SCORE_BYTES. The gather takes its rows in
 # slices of at most _GATHER_SLICE_BYTES: a slice of a whole (cap, d) list is
 # past what XLA:TPU gathers in place, and it then copies the WHOLE store
-# into slabs in every loop step (PERF.md section 6, PR 31).
+# into slabs in every loop step (PERF.md section 6, PR 31). Lists fuller
+# than _WHOLE_LIST_FILL are scanned a whole list a tile: a sub-block tile's
+# bookkeeping costs a scan 7% (capacity 4096) to 12% (512) where nothing is
+# skipped, which is what a tenth of the capacity skipped pays back, and lists
+# filled past 0.9 leave less than that to skip (PERF.md section 6, PR 43).
 _MAX_TILE = 64
 _MAX_GROUP = 64
+_WHOLE_LIST_FILL = 0.9
 _LIST_BLOCK_BYTES = 64 * 1024 * 1024
 _SCORE_BYTES = 1024 * 1024 * 1024
 _GATHER_SLICE_BYTES = 256 * 1024
@@ -142,25 +151,47 @@ def _merge_group(carry, s, ids, k):
         return distance.merge_topk(best_v, best_i, cv, cids, k)
 
 
+def listmajor_sub_rows(cap: int, dim: int, itemsize: int) -> int:
+    """Rows of a list's sub-block: the grain at which the list-major scan
+    gathers a list and stops at its end. The largest power-of-two share of
+    ``cap`` (down to 32 rows) whose (sub, dim) slice in the storage dtype
+    stays inside ``_GATHER_SLICE_BYTES``: 256 rows at d 512 in float16."""
+    sub = cap
+    while sub % 64 == 0 and sub * dim * itemsize > _GATHER_SLICE_BYTES:
+        sub //= 2
+    return sub
+
+
 def listmajor_tiling(rows: int, nprobe: int, nlist: int, cap: int, dim: int,
-                     itemsize: int):
-    """(T, G) of the list-major probe scan for a block of ``rows`` query
-    rows over lists of ``cap`` rows of ``dim`` x ``itemsize`` bytes, from
-    static shapes alone: T query slots a tile, G tiles a loop step. The ONE
-    rule — ``IVFFlatIndex._scan_tiling`` asks it before the trace.
+                     itemsize: int, fill: float = 0.0):
+    """(T, G, sub) of the list-major probe scan for a block of ``rows``
+    query rows over lists of ``cap`` rows of ``dim`` x ``itemsize`` bytes
+    filled to the share ``fill``, from static shapes and what the index
+    knows without a device read. A tile is a run of up to T queries that
+    probe one list x ONE LIVE SUB-BLOCK of ``sub`` rows of that list; G
+    tiles make a loop step. The ONE rule — ``IVFFlatIndex._scan_tiling``
+    asks it before the trace.
 
     T follows the expected reuse of a probed list, ``rows * nprobe /
-    nlist`` pairs. Up to 1 a tile is a pair (T = 1): the scan is then the
-    pair-major one, a matrix-vector product a pair, bound by the read of
-    the list. Past it a tile is a power of two from 8 (one sublane tile of
+    nlist`` pairs. Up to 1 a tile's run is a pair (T = 1): the scan is then
+    the pair-major one, a matrix-vector product a pair, bound by the read
+    of the list. Past it T is a power of two from 8 (one sublane tile of
     queries; fewer cost the same) up to 4 x the reuse: a tile costs the
-    gather of its list and a product whose 128 MXU columns are paid for
-    whether 8 or 64 are filled, so it is wide enough that a list's pairs
-    nearly always fit one tile — as long as the block's score buffer,
+    gather of its sub-block and a product whose 128 MXU columns are paid
+    for whether 8 or 64 are filled, so it is wide enough that a list's
+    pairs nearly always fit one run — as long as the block's score buffer,
     ``(pairs + nlist * T) * cap * 4`` bytes, stays inside ``_SCORE_BYTES``.
-    G keeps a step's gathered block inside ``_LIST_BLOCK_BYTES``. Every
-    number is a v5e's at d 512, float16, capacities 512 to 4096: PERF.md
-    section 6, PR 31."""
+    ``sub`` is ``listmajor_sub_rows`` (a tile then costs what its list
+    holds, to that grain), unless the lists are fuller than
+    ``_WHOLE_LIST_FILL``: full lists leave nothing to skip and a tile of
+    the whole capacity spares them a sub-block tile's bookkeeping (its
+    queries gathered and its slab written once a sub-block). G keeps a
+    step's gathered block inside ``_LIST_BLOCK_BYTES``: the sub-blocks of
+    up to ``_MAX_GROUP`` whole lists, so a step is as fat as when a tile
+    was a whole padded list and a launch takes fewer of them (a loop step
+    costs its drain whether it is full or not). Every number is a v5e's at
+    d 512, float16, capacities 512 to 4096: PERF.md section 6, PR 31 and
+    PR 43."""
     pairs = rows * nprobe
     tile = 1
     if pairs > nlist:
@@ -169,32 +200,47 @@ def listmajor_tiling(rows: int, nprobe: int, nlist: int, cap: int, dim: int,
             tile *= 2
         while tile > 8 and (pairs + nlist * tile) * cap * 4 > _SCORE_BYTES:
             tile //= 2
-    group = 1
-    while (group < _MAX_GROUP
-           and 2 * group * cap * dim * itemsize <= _LIST_BLOCK_BYTES):
-        group *= 2
-    return tile, group
+    lists = 1
+    while (lists < _MAX_GROUP
+           and 2 * lists * cap * dim * itemsize <= _LIST_BLOCK_BYTES):
+        lists *= 2
+    sub = cap if fill > _WHOLE_LIST_FILL else listmajor_sub_rows(cap, dim, itemsize)
+    return tile, lists * (cap // sub), sub
 
 
 def listmajor_tile_bound(npairs: int, nlist: int, tile: int) -> int:
-    """Tiles that ``npairs`` (query, probe) pairs can make, whatever the
-    probes: every full tile holds ``tile`` pairs and each probed list adds
-    at most one partial tile, and no tile is empty."""
+    """Runs of up to ``tile`` queries that ``npairs`` (query, probe) pairs
+    can make, whatever the probes: every full run holds ``tile`` pairs and
+    each probed list adds at most one partial run, and no run is empty. A
+    run makes a tile per live sub-block of its list, ``cap / sub`` at
+    most: the scan's tile arrays hold that many times this."""
     return min(npairs, -(-npairs // tile) + min(nlist, npairs))
 
 
-def _listmajor_plan(probes, nlist: int, tile: int, ntiles: int, nvalid):
+def _listmajor_plan(probes, list_sizes, tile: int, sub: int, nruns: int,
+                    ntiles: int, nvalid):
     """Invert ``probes`` (nq, nprobe) into tiles, on the device: sort the
-    pairs by list id, cut every list's run into tiles of ``tile`` query
-    slots. Pairs of rows at or past ``nvalid`` (a block's zero padding)
-    sort behind every list and make no tile.
+    pairs by list id, cut every list's run of pairs into runs of ``tile``
+    query slots, and give each run one tile per LIVE sub-block of its list
+    (``ceil(size / sub)`` of them: none for an empty list), a run's tiles
+    side by side in sub-block order. Pairs of rows at or past ``nvalid`` (a
+    block's zero padding) sort behind every list and make no run.
 
-    Returns ``tile_list`` (ntiles,) the list a tile scans, ``tile_q``
-    (ntiles, tile) the query row of each slot (an arbitrary live row in a
-    slot past the list's run: its scores are never read), ``where`` (nq,
-    nprobe) the ``tile * T + slot`` each pair's result lands at,
-    ``pair_live`` (nq, nprobe) and the traced number of tiles in use."""
+    What costs a gather an element is made a run (``nruns`` of them, the
+    whole-list scan's tiles), not a tile (``ntiles``: ``cap / sub`` times
+    as many): a tile knows its run and its sub-block, from two scatters of
+    the runs' first tiles and a running count and maximum over the tiles.
+
+    Returns ``run_list`` (nruns,) the list a run scans and ``run_q``
+    (nruns, tile) the query row of each of its slots (an arbitrary live
+    row in a slot past the list's run: its scores are never read);
+    ``tile_run``, ``tile_sub`` (ntiles,) a tile's run and sub-block (past
+    the tiles in use: the last run, and a count from their end);
+    ``where`` (nq, nprobe) the ``first tile * T + slot`` of each pair's run
+    (its sub-block j is ``T * j`` rows further); ``pair_live`` (nq,
+    nprobe); and the traced numbers of tiles and of runs in use."""
     nq, nprobe = probes.shape
+    nlist = list_sizes.shape[0]
     npairs = nq * nprobe
     pair = jnp.arange(npairs, dtype=jnp.int32)
     key = probes.reshape(npairs).astype(jnp.int32)
@@ -204,20 +250,36 @@ def _listmajor_plan(probes, nlist: int, tile: int, ntiles: int, nvalid):
     skey = key[order]
     start = jnp.searchsorted(
         skey, jnp.arange(nlist + 1, dtype=jnp.int32)).astype(jnp.int32)
-    ntile = -(-(start[1:] - start[:-1]) // tile)  # tiles of each list
-    tend = jnp.cumsum(ntile)
-    tfirst = tend - ntile
-    t = jnp.arange(ntiles, dtype=jnp.int32)
-    tile_list = jnp.minimum(
-        jnp.searchsorted(tend, t, side="right"), nlist - 1).astype(jnp.int32)
-    pos = ((start[tile_list] + (t - tfirst[tile_list]) * tile)[:, None]
+    nrun = -(-(start[1:] - start[:-1]) // tile)  # runs of each list
+    rend = jnp.cumsum(nrun)
+    rfirst = rend - nrun
+    r = jnp.arange(nruns, dtype=jnp.int32)
+    run_list = jnp.minimum(
+        jnp.searchsorted(rend, r, side="right"), nlist - 1).astype(jnp.int32)
+    pos = ((start[run_list] + (r - rfirst[run_list]) * tile)[:, None]
            + jnp.arange(tile, dtype=jnp.int32)[None, :])
-    tile_q = order[jnp.minimum(pos, npairs - 1)] // nprobe
+    run_q = order[jnp.minimum(pos, npairs - 1)] // nprobe
+    # tiles of each run: the live sub-blocks of its list (a run past those
+    # in use makes none and starts where the tiles in use end)
+    nsub = -(-list_sizes.astype(jnp.int32) // sub)
+    width = jnp.where(r < rend[-1], nsub[run_list], 0)
+    tend = jnp.cumsum(width)
+    tfirst = tend - width
+    # a tile's run is the last that starts at or before it; its sub-block
+    # is its distance from that start
+    marks = jnp.zeros((ntiles + 1,), jnp.int32)
+    tile_run = jnp.cumsum(marks.at[tfirst].add(1))[:ntiles] - 1
+    seg = jax.lax.cummax(marks.at[tfirst].max(tfirst))[:ntiles]
+    tile_sub = jnp.arange(ntiles, dtype=jnp.int32) - seg
     slist = jnp.minimum(skey, nlist - 1)
+    rank = pair - start[slist]  # a sorted pair's place in its list's run
     where = jnp.zeros((npairs,), jnp.int32).at[order].set(
-        tfirst[slist] * tile + pair - start[slist], unique_indices=True)
-    return (tile_list, tile_q, where.reshape(nq, nprobe),
-            (key < nlist).reshape(nq, nprobe), tend[-1])
+        jnp.where(skey < nlist,
+                  tfirst[jnp.minimum(rfirst[slist] + rank // tile, nruns - 1)] * tile
+                  + rank % tile, 0),
+        unique_indices=True)
+    return (run_list, run_q, tile_run, tile_sub, where.reshape(nq, nprobe),
+            (key < nlist).reshape(nq, nprobe), tend[-1], rend[-1])
 
 
 def _decode_block(block, codec: str, vmin, span):
@@ -227,28 +289,33 @@ def _decode_block(block, codec: str, vmin, span):
     return block.astype(jnp.float32)
 
 
-def _gather_lists(list_data, lists):
-    """``list_data[lists]``, (G, cap, d) in the storage dtype, gathered
-    through a view of the store in slices of at most _GATHER_SLICE_BYTES
-    (the reshape is a bitcast: whole sublane tiles of rows stay together)."""
-    nlist, cap, d = list_data.shape
-    sub = cap
-    while (sub % 64 == 0
-           and sub * d * list_data.dtype.itemsize > _GATHER_SLICE_BYTES):
-        sub //= 2
-    parts = cap // sub
-    view = list_data.reshape(nlist * parts, sub, d)
-    idx = lists[:, None] * parts + jnp.arange(parts, dtype=lists.dtype)[None, :]
-    return view[idx.reshape(-1)].reshape(lists.shape[0], cap, d)
+def _gather_blocks(lists, blocks, sub: int, slice_rows: int):
+    """Rows ``[b * sub, (b + 1) * sub)`` of a padded-list array (nlist, cap,
+    ...) taken as one run of ``nlist * cap`` rows, for each ``b`` of
+    ``blocks`` (G,): (G, sub, ...) in the storage dtype — list ``l``'s
+    sub-block ``j`` is ``b = l * (cap // sub) + j``. Gathered through a view
+    of the array in slices of ``slice_rows`` rows (``listmajor_sub_rows``:
+    at most ``_GATHER_SLICE_BYTES``; the reshape is a bitcast for the
+    payload, whole sublane tiles of rows stay together): a slice of a
+    whole (cap, d) list is past what XLA:TPU gathers in place."""
+    view = lists.reshape((-1, slice_rows) + lists.shape[2:])
+    each = sub // slice_rows
+    idx = blocks[:, None] * each + jnp.arange(each, dtype=blocks.dtype)[None, :]
+    return view[idx.reshape(-1)].reshape((blocks.shape[0], sub) + lists.shape[2:])
 
 
 def _listmajor_scan(list_data, list_ids, list_sizes, q, qn, probes, k: int,
                     metric: str, codec: str, vmin, span, list_norms,
-                    scan_bf16: bool, tile: int, group: int, nvalid):
-    """The probe scan in list-major order: each probed list is gathered
-    once per ``tile`` queries that probe it and multiplied against all of
-    them, ``einsum("gtd,gcd->gtc")``, where the query-major scan gathers
+                    scan_bf16: bool, tile: int, group: int, sub: int, nvalid):
+    """The probe scan in list-major order: each live sub-block (``sub``
+    rows; 0: the whole capacity) of each probed list is gathered once per
+    ``tile`` queries that probe the list and multiplied against all of
+    them, ``einsum("gtd,gsd->gts")``, where the query-major scan gathers
     one (cap, d) block per (query, probe) pair for a matrix-vector product.
+    A sub-block past the end of its list (``j >= ceil(size / sub)``) is
+    never gathered, never multiplied and never written: the scan costs the
+    rows the lists hold, to the grain of ``sub``, and not their padded
+    capacity.
 
     Every row of every probed list is scored for every query that probes
     it, at the query-major scan's precision. NO PAIR IS EVER DROPPED: the
@@ -257,59 +324,86 @@ def _listmajor_scan(list_data, list_ids, list_sizes, q, qn, probes, k: int,
     count is the traced number of tile groups in use, so the bound costs
     nothing when the probes are spread.
 
-    The loop only scores: a tile's masked scores land in a ``(slots, cap)``
-    buffer at ``tile * T + slot``. The top-k is taken once a query, over
-    its ``nprobe`` score rows side by side in probe order — the
+    The loop only scores: a tile's masked scores land in a ``(tiles, T,
+    sub)`` buffer at its own place. The top-k is taken once a query, over
+    its ``nprobe`` lists' columns side by side in probe order — the
     query-major scan's candidates in the query-major scan's order, so ties
-    fall as there (earlier probe, then lower position). A ``top_k`` a pair
-    inside the loop is a sort of every ``cap``-wide slot row on a v5e, 60%
-    of a launch (PERF.md section 6, PR 31); over a query's whole row
-    ``_seg_reduce`` picks the few segments that can hold a neighbour
-    first. The buffer is ``(pairs + nlist * T) * cap * 4`` bytes at most."""
+    fall as there (earlier probe, then lower position); a column past the
+    end of its list reads -inf there whatever the buffer holds (it is
+    ``jax.lax.empty``: a dead sub-block's rows were never written). A
+    ``top_k`` a pair inside the loop is a sort of every ``cap``-wide slot
+    row on a v5e, 60% of a launch (PERF.md section 6, PR 31); over a
+    query's whole row ``_seg_reduce`` picks the few segments that can hold
+    a neighbour first. The buffer is ``(pairs + nlist * T) * cap * 4``
+    bytes at most.
+
+    -> (vals, ids, counts): ``counts`` int32 (2,), the sub-blocks of the
+    tiles in use had every list been full (runs x ``cap / sub``: what a
+    scan of whole padded lists gathers) and those the scan gathered."""
     nq, nprobe = probes.shape
-    nlist, cap = list_data.shape[0], list_data.shape[1]
-    ntiles = listmajor_tile_bound(nq * nprobe, nlist, tile)
-    ntiles = -(-ntiles // group) * group
+    nlist, cap, d = list_data.shape
+    sub = sub or cap
+    parts = cap // sub
+    slice_rows = listmajor_sub_rows(sub, d, list_data.dtype.itemsize)
+    nruns = listmajor_tile_bound(nq * nprobe, nlist, tile)
+    ntiles = -(-nruns * parts // group) * group
     with jax.named_scope("coarse"):
-        tile_list, tile_q, where, pair_live, used = _listmajor_plan(
-            probes, nlist, tile, ntiles, nvalid)
-        # a tile's row mask and stored norms, gathered for all tiles at once:
-        # sixteen rows a loop step is a gather bound by its latency
-        tile_valid = ((jnp.arange(cap)[None, :] < list_sizes[tile_list][:, None])
-                      & (list_ids[tile_list] >= 0))  # (ntiles, cap)
-        tile_norms = None if list_norms is None else list_norms[tile_list]
+        (run_list, run_q, tile_run, tile_sub, where, pair_live, used,
+         runs) = _listmajor_plan(probes, list_sizes, tile, sub, nruns, ntiles, nvalid)
+        tile_sub = jnp.minimum(tile_sub, parts - 1)  # past the tiles in use
+        run_qn = qn[run_q]  # (nruns, T, 1)
 
     def body(i, scores):
         with jax.named_scope("list_scan"):
             t0 = i * group
-            tl = jax.lax.dynamic_slice_in_dim(tile_list, t0, group)  # (G,)
-            tq = jax.lax.dynamic_slice_in_dim(tile_q, t0, group)  # (G, T)
-            block = _decode_block(_gather_lists(list_data, tl),
-                                  codec, vmin, span)  # (G, cap, d)
+            tr = jax.lax.dynamic_slice_in_dim(tile_run, t0, group)  # (G,)
+            ts = jax.lax.dynamic_slice_in_dim(tile_sub, t0, group)
+            tl = run_list[tr]
+            tq = run_q[tr]  # (G, T)
+            tb = tl * parts + ts
+            block_ids = _gather_blocks(list_ids, tb, sub, slice_rows)  # (G, sub)
+            stored = (None if list_norms is None
+                      else _gather_blocks(list_norms, tb, sub, slice_rows))
+            block = _decode_block(_gather_blocks(list_data, tb, sub, slice_rows),
+                                  codec, vmin, span)  # (G, sub, d)
             qs = q[tq]  # (G, T, d)
             if scan_bf16:
-                ip = jnp.einsum("gtd,gcd->gtc", qs.astype(jnp.bfloat16),
+                ip = jnp.einsum("gtd,gsd->gts", qs.astype(jnp.bfloat16),
                                 block.astype(jnp.bfloat16),
                                 preferred_element_type=jnp.float32)
             else:
-                ip = jnp.einsum("gtd,gcd->gtc", qs, block, precision=_HIGHEST,
+                ip = jnp.einsum("gtd,gsd->gts", qs, block, precision=_HIGHEST,
                                 preferred_element_type=jnp.float32)
             if metric == "dot":
                 s = ip
             else:
-                bn = (jax.lax.dynamic_slice_in_dim(tile_norms, t0, group)
-                      if tile_norms is not None else base.row_norms_f32(block))
-                s = -(qn[tq] - 2.0 * ip + bn[:, None, :])
-            valid = jax.lax.dynamic_slice_in_dim(tile_valid, t0, group)
+                bn = stored if stored is not None else base.row_norms_f32(block)
+                s = -(run_qn[tr] - 2.0 * ip + bn[:, None, :])
+            # rows of its list a tile's sub-block holds, past 0: at most sub
+            live = list_sizes[tl].astype(jnp.int32) - ts * sub
+            valid = (jnp.arange(sub)[None, :] < live[:, None]) & (block_ids >= 0)
             s = jnp.where(valid[:, None, :], s, distance.NEG_INF)
-            return jax.lax.dynamic_update_slice_in_dim(
-                scores, s.reshape(group * tile, cap), t0 * tile, axis=0)
+            return jax.lax.dynamic_update_slice_in_dim(scores, s, t0, axis=0)
 
-    # every slot a live pair reads is written by a tile in use: no fill
-    scores = jax.lax.fori_loop(0, -(-used // group), body,
-                               jax.lax.empty((ntiles * tile, cap), jnp.float32))
+    # every slot a live pair reads is written by a tile in use: no fill; the
+    # buffer ends in ``parts`` tiles no loop step writes, so that a pair's
+    # ``parts`` sub-blocks, dead ones too, are read inside it
+    scores = jax.lax.fori_loop(
+        0, -(-used // group), body,
+        jax.lax.empty((ntiles + parts, tile, sub), jnp.float32))
     with jax.named_scope("merge_topk"):
-        row = jnp.where(pair_live[:, :, None], scores[where], distance.NEG_INF)
+        # a pair's row: its slot of the ``parts`` tiles from its run's first
+        # (one gather slice a pair, as when a tile was a whole list)
+        dn = jax.lax.GatherDimensionNumbers(
+            offset_dims=(1, 2), collapsed_slice_dims=(1,), start_index_map=(0, 1))
+        row = jax.lax.gather(
+            scores, jnp.stack([(where // tile).reshape(-1),
+                               (where % tile).reshape(-1)], -1), dn,
+            slice_sizes=(parts, 1, sub), mode="promise_in_bounds")
+        row = row.reshape(nq, nprobe, cap)
+        held = pair_live[:, :, None] & (
+            jnp.arange(cap)[None, None, :] < list_sizes[probes][:, :, None])
+        row = jnp.where(held, row, distance.NEG_INF)
         vals, pos = distance.segmented_argtopk(row.reshape(nq, nprobe * cap), k)
         if vals.shape[1] < k:  # fewer columns than k: the tail stays empty
             pad = ((0, 0), (0, k - vals.shape[1]))
@@ -318,25 +412,30 @@ def _listmajor_scan(list_data, list_ids, list_sizes, q, qn, probes, k: int,
         found = (pos >= 0) & (vals > distance.NEG_INF)
         pos = jnp.where(found, pos, 0)
         lists = jnp.take_along_axis(probes, pos // cap, axis=1)
-        ids = list_ids[lists, pos % cap].astype(jnp.int32)
-        return vals, jnp.where(found, ids, -1)
+        out_ids = list_ids[lists, pos % cap].astype(jnp.int32)
+        counts = jnp.stack([runs * parts, used]).astype(jnp.int32)
+        return vals, jnp.where(found, out_ids, -1), counts
 
 
 @functools.partial(jax.jit, static_argnames=("k", "nprobe", "g", "metric", "codec",
                                              "use_pallas", "scan_bf16", "tile",
-                                             "group"))
+                                             "group", "sub"))
 def _ivf_flat_search(centroids, list_data, list_ids, list_sizes, q,
                      k: int, nprobe: int, g: int, metric: str, codec: str,
                      vmin=None, span=None, list_norms=None,
                      use_pallas: bool = False, scan_bf16: bool = False,
-                     tile: int = 1, group: int = 1, nvalid=None):
+                     tile: int = 1, group: int = 1, sub: int = 0, nvalid=None):
     """IVF-Flat/SQ8 probe scan.
 
     The XLA arm scans list-major (_listmajor_scan): tiles of ``tile`` query
-    slots, ``group`` tiles a loop step, both chosen by the index from
-    static shapes (``listmajor_tiling``); the defaults are the pair-major
-    scan, one pair a step. The Pallas arm scans query-major, ``g`` probes
+    slots over one live sub-block of ``sub`` rows of a probed list,
+    ``group`` tiles a loop step, all three chosen by the index
+    (``listmajor_tiling``); the defaults are the pair-major scan over whole
+    lists, one pair a step. The Pallas arm scans query-major, ``g`` probes
     of every query a step.
+    -> (vals, ids, counts): ``counts`` int32 (2,) is ``_listmajor_scan``'s
+    (sub-blocks of the tiles at whole capacity, sub-blocks gathered); the
+    Pallas arm, which gathers no block, returns zeros.
     nvalid: traced int32, the block's real rows; the rest is zero padding
     whose pairs the list-major scan leaves out (their result rows are
     empty). None: every row is real.
@@ -363,7 +462,7 @@ def _ivf_flat_search(centroids, list_data, list_ids, list_sizes, q,
     if not use_pallas:
         return _listmajor_scan(list_data, list_ids, list_sizes, q, qn, probes,
                                k, metric, codec, vmin, span, list_norms,
-                               scan_bf16, tile, group, nvalid)
+                               scan_bf16, tile, group, sub, nvalid)
     from distributed_faiss_tpu.ops import flat_pallas
 
     groups = probes.reshape(nq, nprobe // g, g).transpose(1, 0, 2)  # (ng, nq, g)
@@ -382,7 +481,7 @@ def _ivf_flat_search(centroids, list_data, list_ids, list_sizes, q,
         return _merge_group(carry, s.reshape(nq, g * cap), ids.reshape(nq, g * cap), k), None
 
     (vals, ids), _ = jax.lax.scan(body, init, groups)
-    return vals, ids
+    return vals, ids, jnp.zeros((2,), jnp.int32)
 
 
 def _adc_pair_scores(lut, codes, sizes, use_pallas: bool):
@@ -452,13 +551,14 @@ def _ivf_pq_search(centroids, codebooks, list_codes, list_ids, list_sizes, q,
 
 @functools.partial(jax.jit, static_argnames=("k", "scan_k", "nprobe", "g", "metric",
                                              "codec", "refine", "use_pallas",
-                                             "scan_bf16", "tile", "group"))
+                                             "scan_bf16", "tile", "group", "sub"))
 def _ivf_flat_search_fused(centroids, list_data, list_ids, list_sizes, refine_data,
                            q3, k: int, scan_k: int, nprobe: int, g: int,
                            metric: str, codec: str, refine: bool,
                            vmin=None, span=None, list_norms=None,
                            use_pallas: bool = False, scan_bf16: bool = False,
-                           tile: int = 1, group: int = 1, counts=None):
+                           tile: int = 1, group: int = 1, sub: int = 0,
+                           counts=None):
     """Whole multi-block search in ONE device launch.
 
     q3: (nblocks, block, d); counts: (nblocks,) int32 real rows of each
@@ -466,18 +566,18 @@ def _ivf_flat_search_fused(centroids, list_data, list_ids, list_sizes, refine_da
     the per-block program sequentially on device, so the transient-memory
     budgets sized for one block still hold — but the host pays a single
     dispatch for the entire batch instead of one per block
-    (benchmarks/profile_ivf.py)."""
+    (benchmarks/profile_ivf.py). The third output is ``_ivf_flat_search``'s,
+    one pair of counts a block."""
 
     def body(block):
         qb, nvalid = block
-        vals, ids = _ivf_flat_search(centroids, list_data, list_ids, list_sizes,
-                                     qb, scan_k, nprobe, g, metric, codec,
-                                     vmin, span, list_norms,
-                                     use_pallas=use_pallas, scan_bf16=scan_bf16,
-                                     tile=tile, group=group, nvalid=nvalid)
+        vals, ids, scanned = _ivf_flat_search(
+            centroids, list_data, list_ids, list_sizes, qb, scan_k, nprobe, g,
+            metric, codec, vmin, span, list_norms, use_pallas=use_pallas,
+            scan_bf16=scan_bf16, tile=tile, group=group, sub=sub, nvalid=nvalid)
         if refine:
             vals, ids = _rerank_exact(refine_data, qb, ids, k, metric)
-        return vals, ids
+        return vals, ids, scanned
 
     return jax.lax.map(body, (q3, counts))
 
@@ -819,12 +919,13 @@ class IVFFlatIndex(_IVFBase):
         _first_use_check(self, scan, self._pallas_probe, self._PALLAS_KERNEL, 1e-3)
 
     def _scan_tiling(self, rows: int, nprobe: int):
-        """(tile, group) of the XLA probe scan for a block of ``rows`` query
-        rows: the one place they are decided, asked at every search since
-        the capacity grows with the lists (``listmajor_tiling`` is the
-        rule)."""
+        """(tile, group, sub) of the XLA probe scan for a block of ``rows``
+        query rows: the one place they are decided, asked at every search
+        since the capacity and the fill grow with the lists
+        (``listmajor_tiling`` is the rule)."""
         return listmajor_tiling(rows, nprobe, self.nlist, self.lists.cap,
-                                self.dim, np.dtype(self.lists.dtype).itemsize)
+                                self.dim, np.dtype(self.lists.dtype).itemsize,
+                                fill=self._n / (self.nlist * self.lists.cap))
 
     def search(self, q: np.ndarray, k: int):
         return self.launch_search(q, k).collect()
@@ -842,8 +943,8 @@ class IVFFlatIndex(_IVFBase):
         # the block this call launches: a batch under one block pads to its
         # own pow2 bucket, not to nb
         rows = nb if q.shape[0] > nb else distance.bucket_size(q.shape[0])
-        tile, group = self._scan_tiling(rows, nprobe)
-        extra = dict(tile=tile, group=group)
+        tile, group, sub = self._scan_tiling(rows, nprobe)
+        extra = dict(tile=tile, group=group, sub=sub)
         if self.codec == "sq8":
             extra.update(vmin=self.sq_params["vmin"], span=self.sq_params["span"])
         norms = self._scan_norms()
@@ -854,16 +955,21 @@ class IVFFlatIndex(_IVFBase):
         lists = (self.centroids, self.lists.data, self.lists.ids, self.lists.sizes)
         refine_rows = self.refine_store.data if self.refine_k_factor else None
 
+        counts = []  # the count output of every scan the XLA arm served
+
+        def launched(out):
+            return _count_on_its_way(out, "list rows count")
+
         def scan(b, with_pallas, nvalid=None):
             # maybe_checked = GRAFT_SANITIZE=1 checkify wrapper (identity
             # when off); scalar knobs ride as kwargs so the sanitizer can
             # partial-bind them before checkify abstracts the operands
-            return sanitize.maybe_checked(
+            return launched(sanitize.maybe_checked(
                 _ivf_flat_search, *lists,
                 b, k=scan_k, nprobe=nprobe, g=g, metric=self.metric,
                 codec=self.codec, list_norms=norms, use_pallas=with_pallas,
                 scan_bf16=self.scan_bf16, nvalid=nvalid, **extra,
-            )
+            ))
 
         if self.use_pallas and self._pallas_runtime_ok and not self._pallas_flat_validated:
             self._pallas_probe = jnp.asarray(
@@ -875,10 +981,12 @@ class IVFFlatIndex(_IVFBase):
             list-major order (``engine.scan_listmajor``, beside the
             ``engine.scan`` stage whose wait books it): the XLA arm does,
             the Pallas kernel scans query-major; the last path tried is the
-            one served."""
+            one served, and its counts are the ones booked."""
+            vals, ids, scanned = out
             if not with_pallas:
                 tracing.count("engine.scan_listmajor")
-            return out
+                counts.append(scanned)
+            return vals, ids
 
         def run(b, n):
             return GuardedScan(self, lambda p: scan(b, p, n), listmajor)
@@ -886,21 +994,46 @@ class IVFFlatIndex(_IVFBase):
         def refine(b, ids):
             return _rerank_exact(refine_rows, b, ids, k, self.metric)
 
-        def run_fused(q3, counts):
+        def run_fused(q3, nvalid):
             return GuardedScan(
-                self, lambda p: sanitize.maybe_checked(
+                self, lambda p: launched(sanitize.maybe_checked(
                     _ivf_flat_search_fused, *lists, refine_rows,
                     q3, k=k, scan_k=scan_k, nprobe=nprobe, g=g,
                     metric=self.metric, codec=self.codec,
                     refine=bool(self.refine_k_factor), list_norms=norms,
-                    use_pallas=p, scan_bf16=self.scan_bf16, counts=counts,
+                    use_pallas=p, scan_bf16=self.scan_bf16, counts=nvalid,
                     **extra,
-                ), listmajor)
+                )), listmajor)
 
-        return self._launch_blocks(
+        pending = self._launch_blocks(
             q, k, run, block=nb, fused_fn=run_fused,
             refine_fn=refine if self.refine_k_factor else None,
             with_counts=True)
+
+        def collect():
+            out = pending.collect()
+            self._book_list_rows(counts, sub)
+            return out
+
+        return base.SearchHandle(collect)
+
+    @staticmethod
+    def _book_list_rows(counts, sub: int) -> None:
+        """Two count rows a list-major scan, beside ``engine.scan_listmajor``,
+        from the program's third output (sub-blocks of ``sub`` rows: those
+        of the scan's tiles had every list been full, and those it
+        gathered): ``engine.scan_list_rows`` (rows of the tiles at whole
+        capacity: what a scan of whole padded lists gathers and multiplies)
+        and ``engine.scan_list_rows_skipped`` (those of them in sub-blocks
+        past the end of their list, which the scan never gathers). Called
+        once the search's results are on the host, as
+        ``IVFPQIndex._book_adc_cols`` is and for its reason: by then the
+        counts, whose copies started with their launches, have landed."""
+        for scanned in counts:
+            with xfercheck.explicit("list rows count fetch"):
+                whole, live = np.asarray(scanned).reshape(-1, 2).sum(0, dtype=np.int64)
+            tracing.count("engine.scan_list_rows", float(whole * sub))
+            tracing.count("engine.scan_list_rows_skipped", float((whole - live) * sub))
 
     def reconstruct_batch(self, ids: np.ndarray) -> np.ndarray:
         rows = self._device_rows(ids)
@@ -975,6 +1108,16 @@ class IVFFlatIndex(_IVFBase):
             if idx.refine_store is not None:
                 idx.refine_store.add(np.asarray(state["refine_rows"], np.float16))
         return idx
+
+
+def _count_on_its_way(out, what: str):
+    """A scan program's outputs, the copy of its count (the third) to the
+    host started with the launch: by the time the search's results are
+    fetched it has landed, and booking it costs no device-to-host latency
+    (0.4 ms on a v5e even for four ready bytes: PERF.md, PR 35)."""
+    with xfercheck.explicit(what + ", started with the launch"):
+        out[2].copy_to_host_async()
+    return out
 
 
 class GuardedScan(base.Dispatched):
@@ -1172,10 +1315,7 @@ class IVFPQIndex(_IVFBase):
         counts = []  # (capacity columns, columns scored) of every scan
 
         def launched(out):
-            """A scan program's outputs, its count on the way to the host."""
-            with xfercheck.explicit("ADC column count, started with the launch"):
-                out[2].copy_to_host_async()
-            return out
+            return _count_on_its_way(out, "ADC column count")
 
         def counted(rows):
             """At a scan's wait: its count taken off its outputs."""

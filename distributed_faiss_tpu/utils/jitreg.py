@@ -409,8 +409,8 @@ def _ivf_flat_operands(codec="f16"):
 def _listmajor_tiling():
     from distributed_faiss_tpu.models import ivf
 
-    tile, group = ivf.listmajor_tiling(_NQ, _NPROBE, _NLIST, _CAP, _D, 2)
-    return dict(tile=tile, group=group)
+    tile, group, sub = ivf.listmajor_tiling(_NQ, _NPROBE, _NLIST, _CAP, _D, 2)
+    return dict(tile=tile, group=group, sub=sub)
 
 
 def spec_ivf_flat_search():

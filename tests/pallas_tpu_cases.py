@@ -90,15 +90,16 @@ def cases():
 
 def listmajor_programs():
     """``ivfsq-batch``'s scan program (PR 31: the XLA arm, list-major, its
-    loop's trip count traced) at the cell's geometry — d 512, capacity
-    4096, 1024 float16 lists, nprobe 64, k 10 — for the 128- and the
-    256-row bucket, under the index's own tiling: [(name, fn, sig)]."""
+    loop's trip count traced; PR 43: a tile is a live sub-block of a probed
+    list) at the cell's geometry — d 512, capacity 4096, 1024 float16
+    lists, nprobe 64, k 10 — for the 128- and the 256-row bucket, under the
+    index's own tiling: [(name, fn, sig)]."""
     from distributed_faiss_tpu.models import ivf
 
     d, cap, nlist, nprobe = 512, 4096, 1024, 64
 
     def program(rows):
-        tile, group = ivf.listmajor_tiling(rows, nprobe, nlist, cap, d, 2)
+        tile, group, sub = ivf.listmajor_tiling(rows, nprobe, nlist, cap, d, 2)
 
         def sig(sds):
             args = (sds((nlist, d), "float32"), sds((nlist, cap, d), "float16"),
@@ -106,12 +107,30 @@ def listmajor_programs():
                     sds((rows, d), "float32"))
             return args, dict(k=10, nprobe=nprobe, g=1, metric="l2", codec="f16",
                               list_norms=sds((nlist, cap), "float32"),
-                              tile=tile, group=group, nvalid=sds((), "int32"))
+                              tile=tile, group=group, sub=sub,
+                              nvalid=sds((), "int32"))
 
-        return (f"ivf flat list-major rows={rows} T={tile} G={group}",
+        return (f"ivf flat list-major rows={rows} T={tile} G={group} sub={sub}",
                 ivf._ivf_flat_search, sig)
 
     return [program(128), program(256)]
+
+
+_ITEMSIZE = {"f16": 2, "bf16": 2, "f32": 4, "s32": 4, "u32": 4, "s8": 1, "u8": 1,
+             "pred": 1, "s64": 8, "f64": 8}
+
+
+def largest_gather_slice_bytes(hlo_text):
+    """The largest slice any ``gather`` of a compiled program takes, in
+    bytes (a gather's result has its operand's element type)."""
+    worst = 0
+    for dtype, sizes in re.findall(
+            r"= (\w+)\[[\d,]*\]\S* gather\(.*?slice_sizes=\{([\d,]+)\}", hlo_text):
+        n = 1
+        for dim in sizes.split(","):
+            n *= int(dim)
+        worst = max(worst, n * _ITEMSIZE[dtype])
+    return worst
 
 
 def lower_for_tpu(fn, sig, sds):
@@ -159,9 +178,12 @@ def main():
                   flush=True)
     for name, fn, sig in listmajor_programs():
         try:
-            mem = lower_for_tpu(fn, sig, sds).compile().memory_analysis()
-            print(json.dumps({"program": name, "ok": True,
-                              "temp_bytes": mem.temp_size_in_bytes}), flush=True)
+            compiled = lower_for_tpu(fn, sig, sds).compile()
+            print(json.dumps({
+                "program": name, "ok": True,
+                "temp_bytes": compiled.memory_analysis().temp_size_in_bytes,
+                "largest_gather_slice_bytes": largest_gather_slice_bytes(
+                    compiled.as_text())}), flush=True)
         except Exception as e:
             print(json.dumps({"program": name, "ok": False,
                               "error": f"{type(e).__name__}: {e}"[:600]}),

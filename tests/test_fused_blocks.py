@@ -78,7 +78,7 @@ def test_fused_matches_per_block_loop(rng, small_blocks):
     scan_k = k * idx.refine_k_factor
 
     def run(b):
-        vals, ids = ivfmod._ivf_flat_search(
+        vals, ids, _ = ivfmod._ivf_flat_search(
             idx.centroids, idx.lists.data, idx.lists.ids, idx.lists.sizes,
             b, scan_k, nprobe, g, "l2", "sq8",
             vmin=idx.sq_params["vmin"], span=idx.sq_params["span"],

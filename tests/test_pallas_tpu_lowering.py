@@ -72,11 +72,15 @@ def test_the_exact_scan_compiled_for_v5e_sorts_no_wide_row(compiled_for_v5e):
 
 def test_the_list_major_scan_compiles_for_v5e_and_copies_no_store(compiled_for_v5e):
     """``ivfsq-batch``'s program, with its traced trip count, at the 128-
-    and the 256-row bucket. Its scratch is the score buffer, 0.5 and 1.0 GB:
-    a gather slice of a whole (4096, 512) list makes XLA:TPU copy the 4.3 GB
-    store into slabs in every loop step (``temp_size`` 4.3 GB: the parent's
-    1.1 s a launch, PERF.md section 6, PR 31); the 256 KB view avoids it."""
+    and the 256-row bucket. Its scratch is the score buffer and a query's
+    gathered candidates, 0.85 and 1.2 GB: a gather slice of a whole (4096,
+    512) list makes XLA:TPU copy the 4.3 GB store into slabs in every loop
+    step (``temp_size`` 4.3 GB: the parent's 1.1 s a launch, PERF.md section
+    6, PR 31); no gather of the compiled program takes a slice over 512 kB
+    (a sub-block of a list is 256 kB)."""
     rows = [r for r in compiled_for_v5e if "program" in r]
     assert len(rows) == len(pallas_tpu_cases.listmajor_programs()) == 2
     assert all(r["ok"] for r in rows), rows
     assert max(r["temp_bytes"] for r in rows) < 2 << 30, rows
+    slices = [r["largest_gather_slice_bytes"] for r in rows]
+    assert 0 < max(slices) <= 512 << 10, rows
