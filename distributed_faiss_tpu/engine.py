@@ -1857,7 +1857,11 @@ class Index:
         IVF-PQ index, the candidate columns of its (query, probe) pairs'
         whole capacity and those of them the ADC scan did not compute (the
         fused kernel stops at the end of each list; the XLA one-hot skips
-        none; ``IVFPQIndex._book_adc_cols`` books both);
+        none; ``IVFPQIndex._book_adc_cols`` books both, for a mesh-sharded
+        PQ index too, every chip's columns counted once);
+        ``engine.mesh_place`` (a mesh index alone) is one record a
+        replicated operand placed on the rank's mesh for a launch
+        (``parallel/mesh._replicated``);
         ``engine.store_grow`` is one record a reallocation of a
         ``DeviceVectorStore`` (models/base.py), allocation to the end of
         the copy. ``engine.launch_overlapped`` (a count row, at zero beside

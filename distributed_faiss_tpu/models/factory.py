@@ -132,7 +132,8 @@ def _build_knnlm(cfg: IndexCfg):
             cfg.dim, _centroids(cfg), m=m, nbits=nbits, metric=cfg.get_metric(),
             mesh=_mesh(cfg), kmeans_iters=_kmeans_iters(cfg),
             probe_routing=_probe_routing(cfg),
-            use_pallas=bool(cfg.extra.get("pallas_adc", False)),
+            # absent: the index chooses its ADC kernel; set: forced
+            use_pallas=cfg.extra.get("pallas_adc"),
             refine_k_factor=int(cfg.extra.get("refine_k_factor", 0)),
         )
     if _probe_routing(cfg):

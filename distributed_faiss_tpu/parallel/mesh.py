@@ -64,7 +64,7 @@ from distributed_faiss_tpu.models import base
 from distributed_faiss_tpu.models import ivf as ivfmod
 from distributed_faiss_tpu.models.ivf import IVFFlatIndex, IVFPQIndex, probe_group_size
 from distributed_faiss_tpu.ops import distance
-from distributed_faiss_tpu.utils import xfercheck
+from distributed_faiss_tpu.utils import tracing, xfercheck
 
 _HIGHEST = jax.lax.Precision.HIGHEST
 logger = logging.getLogger(__name__)
@@ -286,7 +286,14 @@ def _counted(index, call):
 
     def wrapped(*args, **kwargs):
         index.launches += 1
-        return call(*args, **kwargs)
+        out = call(*args, **kwargs)
+        # the window's first program is on its way to the chips now: a mesh
+        # scan callable waits for its outputs itself, and the instant
+        # ``blocked_search`` takes once it returns would put the chip's
+        # whole span before the window's ``dispatched`` (the scheduler's
+        # timeline read 0.8 ms busy of a 50 ms window: PERF.md, PR 45)
+        tracing.instant("dispatched", first=True)
+        return out
 
     return wrapped
 
@@ -296,8 +303,12 @@ def _replicated(mesh, arr):
     mesh. The sharded jit entries would do the same reshard implicitly at
     dispatch, but the serving path runs under DFT_XFERCHECK's transfer
     guard, which (rightly) flags implicit cross-device placement — the
-    query feed is a designed transfer, so make it one."""
-    return jax.device_put(arr, NamedSharding(mesh, P()))
+    query feed is a designed transfer, so make it one. Host work a local
+    index does not have: stage ``engine.mesh_place`` (one record a placed
+    operand; the centroid table and codebooks stay placed after a rank's
+    first launch, the query block is placed every launch)."""
+    with tracing.stage("engine.mesh_place"):
+        return jax.device_put(arr, NamedSharding(mesh, P()))
 
 
 # --------------------------------------------------------------- index models
@@ -1076,7 +1087,8 @@ def _sharded_ivf_pq_search_fused(centroids, codebooks, list_codes, list_ids,
                                  g: int, metric: str, use_pallas: bool = False,
                                  adc_k: int = 0, raw_data=None):
     """Multi-block masked sharded IVF-PQ in one launch (see
-    _sharded_ivf_flat_search_fused)."""
+    _sharded_ivf_flat_search_fused); the third output is
+    ``_sharded_ivf_pq_search``'s, one count a block."""
 
     def body(qb):
         return _sharded_ivf_pq_search(centroids, codebooks, list_codes,
@@ -1107,6 +1119,11 @@ def _sharded_ivf_pq_search(centroids, codebooks, list_codes, list_ids, list_size
     merges top-k over ICI. Per-chip top-adc_k is a superset of this chip's
     contribution to the global ADC top-adc_k, so recall >= the unsharded
     refine path's; the ICI still carries only (S, nq, k).
+
+    -> (vals, ids, the candidate columns the scan computed ADC sums for, an
+    int32 scalar summed over the chips, every (query, probe) pair counted
+    on the chip that owns its list: ``nq * nprobe * cap`` on the XLA arm,
+    whose one-hot scores every pair on every chip).
     """
     q = q.astype(jnp.float32)
     coarse = distance.pairwise_scores(q, centroids, metric)
@@ -1146,9 +1163,11 @@ def _sharded_ivf_pq_search(centroids, codebooks, list_codes, list_ids, list_size
             else:
                 lut = jnp.broadcast_to(shared_lut[:, None], (nq, g, m, ksub))
             # a pair this chip does not own costs the kernel nothing
-            s, _ = ivfmod._adc_pair_scores(
+            s, cols = ivfmod._adc_pair_scores(
                 lut.reshape(nq * g, m, ksub), codes.reshape(nq * g, cap, m),
                 jnp.where(mine, sizes, 0).reshape(nq * g), use_pallas)
+            if not use_pallas:  # the one-hot's count is of every pair
+                cols = jnp.sum(mine.astype(jnp.int32)) * cap
             s = s.reshape(nq, g, cap)
             valid = (jnp.arange(cap)[None, None, :] < sizes[:, :, None])
             valid = valid & (ids >= 0) & mine[:, :, None]
@@ -1160,9 +1179,9 @@ def _sharded_ivf_pq_search(centroids, codebooks, list_codes, list_ids, list_size
             pos = jnp.where(valid, pos, -1)
             cv, cpos = distance.segmented_topk_rows(
                 s.reshape(nq, g * cap), min(local_k, g * cap), pos.reshape(nq, g * cap))
-            return distance.merge_topk(carry[0], carry[1], cv, cpos, local_k), None
+            return distance.merge_topk(carry[0], carry[1], cv, cpos, local_k), cols
 
-        (vals, pos), _ = jax.lax.scan(body, init, groups)
+        (vals, pos), cols = jax.lax.scan(body, init, groups)
         safe = jnp.where(pos >= 0, pos, 0)
         ids = jnp.where(pos >= 0, ids_local.reshape(-1)[safe], -1)
         if raw_local is not None:
@@ -1178,7 +1197,8 @@ def _sharded_ivf_pq_search(centroids, codebooks, list_codes, list_ids, list_size
         fv = jnp.transpose(av, (1, 0, 2)).reshape(nq, -1)
         fi = jnp.transpose(ai, (1, 0, 2)).reshape(nq, -1)
         best, pick = jax.lax.top_k(fv, k)
-        return best, jnp.take_along_axis(fi, pick, axis=1)
+        return (best, jnp.take_along_axis(fi, pick, axis=1),
+                jax.lax.psum(jnp.sum(cols), AXIS))
 
     if raw_data is not None:
         fn = shard_map(
@@ -1186,7 +1206,7 @@ def _sharded_ivf_pq_search(centroids, codebooks, list_codes, list_ids, list_size
             mesh=mesh,
             in_specs=(P(), P(), P(AXIS, None, None), P(AXIS, None), P(AXIS),
                       P(AXIS, None, None)),
-            out_specs=(P(), P()),
+            out_specs=(P(), P(), P()),
             check_vma=False,
         )
         return fn(q, groups, list_codes, list_ids, list_sizes, raw_data)
@@ -1194,7 +1214,7 @@ def _sharded_ivf_pq_search(centroids, codebooks, list_codes, list_ids, list_size
         lambda a, b, c, d, e: local(a, b, c, d, e, None),
         mesh=mesh,
         in_specs=(P(), P(), P(AXIS, None, None), P(AXIS, None), P(AXIS)),
-        out_specs=(P(), P()),
+        out_specs=(P(), P(), P()),
         check_vma=False,
     )
     return fn(q, groups, list_codes, list_ids, list_sizes)
@@ -1210,11 +1230,13 @@ class ShardedIVFPQIndex(IVFPQIndex):
     def __init__(self, dim: int, nlist: int, m: int = 64, nbits: int = 8,
                  metric: str = "l2", mesh: Optional[Mesh] = None,
                  kmeans_iters: int = 10, pq_iters: int = 15,
-                 probe_routing: bool = False, use_pallas: bool = False,
-                 refine_k_factor: int = 0):
+                 probe_routing: bool = False,
+                 use_pallas: Optional[bool] = None, refine_k_factor: int = 0):
+        # use_pallas as the local index holds it: None, the index chooses
+        # its ADC kernel (IVFPQIndex._kernel_applies); True / False force
         super().__init__(dim, nlist, m=m, nbits=nbits, metric=metric,
                          kmeans_iters=kmeans_iters, pq_iters=pq_iters,
-                         use_pallas=bool(use_pallas),
+                         use_pallas=use_pallas,
                          refine_k_factor=refine_k_factor)
         # the single-device refine store the parent builds is replaced by a
         # mesh-sharded raw-row store laid out exactly like the code lists
@@ -1286,45 +1308,72 @@ class ShardedIVFPQIndex(IVFPQIndex):
         nb = base.pick_query_block(
             self.lists.cap * (self.m + 8) + self.m * 256 * 4)
 
-        def run_masked(b, pallas_on):
-            g = probe_group_size(
-                nprobe,
-                ivfmod.pq_probe_payload_bytes(self.lists.cap, self.m, nq_block=nb))
-            return _sharded_ivf_pq_search(
+        cap = self.lists.cap
+        g = probe_group_size(
+            nprobe, ivfmod.pq_probe_payload_bytes(cap, self.m, nq_block=nb))
+
+        def masked(program, b, pallas_on, adc_k=adc_k, raw=raw):
+            return ivfmod._count_on_its_way(program(
                 self.centroids, self.codebooks, self.lists.data, self.lists.ids,
                 self.lists.sizes, _replicated(self.mesh, b), self.mesh, k,
                 nprobe, g, self.metric,
                 use_pallas=pallas_on, adc_k=adc_k, raw_data=raw,
-            )
+            ), "ADC column count")
 
-        def guarded(call, *args):
-            # the unsharded path's ladder (kernel -> XLA oracle -> demote).
-            # launches counts INSIDE it so a proven-failure XLA re-dispatch
-            # is a second counted launch (the perf rows must expose the
-            # degrade, not hide it)
-            return self._guarded_scan(_counted(self, lambda p: call(*args, p)))
+        def attempt(call, *args):
+            # launches counts INSIDE the ladder so a proven-failure XLA
+            # re-dispatch is a second counted launch (the perf rows must
+            # expose the degrade, not hide it)
+            return _counted(self, lambda p: call(*args, p))
+
+        if (self._kernel_applies() and self._pallas_runtime_ok
+                and not self._adc_validated):
+            # first fused scan of this index (warm-up, in a served rank), as
+            # the local index checks its own: the kernel's ADC scores, before
+            # any refine, against the XLA path's on one small block. Always
+            # of the masked program: both modes score a pair through the
+            # one ivfmod._adc_pair_scores, which is what is checked, and the
+            # masked program takes a block as it is, where the routed one
+            # needs a pair bucket sized to the block. A routed index pays
+            # for it with one compile and two launches of a program it does
+            # not serve with, once, in its first window
+            self._adc_validated = True
+            ivfmod._first_use_check(
+                self, lambda b, p: _counted(self, masked)(
+                    _sharded_ivf_pq_search, b, p, adc_k=0, raw=None),
+                distance.pad_rows(np.asarray(q[:8], np.float32), 8),
+                self._PALLAS_KERNEL, 1e-4)
 
         if self.probe_routing:
+            # the unsharded path's ladder (kernel -> XLA oracle -> demote)
             return _routed_search_blocks(
                 self, q, k, nprobe, group,
-                lambda block, n, bucket: guarded(run_routed, block, n, bucket),
+                lambda block, n, bucket: self._guarded_scan(
+                    attempt(run_routed, block, n, bucket)),
                 local_k=adc_k or k,
             )
-        def run_masked_fused(q3, pallas_on):
-            g = probe_group_size(
-                nprobe,
-                ivfmod.pq_probe_payload_bytes(self.lists.cap, self.m, nq_block=nb))
-            return _sharded_ivf_pq_search_fused(
-                self.centroids, self.codebooks, self.lists.data, self.lists.ids,
-                self.lists.sizes, _replicated(self.mesh, q3), self.mesh, k,
-                nprobe, g, self.metric,
-                use_pallas=pallas_on, adc_k=adc_k, raw_data=raw,
-            )
 
-        return self._search_blocks(
-            q, k, lambda b: guarded(run_masked, b),
+        counts = []  # (capacity columns, columns scored) of every scan
+
+        def guarded(program, b, rows):
+            """The same ladder, and at the scan's wait its count taken off
+            its outputs, for the rows the local index books."""
+
+            def settled(out, with_pallas):
+                vals, ids, cols = self._fused_counted(out, with_pallas)
+                counts.append((rows * nprobe * cap, cols))
+                return vals, ids
+
+            return ivfmod.GuardedScan(
+                self, attempt(masked, program, b), settled).wait()
+
+        out = self._search_blocks(
+            q, k, lambda b: guarded(_sharded_ivf_pq_search, b, b.shape[0]),
             block=nb,
-            fused_fn=lambda q3: guarded(run_masked_fused, q3))
+            fused_fn=lambda q3: guarded(_sharded_ivf_pq_search_fused, q3,
+                                        q3.shape[0] * q3.shape[1]))
+        self._book_adc_cols(counts)
+        return out
 
     def state_dict(self):
         state = super().state_dict()
